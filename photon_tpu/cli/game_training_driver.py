@@ -719,6 +719,7 @@ def _run_inner(args, task) -> dict:
                     "sweep": rec.sweep,
                     "coordinate": rec.coordinate_id,
                     "seconds": rec.seconds,
+                    **(rec.convergence or {}),
                     **(rec.validation.values if rec.validation else {}),
                 }
                 for i, r in enumerate(results)

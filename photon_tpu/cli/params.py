@@ -28,6 +28,13 @@ from __future__ import annotations
 import dataclasses
 from typing import TYPE_CHECKING, Optional, Sequence
 
+# Where the persistent XLA cache goes is the runtime's decision; the drivers
+# reach it through this module, next to the flag (jax-free at import).
+from photon_tpu.runtime.compile_store import (  # noqa: F401
+    compilation_cache_dir,
+    enable_compilation_cache,
+)
+
 # The estimator/optimizer config types reach jax-backed kernels on import.
 # They are needed only by the coordinate mini-DSL parsers, so they load
 # lazily inside those functions — the accelerator-free drivers (router,
@@ -262,63 +269,17 @@ def mesh_from_flags(n_devices: int, mesh_spec=None):
 
 
 def add_compilation_cache_flag(parser) -> None:
-    """Shared --compilation-cache-dir flag (default: $PHOTON_XLA_CACHE_DIR)."""
-    import os
-
+    """Shared --compilation-cache-dir flag (see
+    :func:`~photon_tpu.runtime.compile_store.compilation_cache_dir` for
+    where the cache goes without it)."""
     parser.add_argument(
-        "--compilation-cache-dir",
-        default=os.environ.get("PHOTON_XLA_CACHE_DIR") or None,
+        "--compilation-cache-dir", default=None,
         help="persistent XLA compilation cache directory: compiled programs "
              "survive process restarts (supervisor relaunches, repeated "
-             "driver runs), so a 20-40s accelerator compile is paid once "
-             "per program shape, not once per process "
-             "(default: $PHOTON_XLA_CACHE_DIR)")
-
-
-def enable_compilation_cache(path) -> None:
-    """Turn on jax's persistent compilation cache at ``path`` (no-op if
-    falsy). Must run before the first jit compilation — jax only consults
-    the cache dir at compile time, so everything compiled BEFORE this call
-    is silently uncached and will recompile on the next restart. A late
-    call used to be a silent no-op for those programs; now it is detected
-    (any watched kernel already traced in this process) and warned LOUDLY,
-    because a driver that reorders its init quietly loses exactly the
-    warm-restart behavior the recovery stack depends on
-    (docs/robustness.md §"Recovery time")."""
-    if not path:
-        return
-    import logging
-    import os
-
-    import jax
-
-    from photon_tpu.runtime.compile_store import process_has_compiled
-
-    if process_has_compiled():
-        logging.getLogger("photon_tpu.cli").warning(
-            "enable_compilation_cache(%r) called AFTER this process already "
-            "compiled kernels: programs compiled before this point were NOT "
-            "persisted and will recompile from scratch on the next restart "
-            "(the cache handle is re-initialized now, so later compiles DO "
-            "persist). Call it (or enable_compile_store) before the first "
-            "jit dispatch — typically first thing in the driver, before "
-            "data loading touches any jitted code.", path,
-        )
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs",
-        float(os.environ.get("PHOTON_XLA_CACHE_MIN_SECS", "1.0")),
-    )
-    # A late enable used to be a TOTAL silent no-op: jax memoizes the
-    # cache handle at the process's first compile (watched or not — even a
-    # stray jnp.zeros counts), so setting the dir afterwards persisted
-    # nothing, ever. Resetting the handle unconditionally makes the call
-    # effective from here on (the warning above still marks pre-call
-    # compiles as lost).
-    from photon_tpu.runtime.compile_store import _reset_jax_cache_handle
-
-    _reset_jax_cache_handle()
+             "driver runs), so an accelerator compile is paid once per "
+             "program shape, not once per process (default: "
+             "$JAX_COMPILATION_CACHE_DIR if set — naming another directory "
+             "here is then an error — else <checkout>/.jax_cache)")
 
 
 def add_compile_store_flag(parser) -> None:
@@ -343,9 +304,9 @@ def enable_compile_store(args, output_dir=None):
     """Activate the AOT compile store process-wide (``--compile-store off``
     disables). Defaults to ``<output-dir>/compile-store`` so supervised
     restarts and checkpoint resumes get zero-recompile behavior out of the
-    box; when the driver wired no ``--compilation-cache-dir``, the store
-    supplies the persistent-cache layer itself (see
-    runtime/compile_store.configure). Returns the store or None."""
+    box. The artifact bytes live in the one persistent cache
+    (:func:`compilation_cache_dir`), never under the store. Returns the
+    store or None."""
     import logging
 
     from photon_tpu.runtime import compile_store

@@ -96,8 +96,8 @@ from photon_tpu.optim.base import (
 Array = jax.Array
 
 NEWTON_MAX_P = 64           # [P,P] solves stay tiny; beyond this, fall back
-                            # (documented gate: module doc, docs/scaling.md,
-                            # docs/round5.md all say P <= 64 — keep in sync)
+                            # (documented gate: module doc and
+                            # docs/scaling.md say P <= 64 — keep in sync)
 NEWTON_CHUNK_MAX_P = 128    # wider P admitted for CHUNKED primal candidates
                             # under MEASURED routing only — at P in (64,128]
                             # the dense Hessian may or may not beat L-BFGS

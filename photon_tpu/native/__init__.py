@@ -9,6 +9,7 @@ Python codec (``photon_tpu.io.avro``) — slower, identical semantics.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -43,20 +44,43 @@ _lib: Optional[ctypes.CDLL] = None
 _failed = False
 
 
-def _compile() -> bool:
-    cmd = [
-        # -march=native is safe here: the .so is compiled on demand on the
-        # same host that runs it (never shipped), and the hash/parse inner
-        # loops gain measurably from host vector ISA.
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        "-o", _SO + ".tmp", _SRC,
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-    except (OSError, subprocess.SubprocessError):
-        return False
-    os.replace(_SO + ".tmp", _SO)
+def _compile(force: bool = False) -> bool:
+    """Build ``_SO`` in place; False if the toolchain cannot.
+
+    Several processes may get here at once (xdist workers, ingest workers,
+    the drivers of one fresh checkout). Each builds to a name of its own in
+    this directory and renames it into place, so a reader never maps a half
+    written file; the flock makes the losers of the race wait for the
+    winner's file instead of building their own.
+    """
+    with open(_SO + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not force and not _stale():
+            return True  # another process built it while we waited
+        tmp = f"{_SO}.{os.getpid()}.tmp"
+        cmd = [
+            # -march=native is safe here: the .so is compiled on demand on
+            # the same host that runs it (never shipped), and the hash/parse
+            # inner loops gain measurably from host vector ISA.
+            "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+            "-o", tmp, _SRC,
+        ]
+        try:
+            subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+            os.replace(tmp, _SO)
+        except (OSError, subprocess.SubprocessError):
+            return False
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
     return True
+
+
+def _stale() -> bool:
+    return (
+        not os.path.exists(_SO)
+        or os.path.getmtime(_SO) < os.path.getmtime(_SRC)
+    )
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -128,11 +152,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _failed:
             return _lib
         try:
-            stale = (
-                not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            )
-            if stale and not _compile():
+            if _stale() and not _compile():
                 _failed = True
                 return None
             try:
@@ -141,7 +161,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 # A cached .so that predates newly-added symbols (mtime
                 # preserved by tar/rsync, or equal mtimes): rebuild once
                 # instead of crashing every ingest call.
-                if not _compile():
+                if not _compile(force=True):
                     _failed = True
                     return None
                 _lib = _bind(ctypes.CDLL(_SO))

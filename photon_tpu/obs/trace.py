@@ -30,7 +30,7 @@ Design constraints (docs/observability.md):
   ``obs.fleet.merge_traces`` can align N per-process shards onto one
   wall-clock timeline (docs/observability.md §"Fleet view").
 
-Span taxonomy (``cat`` → ``name``) is documented in docs/observability.md.
+Span catalog (``cat`` → ``name``) is documented in docs/observability.md.
 """
 from __future__ import annotations
 

@@ -1,4 +1,17 @@
-"""Pallas TPU kernels for the ELL sparse hot ops (matvec / rmatvec).
+"""Pallas kernels for the ELL sparse hot ops (matvec / rmatvec).
+
+**Status (PR 22): these kernels run only in the Pallas interpreter. The TPU
+compiler refuses them** — asked to compile ``_run_op`` for a described v5e
+(``tests/test_chip_compile.py``) it says ``Unimplemented primitive in
+Pallas TPU lowering for KernelType.TC: dynamic_slice`` (the value-level
+slices in ``_gather_onehot_kernel.chunk``), and behind that the design's
+central step is refused too: a same-shape ``take_along_axis`` over a
+``[2048,128]`` / ``[4096,128]`` table is ``Not implemented: Multiple source
+vregs along gather dimension`` — the hardware gather reads within one
+8x128 register, not across a VMEM table. So nothing attaches these tables
+by default (``SparseFeatures.with_accelerator_paths`` attaches the XLA fast
+path); the module stays as the starting point for ROADMAP S1/D2. The
+paragraphs below describe the intent, not a measured result.
 
 Why: the XLA fast paths in :mod:`photon_tpu.ops.fast_sparse` still run ~200x
 off the HBM roofline (BENCH_DETAILS.json ``fraction_of_roofline`` ~0.005 on
@@ -36,9 +49,10 @@ Design (SURVEY.md §7 hard-part #2, VERDICT round-2 ask #2):
   over budget, construction raises and ``with_pallas_path`` falls back to
   the XLA fast path.
 
-Layouts ride on ``SparseFeatures.pallas`` (see ``with_pallas_path``); the
-kernels are f32-only and fall back to the XLA path off-TPU (tests run them
-in Pallas interpret mode on CPU).
+Layouts ride on ``SparseFeatures.pallas`` (see ``with_pallas_path``, an
+explicit opt-in); the kernels are f32-only, off-TPU the XLA path serves
+(tests run them in Pallas interpret mode on CPU), and on a TPU backend
+they are compiled — never interpreted — and so fail loudly today.
 """
 from __future__ import annotations
 

@@ -30,7 +30,7 @@ from functools import partial
 import jax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from photon_tpu.parallel.mesh import shard_map  # version-compat wrapper
+from photon_tpu.parallel.mesh import shard_map
 
 from photon_tpu.data.batch import LabeledBatch
 from photon_tpu.functions.objective import GLMObjective
@@ -65,9 +65,13 @@ def fit_data_parallel(
     (padding is invisible to the objective — SURVEY.md batch semantics).
     Returns (GeneralizedLinearModel, OptimizerResult), both replicated.
     """
-    from photon_tpu.parallel.mesh import pad_and_shard_batch
+    from photon_tpu.parallel.mesh import (
+        note_sharded_bytes,
+        pad_and_shard_batch,
+    )
 
     batch = pad_and_shard_batch(batch, mesh, data_axis)
+    note_sharded_bytes("fixed_effect_features", batch.features)
     rep = replicated(mesh)
     w0 = jax.device_put(w0, rep)
     # Array-valued reg_mask / prior / normalization can't be part of the

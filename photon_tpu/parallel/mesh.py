@@ -19,22 +19,13 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 - re-exported to parallel/*
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXIS = "data"
 ENTITY_AXIS = "entity"
 FEATURE_AXIS = "feature"
 DCN_AXIS = "dcn"
-
-try:  # jax >= 0.6 exports shard_map at top level (check_vma kwarg)
-    from jax import shard_map
-except ImportError:  # older jax: experimental home + the pre-rename kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw:  # renamed from check_rep in newer jax
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_compat(f, **kw)
 
 # An axis argument throughout parallel/ may be one mesh axis name or a tuple
 # of names (e.g. ("dcn", "data") — rows sharded over slices x chips, with
@@ -132,6 +123,25 @@ def batch_sharding(mesh: Mesh, axis=DATA_AXIS) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+def note_sharded_bytes(kind: str, tree) -> None:
+    """Record, per device, the bytes it holds of a freshly placed sharded
+    pytree (``sharded_bytes_per_device{array=kind, device=id}``) — how a
+    run shows its rows or entities are spread over the mesh and not all on
+    device 0. Shard metadata only: no transfer, no sync."""
+    from photon_tpu.obs.metrics import REGISTRY
+
+    per_device: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            per_device[shard.device.id] = (
+                per_device.get(shard.device.id, 0) + shard.data.nbytes)
+    gauge = REGISTRY.gauge(
+        "sharded_bytes_per_device",
+        "bytes each device holds of the last placed sharded arrays, by kind")
+    for device, nbytes in per_device.items():
+        gauge.set(nbytes, array=kind, device=str(device))
 
 
 def shard_batch_pytree(batch, mesh: Mesh, axis=DATA_AXIS):

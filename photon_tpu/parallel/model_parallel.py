@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from photon_tpu.parallel.mesh import shard_map  # version-compat wrapper
+from photon_tpu.parallel.mesh import shard_map
 
 from photon_tpu.data.batch import DenseFeatures, LabeledBatch, SparseFeatures
 from photon_tpu.functions.problem import GLMOptimizationProblem

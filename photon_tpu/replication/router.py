@@ -510,18 +510,22 @@ class RouterServer:
         view can only improve at that replica's next health sweep, so the
         honest hint is the time until ``last_check_ts +
         health_interval_s`` — a client told "1" against a 30 s sweep would
-        hammer a door that cannot open yet. Clamped to >= 1 s (ceil)."""
-        now = time.time()
+        hammer a door that cannot open yet. Clamped (ceil) to
+        ``[1, health_interval_s]``: ``now`` is read under the lock, so a
+        sweep cannot land between the two reads and push the hint past one
+        whole interval."""
+        longest = max(1, math.ceil(self.health_interval_s))
         with self._lock:
+            now = time.time()
             checked = [r for r in self._replicas
                        if r.last_check_ts is not None]
             if not checked:
-                return str(max(1, math.ceil(self.health_interval_s)))
+                return str(longest)
             best = min(checked,
                        key=lambda r: (r.consecutive_failures,
                                       -(r.last_check_ts or 0.0)))
             eta = (best.last_check_ts + self.health_interval_s) - now
-        return str(max(1, math.ceil(eta)))
+        return str(min(longest, max(1, math.ceil(eta))))
 
     # ------------------------------------------------------------ snapshots
 

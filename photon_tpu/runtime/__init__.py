@@ -4,7 +4,7 @@ The reference inherited its runtime resilience from Spark (a lost executor
 is rescheduled, lineage replays the partition — SURVEY.md §5.3); the
 rebuild's runtime is a JAX backend client whose failure modes — init hangs,
 compile errors, device loss, OOM — previously surfaced as unclassified
-exceptions or, worse, 25-minute silent hangs (TPU_RECOVERY.jsonl).
+exceptions or, worse, silent hangs inside backend init.
 ``backend_guard`` makes backend failure a first-class, tested contract:
 fail fast under a hard deadline, classify the cause, and recover under an
 explicit policy (docs/robustness.md §"Backend-failure resilience").

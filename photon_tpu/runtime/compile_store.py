@@ -5,8 +5,8 @@ checkpoint-resume restarts, in-run device-loss recovery, serving kernel
 re-warmup — pays a full XLA retrace on re-entry, because
 ``supervisor.clear_executable_caches`` and process restarts drop every
 compiled executable (PR 4 measured 3.5 s compile + 6.4 s calibration at the
-100K bucket shape, and TPU_RECOVERY.jsonl shows restart storms where that
-cost recurs per attempt). Upstream photon-ml never had this failure mode —
+100K bucket shape on the CPU, and in a restart storm that cost recurs per
+attempt). Upstream photon-ml never had this failure mode —
 Spark re-JITs Scala closures for free — so the rebuild's recovery-time
 story is only honest once compilation stops being the dominant term in
 MTTR (ROADMAP item 4).
@@ -17,7 +17,7 @@ The store has two layers:
   (``jax_compilation_cache_dir``): every XLA executable serializes to disk
   keyed by its HLO digest, so a re-compile after a cache clear or a process
   restart is a disk LOAD, not an XLA compile. The store forces the cache on
-  (under ``<root>/xla`` when the driver didn't wire its own dir) with a
+  (where :func:`compilation_cache_dir` puts it) with a
   zero min-compile-time floor — recovery cares about every kernel in the
   closed set, not just the slow ones.
 * **The manifest** (``<root>/manifest.json`` + one pickled abstract
@@ -73,9 +73,11 @@ __all__ = [
     "CompileStore",
     "active",
     "arm_first_step_clock",
+    "compilation_cache_dir",
     "compile_split",
     "configure",
     "deactivate",
+    "enable_compilation_cache",
     "install_accounting",
     "manifest_ref_if_active",
     "note_compilation",
@@ -220,7 +222,7 @@ class compile_split:
 
 # Any process-wide compilation (registered kernels bump this via
 # obs.retrace.note_trace) — the "already compiled" detector behind the
-# enable_compilation_cache late-call guard (cli/params.py).
+# enable_compilation_cache late-call guard.
 _compiled_flag = threading.Event()
 
 
@@ -531,23 +533,96 @@ _DISABLED = False  # explicit opt-out pins OFF even with the env var set
 
 
 def configure(root: str, enable_xla_cache: bool = True) -> CompileStore:
-    """Make ``root`` this process's active compile store. When no
-    persistent compilation cache is wired yet (``jax_compilation_cache_dir``
-    unset) and ``enable_xla_cache``, the store supplies one —
-    ``$PHOTON_XLA_CACHE_DIR`` or ``<root>/xla`` — with a zero
-    min-compile-time floor (recovery needs EVERY kernel in the closed set
-    persisted, not just the slow ones)."""
+    """Make ``root`` this process's active compile store. With
+    ``enable_xla_cache`` the persistent compilation cache is switched on
+    where :func:`compilation_cache_dir` puts it (unless a directory
+    is already in force), with a zero min-compile-time floor (recovery
+    needs EVERY kernel in the closed set persisted, not just the slow
+    ones)."""
     global _ACTIVE, _DISABLED
     store = CompileStore(root)
     if enable_xla_cache:
-        _ensure_persistent_cache(store)
+        _ensure_persistent_cache()
     with _active_lock:
         _ACTIVE = store
         _DISABLED = False  # an explicit configure overrides a prior opt-out
     return store
 
 
-def _ensure_persistent_cache(store: CompileStore) -> None:
+def compilation_cache_dir(flag=None):
+    """Where the persistent XLA cache goes — the ONE resolution every
+    driver, the compile store and chip_smoke.py share. Returns the
+    directory the program must set in code, or None when it must set none:
+
+    * ``$JAX_COMPILATION_CACHE_DIR`` set: JAX reads it by itself, so the
+      answer is None. A ``--compilation-cache-dir`` naming another place is
+      refused rather than silently winning (or silently losing).
+    * unset: the flag's directory, else ``<checkout>/.jax_cache`` — a fixed
+      path beside the package, never under an output directory or a
+      temporary name: the path is part of how a later process finds what
+      an earlier one compiled, so a directory that moves never hits.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        if flag and os.path.realpath(flag) != os.path.realpath(env):
+            raise ValueError(
+                f"--compilation-cache-dir {flag!r} conflicts with "
+                f"JAX_COMPILATION_CACHE_DIR={env!r}; drop one of them")
+        return None
+    if flag:
+        return flag
+    import photon_tpu
+
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.abspath(photon_tpu.__file__)))
+    return os.path.join(checkout, ".jax_cache")
+
+
+def enable_compilation_cache(flag=None, min_compile_secs=None):
+    """Turn on jax's persistent compilation cache at
+    :func:`compilation_cache_dir` and return the directory in force. Must
+    run before the first jit compilation — jax only consults the cache dir
+    at compile time, so everything compiled BEFORE this call is silently
+    uncached and will recompile on the next restart. A late call is
+    detected (any watched kernel already traced in this process) and
+    warned LOUDLY, because a driver that reorders its init quietly loses
+    exactly the warm-restart behavior the recovery stack depends on
+    (docs/robustness.md §"Recovery time").
+
+    ``min_compile_secs`` is the persistence floor (default
+    ``PHOTON_XLA_CACHE_MIN_SECS``, else jax's own 1.0 s)."""
+    import jax
+
+    if min_compile_secs is None:
+        min_compile_secs = float(
+            os.environ.get("PHOTON_XLA_CACHE_MIN_SECS", "1.0"))
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", min_compile_secs)
+    path = compilation_cache_dir(flag)
+    if path is None or path == jax.config.jax_compilation_cache_dir:
+        return jax.config.jax_compilation_cache_dir
+    if process_has_compiled():
+        logger.warning(
+            "enable_compilation_cache(%r) called AFTER this process already "
+            "compiled kernels: programs compiled before this point were NOT "
+            "persisted and will recompile from scratch on the next restart "
+            "(the cache handle is re-initialized now, so later compiles DO "
+            "persist). Call it (or enable_compile_store) before the first "
+            "jit dispatch — typically first thing in the driver, before "
+            "data loading touches any jitted code.", path,
+        )
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    # jax memoizes the cache handle at the process's first compile (watched
+    # or not — even a stray jnp.zeros counts), so a dir set afterwards
+    # would persist nothing, ever. Resetting the handle makes the call
+    # effective from here on (the warning above still marks pre-call
+    # compiles as lost).
+    _reset_jax_cache_handle()
+    return path
+
+
+def _ensure_persistent_cache() -> None:
     try:
         import jax
 
@@ -558,17 +633,12 @@ def _ensure_persistent_cache(store: CompileStore) -> None:
         # cold while the prewarm journal claims the store is working.
         min_secs = float(os.environ.get("PHOTON_XLA_CACHE_MIN_SECS", "0.0"))
         if jax.config.jax_compilation_cache_dir:
-            # Driver already wired its own dir; layer on it, floor lowered.
+            # A directory is already in force (the variable, or the driver
+            # enabled it first); layer on it, floor lowered.
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", min_secs)
             return
-        path = (os.environ.get("PHOTON_XLA_CACHE_DIR")
-                or os.path.join(store.root, "xla"))
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_secs)
-        _reset_jax_cache_handle()
+        enable_compilation_cache(None, min_compile_secs=min_secs)
     except Exception as e:  # noqa: BLE001 - cache layer is best-effort
         logger.warning("compile store: persistent cache unavailable (%s); "
                        "prewarm will compile instead of load", e)

@@ -105,7 +105,7 @@ class MapCountWatchdog:
                              f"{warn_fraction}")
         self.warn_fraction = warn_fraction
         self.rewarn_seconds = rewarn_seconds
-        self._last_warn = 0.0
+        self._last_warn: Optional[float] = None  # never warned
 
     @staticmethod
     def map_count() -> int:
@@ -134,7 +134,8 @@ class MapCountWatchdog:
         warned = False
         now = time.monotonic()
         if frac >= self.warn_fraction and (
-            now - self._last_warn >= self.rewarn_seconds
+            self._last_warn is None
+            or now - self._last_warn >= self.rewarn_seconds
         ):
             self._last_warn = now
             warned = True
@@ -354,9 +355,8 @@ def run_with_recovery(
             # OOM is deterministic-unless-degraded: the same shapes re-OOM
             # no matter how long we wait, so neither sleep on it nor DRAW
             # from the decorrelated-jitter schedule (a drawn-but-unslept
-            # delay would still inflate the next transient's backoff) —
-            # the TPU_RECOVERY.jsonl pattern of repeated identical
-            # failures (runtime/memory_guard).
+            # delay would still inflate the next transient's backoff)
+            # (runtime/memory_guard).
             from photon_tpu.runtime.memory_guard import is_oom
 
             delay = 0.0 if is_oom(e) else next(delays)
@@ -367,11 +367,9 @@ def run_with_recovery(
 
 # ---------------------------------------------------------------- supervision
 #
-# RunSupervisor formalizes what the ad-hoc TPU recovery tooling grew by
-# hand (TPU_RECOVERY.jsonl: per-attempt {attempt, seconds, ok, tail, time}
-# rows appended by scripts/tpu_recovery_daemon.py): classified restarts
-# from checkpoints, an append-only machine-readable journal under the
-# write_metrics_jsonl atomic O_APPEND contract, restart counters, and
+# RunSupervisor: classified restarts from checkpoints, an append-only
+# machine-readable journal under the write_metrics_jsonl atomic O_APPEND
+# contract, restart counters, and
 # recovery.* trace events — docs/robustness.md §"Recovery journal".
 
 

@@ -10,7 +10,7 @@ swap). Examples::
     python scripts/bench_compare.py BENCH_r03.json BENCH_r05.json
 
     # cross-backend pair: metrics marked `incomparable`, never scored
-    python scripts/bench_compare.py BENCH_r02.json BENCH_r05.json
+    python scripts/bench_compare.py BENCH_DETAILS.json BENCH_r05.json
 
     # the ci.sh advisory stage: the two newest checked-in artifacts
     python scripts/bench_compare.py --newest 2 --json verdict.json

@@ -3,8 +3,7 @@
 Exercises the fail-fast backend contract end to end WITHOUT a chip, using
 the probe's injection seam (``probe_code`` runs arbitrary child code):
 
-1. an injected init HANG is killed at the configured deadline — seconds,
-   not the ~1500 s the operational record shows (TPU_RECOVERY.jsonl) —
+1. an injected init HANG is killed at the configured deadline — seconds —
    and classified ``init_unavailable``;
 2. an injected ``Unable to initialize backend: UNAVAILABLE`` init failure
    (the recovery log's literal signature) classifies ``init_unavailable``;
@@ -18,9 +17,9 @@ the probe's injection seam (``probe_code`` runs arbitrary child code):
    and surfaces the last classified cause;
 6. a WARM-RESTART drill (docs/robustness.md §"Recovery time"): a real
    kernel compiles cold into the AOT compile store
-   (``$PHOTON_XLA_CACHE_DIR`` is the persistent artifact layer — ci.sh
-   wires a fresh dir so this stage actually exercises warm-restart
-   behavior instead of always restarting cold), the attempt dies on a
+   (the persistent cache is the artifact layer — ci.sh names a fresh
+   dir through ``$JAX_COMPILATION_CACHE_DIR`` so the cold half really is
+   cold), the attempt dies on a
    device loss after the executable caches clear, and the supervisor's
    pre-warmed retry must journal ``restart_to_first_step_seconds`` with
    the pre-warm's XLA share BELOW its I/O share and ZERO kernel re-traces
@@ -179,14 +178,9 @@ def warm_restart_drill() -> None:
     from photon_tpu.types import TaskType
 
     print("== warm-restart drill: compile store + supervisor pre-warm ==")
-    # $PHOTON_XLA_CACHE_DIR is the artifact layer (ci.sh wires a fresh
-    # temp dir); without it the drill provisions its own so the assertion
-    # below always exercises a real warm restart, never a silent cold one.
-    if not os.environ.get("PHOTON_XLA_CACHE_DIR"):
-        os.environ["PHOTON_XLA_CACHE_DIR"] = tempfile.mkdtemp(
-            prefix="photon-xla-cache-")
-    print(f"  artifact layer: PHOTON_XLA_CACHE_DIR="
-          f"{os.environ['PHOTON_XLA_CACHE_DIR']}")
+    # The persistent cache is the artifact layer; cs.configure below
+    # switches it on where runtime/compile_store.compilation_cache_dir puts it (ci.sh
+    # names a fresh dir through $JAX_COMPILATION_CACHE_DIR).
 
     rng = np.random.default_rng(0)
     n, d, k = 4096, 64, 6

@@ -1,0 +1,149 @@
+#!/usr/bin/env python3
+"""What the two XLA sparse formulations (plain gather/segment_sum and the
+fast one-hot path) give on the device in use, op by op and end to end.
+
+    python scripts/sparse_formulation_check.py ops       # this process owns the device
+    python scripts/sparse_formulation_check.py training  # children own it, one at a time
+
+``ops``: ``matvec``/``rmatvec``/``sq_rmatvec`` of both formulations at
+chip_smoke.py's fixed-effect shape against float64 NumPy (largest error
+relative to the largest entry of the answer), the first call's seconds and
+the median of five warm calls on the host's clock (dispatch, one
+``block_until_ready``; not kernel time).
+
+``training``: two one-device ``game_training_driver`` runs of chip_smoke.py's
+data that differ only in the formulation — so only in the order float32
+sums are taken — compared by chip_smoke.py's own compare child, once run to
+convergence (default flags, two sweeps) and once step for step (one sweep,
+the fixed effect stopped after chip_smoke's FOUR_CHIP_FIXED_ITERATIONS).
+It is how far apart float32 lets two correct runs stop on ONE device: the
+yardstick for the four-chip comparison's limits. Nothing is held to a limit
+here; every figure is printed, one JSON object per line.
+
+``--rehearse`` runs either mode at a tiny size (on the CPU both trainings
+run the plain formulation, so it only rehearses the control flow).
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, HERE)
+
+import chip_smoke  # noqa: E402  (jax-free)
+
+
+def _ops(sizes: dict, seed: int) -> None:
+    import jax
+    import numpy as np
+
+    from photon_tpu.data.batch import SparseFeatures
+
+    n = sizes["n_users"] * sizes["rows_per_user"]
+    k, dim = sizes["k_global"] + 1, sizes["d_global"] + 1
+    rng = np.random.default_rng([seed, 9])
+    idx = np.concatenate([                      # half head, half anywhere
+        rng.integers(0, sizes["d_head"], size=(n, k // 2)),
+        rng.integers(0, dim, size=(n, k - k // 2))], axis=1).astype(np.int32)
+    val = rng.normal(size=(n, k)).astype(np.float32)
+    w = rng.normal(size=dim).astype(np.float32)
+    v = rng.normal(size=n).astype(np.float32)
+    val64, flat = val.astype(np.float64), idx.ravel()
+    want = {
+        "matvec": (w.astype(np.float64)[idx] * val64).sum(1),
+        "rmatvec": np.bincount(
+            flat, (v.astype(np.float64)[:, None] * val64).ravel(), dim),
+        "sq_rmatvec": np.bincount(
+            flat, (v.astype(np.float64)[:, None] * val64 ** 2).ravel(), dim),
+    }
+    plain = SparseFeatures(idx=jax.device_put(idx), val=jax.device_put(val),
+                           dim=dim)
+    dev = jax.devices()[0]
+    out = {"mode": "ops", "device": dev.device_kind,
+           "platform": dev.platform, "shape": [n, k, dim]}
+    for name, feats in (("plain", plain), ("fast", plain.with_fast_path())):
+        for op, arg in (("matvec", w), ("rmatvec", v), ("sq_rmatvec", v)):
+            fn = jax.jit(lambda f, x, op=op: getattr(f, op)(x))
+            x = jax.device_put(arg)
+            t0 = time.monotonic()
+            got = fn(feats, x).block_until_ready()
+            first = time.monotonic() - t0
+            warm = []
+            for _ in range(5):
+                t0 = time.monotonic()
+                fn(feats, x).block_until_ready()
+                warm.append(time.monotonic() - t0)
+            err = np.abs(np.asarray(got, np.float64) - want[op]).max()
+            out[f"{name}.{op}"] = {
+                "max_err_rel_to_largest": float(err / np.abs(want[op]).max()),
+                "first_call_s": round(first, 3),
+                "median_warm_s": sorted(warm)[2]}
+    print(json.dumps(out), flush=True)
+
+
+def _training(sizes: dict, seed: int, out: str, platform: str) -> None:
+    def run(name, spec, on_chip=True, env=None):
+        return chip_smoke._run_child(name, spec, out, platform if on_chip
+                                     else "cpu", on_chip=on_chip, env=env)
+
+    data = run("data", {"seed": seed, "sizes": sizes,
+                        "dir": os.path.join(out, "data")}, on_chip=False)
+    for mode, sweeps, cut in (
+            ("converged", 2, None),
+            ("step_for_step", 1, chip_smoke.FOUR_CHIP_FIXED_ITERATIONS)):
+        dirs = {}
+        for kind, env in (("default", {}),
+                          ("plain", {"PHOTON_DISABLE_ACCEL_PATHS": "1"})):
+            name = f"{mode}_{kind}"
+            dirs[kind] = os.path.join(out, name)
+            rep = run(name, {"driver": "game_training_driver",
+                             "argv": chip_smoke._train_argv(
+                                 data["paths"], dirs[kind], sweeps, 1, cut)},
+                      env=env)
+            chip_smoke._check_device(rep, platform, rep["device"]["count"])
+            print(json.dumps({
+                "mode": "training", "run": name, "device": rep["device"],
+                "phase_seconds": rep["phase_seconds"],
+                "sparse_op_traces": rep["sparse_op_traces"],
+                **chip_smoke._check_training(
+                    rep, dirs[kind], sweeps, fixed_cut=cut is not None)}),
+                flush=True)
+        cmp_ = run(f"{mode}_compare", {
+            "kind": "compare", "a": dirs["default"], "b": dirs["plain"],
+            "coef_tol": float("inf"), "objective_tol": float("inf")},
+            on_chip=False)
+        print(json.dumps({"mode": "training", "compare": mode, **{
+            k: v for k, v in cmp_.items() if not k.endswith("_limit")}}),
+            flush=True)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("mode", choices=["ops", "training"])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=os.path.join(
+        HERE, "chip_smoke_out", "formulations"))
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+    sizes = chip_smoke.TINY if args.rehearse else chip_smoke.REAL
+    platform = "cpu" if args.rehearse else "tpu"
+    if args.mode == "ops":
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        _ops(sizes, args.seed)
+        return 0
+    os.makedirs(args.out, exist_ok=True)
+    try:
+        _training(sizes, args.seed, os.path.abspath(args.out), platform)
+    except chip_smoke.SmokeFailure as e:
+        print(f"sparse_formulation_check: FAILED: {e}", file=sys.stderr)
+        return 1
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,289 @@
+"""Ask the TPU's compiler, without a TPU, about every kernel chip_smoke.py's
+main path dispatches — at the smoke's real shapes, for a described v5e.
+
+The compiler is installed here and compiles for a chip that is described,
+not attached (guide on-chip-measurement §2, rehearsal 3). Nothing runs, so
+this says nothing about results or times; it says whether the chip would
+accept the program, which interpret-mode tests cannot. One file on purpose:
+the process that describes the topology holds the TPU library, and a second
+file could land on another xdist worker. The topology is described inside a
+fixture — never at import — so every worker collects the same tests.
+
+Shapes (chip_smoke.py REAL): fixed effect 2^17 rows x 32 nnz x 2^18
+features; random effect 8,192 users x 16 rows x 5 nnz in a 16-wide local
+subspace; serving micro-batch 64 rows x 128 nnz.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from photon_tpu.data.batch import LabeledBatch, SparseFeatures
+from photon_tpu.ops.fast_sparse import FastSparseAux
+
+N, K, D = 1 << 17, 32, 1 << 18          # fixed effect
+E, S, KU, PU = 8192, 16, 5, 16          # random-effect bucket
+CS_ROWS, Q = 3072, 2048                 # fast-path column table at that data
+SERVE_B, SERVE_K = 64, 128              # serving_driver defaults
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip; keep these out of it. And
+    # compile what the drivers compile: conftest turns x64 on for the
+    # golden tests, the drivers' float32 default leaves it off (with it on,
+    # the fast-path rmatvec at this shape asks the chip for 25.7 GB).
+    was_on = jax.config.jax_enable_compilation_cache
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    jax.config.update("jax_enable_x64", x64)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    return mesh, NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _problem(spec):
+    """The problem the smoke's ``--coordinate`` flag makes, as the jit key
+    ``GLMOptimizationProblem.fit`` builds from it."""
+    from photon_tpu.cli.params import parse_coordinate_spec
+    from photon_tpu.types import TaskType
+
+    c = parse_coordinate_spec(spec)
+    return c.optimization.with_reg_weight(c.reg_weights[0]).problem(
+        TaskType.LOGISTIC_REGRESSION)
+
+
+FIXED = "fixed:type=fixed,shard=global,reg=L2,reg_weights=1"
+PER_USER = "perUser:type=random,re_type=userId,shard=user,reg=L2,reg_weights=1"
+
+
+def _fixed_features(sh, fast: bool) -> SparseFeatures:
+    aux = FastSparseAux(
+        hi=_sds((N, K), "int16", sh), lo=_sds((N, K), "int8", sh),
+        cs_rhi=_sds((CS_ROWS, Q), "int16", sh),
+        cs_rlo=_sds((CS_ROWS, Q), "int8", sh),
+        cs_clo=_sds((CS_ROWS, Q), "int8", sh),
+        cs_val=_sds((CS_ROWS, Q), "float32", sh),
+        cs_range=_sds((CS_ROWS,), "int32", sh),
+        n_ranges=D // 128, n_row_blocks=N // 128,
+    ) if fast else None
+    return SparseFeatures(
+        idx=_sds((N, K), "int32", sh), val=_sds((N, K), "float32", sh),
+        dim=D, fast=aux)
+
+
+def _fixed_batch(sh, fast: bool) -> LabeledBatch:
+    return LabeledBatch(
+        features=_fixed_features(sh, fast),
+        labels=_sds((N,), "float32", sh), offsets=_sds((N,), "float32", sh),
+        weights=_sds((N,), "float32", sh))
+
+
+def _bucket(sh, entities: int):
+    """(batches, w0, local_mask) of one random-effect bucket."""
+    def a(*shape, dtype="float32"):
+        return _sds((entities,) + shape, dtype, sh)
+
+    batches = LabeledBatch(
+        features=SparseFeatures(idx=a(S, KU, dtype="int32"), val=a(S, KU),
+                                dim=PU),
+        labels=a(S), offsets=a(S), weights=a(S))
+    return batches, a(PU), a(PU)
+
+
+# ------------------------------------------------------ one chip, main path
+
+
+@pytest.mark.parametrize("op,vec_len", [
+    ("matvec", D), ("rmatvec", N), ("sq_rmatvec", N)])
+def test_default_sparse_path_compiles(one_chip, op, vec_len):
+    """The layouts ``with_accelerator_paths`` attaches on a TPU (the XLA
+    fast path) lower at 2^17 x 2^18 x 32."""
+    feats = _fixed_features(one_chip, fast=True)
+    assert feats._pallas_mode(jnp.float32) is None  # XLA formulation runs
+    vec = _sds((vec_len,), "float32", one_chip)
+    jax.jit(lambda f, x: getattr(f, op)(x)).lower(feats, vec).compile()
+
+
+def test_glm_fit_compiles(one_chip):
+    """One whole fixed-effect L-BFGS program over the fast-path batch."""
+    from photon_tpu.functions.problem import _fit_jitted
+
+    vec = _sds((D,), "float32", one_chip)
+    compiled = _fit_jitted.lower(
+        _problem(FIXED), _fixed_batch(one_chip, fast=True), vec, vec, None,
+        None, _sds((), "float32", one_chip)).compile()
+    # The [N, K, 128] row-slice temporaries must leave room on a 16 GB chip.
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
+
+
+# The solver the static router picks at the smoke's bucket (primal Newton)
+# compiles at the whole bucket; the other two tiers at one device's share of
+# it under the four-chip mesh. Compile time grows steeply with the entity
+# count (about 2 s at 256 entities, 6 s at 2,048, 60 s at 8,192 — ROADMAP
+# S6), so one full-size case is what the suite can afford.
+@pytest.mark.parametrize("solver,entities", [
+    ("fit_bucket_newton", E),
+    ("fit_bucket_newton_dual", E // 4),
+    ("fit_bucket_vmapped", E // 4),
+])
+def test_random_effect_bucket_solver_compiles(one_chip, solver, entities):
+    from photon_tpu.game.newton_re import (
+        fit_bucket_newton,
+        fit_bucket_newton_dual,
+    )
+    from photon_tpu.game.random_effect import _fit_bucket_jitted
+
+    problem = _problem(PER_USER)
+    batches, w0, mask = _bucket(one_chip, entities)
+    if solver == "fit_bucket_newton":
+        lowered = fit_bucket_newton.lower(problem, batches, w0, mask, None)
+    elif solver == "fit_bucket_newton_dual":
+        lowered = fit_bucket_newton_dual.lower(
+            problem, batches, w0, mask, None, 1)  # u_max 1: the intercept
+    else:
+        lowered = _fit_bucket_jitted.lower(
+            problem, batches, w0, mask, None, None)
+    lowered.compile()
+
+
+def test_additive_score_rows_compiles(one_chip):
+    """The serving kernel at the server's largest warmed micro-batch."""
+    from photon_tpu.estimators.game_transformer import additive_score_rows
+
+    def rows(k, dtype):
+        return _sds((SERVE_B, k), dtype, one_chip)
+
+    additive_score_rows.lower(
+        _sds((SERVE_B,), "float32", one_chip),
+        {"global": rows(SERVE_K, "int32"), "user": rows(SERVE_K, "int32")},
+        {"global": rows(SERVE_K, "float32"),
+         "user": rows(SERVE_K, "float32")},
+        {"fixed": _sds((D + 1,), "float32", one_chip)},
+        {"perUser": rows(PU, "int32")}, {"perUser": rows(PU, "float32")},
+        fixed_parts=(("fixed", "global"),), re_parts=(("perUser", "user"),),
+    ).compile()
+
+
+# --------------------------------------------- four chips, --devices 0 path
+
+
+def test_spmd_value_and_grad_compiles_on_four_devices(four_chips):
+    """The explicit shard_map + psum objective: one all-reduce of (value,
+    gradient) per evaluation over a described 2x2 v5e."""
+    from photon_tpu.parallel.spmd_objective import SpmdGLMObjective
+
+    mesh, rows, replicated = four_chips
+    spmd = SpmdGLMObjective(
+        obj=_problem(FIXED).objective(), batch=None, mesh=mesh)
+
+    def value_and_grad(w, batch):
+        return dataclasses.replace(spmd, batch=batch).value_and_grad(w)
+
+    compiled = jax.jit(value_and_grad).lower(
+        _sds((D,), "float32", replicated), _fixed_batch(rows, fast=False),
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+
+
+def test_fit_data_parallel_compiles_on_four_devices(four_chips):
+    """The default mesh path of the training driver (GSPMD): the whole
+    L-BFGS program with the batch row-sharded and coefficients replicated."""
+    from photon_tpu.parallel.data_parallel import _fit_dp_jitted
+
+    mesh, rows, replicated = four_chips
+    vec = _sds((D,), "float32", replicated)
+    compiled = _fit_dp_jitted.lower(
+        _problem(FIXED), replicated, _fixed_batch(rows, fast=False), vec,
+        vec, None, None).compile()
+    assert "all-reduce" in compiled.as_text()
+    # Each device holds a quarter of the rows, not all of them.
+    per_device_args = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device_args < (N * K * 8 + 3 * N * 4) / 4 + 3 * D * 4
+
+
+def test_entity_sharded_bucket_solver_compiles_on_four_devices(four_chips):
+    from photon_tpu.game.newton_re import fit_bucket_newton
+
+    _, rows, _ = four_chips
+    batches, w0, mask = _bucket(rows, E)
+    fit_bucket_newton.lower(
+        _problem(PER_USER), batches, w0, mask, None).compile()
+
+
+# ------------------------------------- what the compiler refuses (ROADMAP S1)
+
+
+@pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason="the TPU compiler's words: 'Unimplemented primitive in Pallas "
+           "TPU lowering for KernelType.TC: dynamic_slice' — the value-level "
+           "lax.dynamic_slice_in_dim in _gather_onehot_kernel.chunk")
+@pytest.mark.parametrize("op", ["rmatvec", "matvec"])
+def test_pallas_sparse_kernel_compiles(one_chip, op):
+    from photon_tpu.ops import pallas_sparse as ps
+
+    nb = ps.TABLE_SUBLANES[op]
+    total = 4 * nb
+    tables = ps._OpTables(
+        hi=_sds((total, 128), "int32", one_chip),
+        lo=_sds((total, 128), "int32", one_chip),
+        val=_sds((total, 128), "float32", one_chip),
+        chunk_group=_sds((total // ps.CHUNK,), "int32", one_chip),
+        n_groups=D // 128)
+    jax.jit(lambda t, v: ps._run_op(t, v, nb, False, False)).lower(
+        tables, _sds((nb, 128), "float32", one_chip)).compile()
+
+
+@pytest.mark.xfail(
+    strict=True,
+    reason="behind the first refusal, the kernel's design: 'Mosaic failed "
+           "to compile TPU kernel: Not implemented: Multiple source vregs "
+           "along gather dimension' — the hardware gather reads within one "
+           "8x128 register, not across a [2048, 128] or [4096, 128] table")
+def test_pallas_table_wide_gather_compiles(one_chip):
+    from jax.experimental import pallas as pl
+
+    from photon_tpu.ops.pallas_sparse import TABLE_SUBLANES
+
+    nb = TABLE_SUBLANES["matvec"]
+
+    def kernel(table_ref, idx_ref, out_ref):
+        out_ref[:] = jnp.take_along_axis(
+            table_ref[:], idx_ref[:], axis=0, mode="promise_in_bounds")
+
+    call = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((nb, 128), jnp.float32))
+    jax.jit(call).lower(
+        _sds((nb, 128), "float32", one_chip),
+        _sds((nb, 128), "int32", one_chip)).compile()

@@ -37,11 +37,15 @@ from photon_tpu.types import TaskType
 
 
 @pytest.fixture(autouse=True)
-def _isolated_store():
-    """Every test gets a clean store slot and leaves jax's persistent-cache
-    config exactly as it found it (configure() mutates process state)."""
+def _isolated_store(tmp_path):
+    """Every test gets a clean store slot and an EMPTY persistent cache of
+    its own (cold-vs-warm assertions need a cache nothing has written to),
+    and leaves jax's persistent-cache config exactly as it found it
+    (configure() mutates process state)."""
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xla"))
+    cs._reset_jax_cache_handle()
     yield
     cs.deactivate()
     cs.disarm_first_step_clock()
@@ -238,18 +242,20 @@ def test_checkpoint_carries_manifest_ref_and_prewarms(tmp_path):
     assert cs.prewarm_from_checkpoint(payload) is None
 
 
-def test_enable_compilation_cache_late_call_warns(tmp_path, caplog):
+def test_enable_compilation_cache_late_call_warns(tmp_path, caplog,
+                                                  monkeypatch):
     """Satellite: enabling the persistent cache AFTER the first compile
     used to be a silent no-op. It must now warn loudly (and re-initialize
     the cache handle so later compiles do persist)."""
-    from photon_tpu.cli.params import enable_compilation_cache
-
+    # conftest names the session's cache through the variable; a flag is
+    # only honoured without it.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     cs.note_compilation()  # this process has long since compiled something
-    with caplog.at_level(logging.WARNING, logger="photon_tpu.cli"):
-        enable_compilation_cache(str(tmp_path / "xla"))
+    with caplog.at_level(logging.WARNING, logger="photon_tpu.runtime"):
+        cs.enable_compilation_cache(str(tmp_path / "late"))
     assert any("AFTER this process already compiled" in r.message
                for r in caplog.records)
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "late")
 
 
 def test_explicit_off_pins_over_env(tmp_path, monkeypatch):

@@ -124,6 +124,24 @@ def test_dispatch_falls_back_off_tpu(monkeypatch):
     assert not sf._use_pallas(jnp.float64)
 
 
+def test_tpu_backend_never_runs_the_interpreter(monkeypatch):
+    """On a TPU backend explicitly attached tables dispatch the COMPILED
+    kernels whatever PHOTON_PALLAS_INTERPRET says (interpret mode is a CPU
+    test device), and the auto-attach gives them no tables at all."""
+    import jax
+
+    rng = np.random.default_rng(8)
+    idx, val = _random_ell(rng, 64, 40, 3)
+    plain = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), 40)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for flag in ("1", "0"):
+        monkeypatch.setenv("PHOTON_PALLAS_INTERPRET", flag)
+        assert plain.with_pallas_path()._pallas_mode(jnp.float32) is False
+        auto = plain.with_accelerator_paths()
+        assert auto.pallas is None and auto.fast is not None
+        assert auto._pallas_mode(jnp.float32) is None
+
+
 def test_over_budget_gracefully_skips(monkeypatch):
     """A dataset whose packed tables exceed the memory budget attaches NO
     Pallas tables (XLA fast path only), and matvec still works; re-attach on
@@ -321,10 +339,11 @@ def test_megadim_chunking_at_real_constants():
 
 
 def test_estimator_attaches_accelerator_paths(monkeypatch):
-    """Round-4 integration: on an accelerator backend the estimator attaches
-    the MXU layouts to fixed-effect batches automatically (drivers need no
-    layout knowledge), and the fit matches the plain-path fit. Backend
-    mocked to 'tpu' with the interpreter so the kernels execute on CPU."""
+    """On an accelerator backend the estimator attaches the XLA fast-path
+    layouts to fixed-effect batches automatically (drivers need no layout
+    knowledge) and never the Pallas tables — the TPU compiler refuses those
+    kernels (tests/test_chip_compile.py), and a mocked 'tpu' backend must
+    not reach the interpreter either. The fit matches the plain-path fit."""
     import jax
 
     from photon_tpu.estimators.config import (
@@ -375,5 +394,5 @@ def test_estimator_attaches_accelerator_paths(monkeypatch):
     got = est.fit(bundle, None, cfg)
     w_acc = np.asarray(got[0].model["fixed"].model.coefficients.means)
 
-    assert attached == {"pallas": True, "fast": True}
+    assert attached == {"pallas": False, "fast": True}
     np.testing.assert_allclose(w_acc, w_plain, rtol=0, atol=2e-3)
