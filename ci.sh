@@ -26,6 +26,13 @@ if [ "$(id -u)" = "0" ] && [ "$(cat /proc/sys/vm/max_map_count)" -lt 262144 ]; t
 fi
 python -m pytest tests/ -x -q
 
+echo "== pytest (the yardstick's own tests: benchmarks/tests, CPU) =="
+# The harness, the reference, the trace reduction and the per-layer
+# readers (the span-tree readers among them) are in no other selection
+# (ROADMAP D13). A run of its own: tests/ and benchmarks/tests each have a
+# conftest.py.
+JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
+
 if [[ "${1:-}" == "fast" ]]; then
   exit 0
 fi

@@ -42,6 +42,7 @@ from photon_tpu.data.validators import DataValidationType, sanity_check_data
 from photon_tpu.estimators import (
     GameEstimator,
     RandomEffectDataConfig,
+    fit_breakdown,
     select_best,
 )
 from photon_tpu.evaluation import EvaluationSuite
@@ -52,6 +53,7 @@ from photon_tpu.io.data_reader import (
     build_index_from_avro,
 )
 from photon_tpu.io.model_io import save_game_model
+from photon_tpu.obs import recent_trees
 from photon_tpu.types import TaskType
 from photon_tpu.utils import PhotonLogger, Timed, write_metrics_jsonl
 
@@ -659,6 +661,10 @@ def _run_inner(args, task) -> dict:
                     initial_model=initial_model,
                     checkpoint_manager=ckpt,
                 )
+            # Where the fit's seconds went, from its own span tree.
+            parts = fit_breakdown(recent_trees("estimator.fit", last=1)[0])
+            logger.info("fit %.2f s = %s", parts.pop("fit"), " + ".join(
+                f"{name} {seconds:.2f}" for name, seconds in parts.items()))
 
         suite = (
             EvaluationSuite.parse(args.evaluators) if args.evaluators else None

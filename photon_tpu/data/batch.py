@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu.obs.metrics import REGISTRY
+from photon_tpu.obs.trace import trace_span
 from photon_tpu.ops import pass_counter
 from photon_tpu.types import REAL_ACCELERATOR_BACKENDS
 
@@ -57,15 +58,18 @@ class DenseFeatures:
     def dim(self) -> int:
         return self.x.shape[1]
 
+    @jax.named_scope("sparse.matvec")
     def matvec(self, w: Array) -> Array:
         pass_counter.record("matvec")
         return self.x @ w
 
+    @jax.named_scope("sparse.rmatvec")
     def rmatvec(self, v: Array) -> Array:
         """Xᵀv — accumulate per-row coefficients ``v`` into feature space."""
         pass_counter.record("rmatvec")
         return self.x.T @ v
 
+    @jax.named_scope("sparse.sq_rmatvec")
     def sq_rmatvec(self, v: Array) -> Array:
         """(X∘X)ᵀv — for Hessian diagonals: Σᵢ vᵢ·xᵢⱼ²."""
         pass_counter.record("sq_rmatvec")
@@ -278,6 +282,7 @@ class SparseFeatures:
         ).inc(op=op, formulation=kind)
         return kind, interp
 
+    @jax.named_scope("sparse.matvec")
     def matvec(self, w: Array) -> Array:
         kind, interp = self._formulation("matvec", w.dtype)
         if kind == "pallas":
@@ -293,6 +298,7 @@ class SparseFeatures:
         w_ext = jnp.concatenate([w, jnp.zeros((1,), w.dtype)])
         return jnp.sum(w_ext[self.idx] * self.val, axis=-1)
 
+    @jax.named_scope("sparse.rmatvec")
     def rmatvec(self, v: Array) -> Array:
         kind, interp = self._formulation("rmatvec", v.dtype)
         if kind == "pallas":
@@ -309,6 +315,7 @@ class SparseFeatures:
         )
         return out[: self.dim]
 
+    @jax.named_scope("sparse.sq_rmatvec")
     def sq_rmatvec(self, v: Array) -> Array:
         kind, interp = self._formulation("sq_rmatvec", v.dtype)
         if kind == "pallas":
@@ -376,7 +383,19 @@ class LabeledBatch:
         if cache is not None and id(feats) in cache:
             attached = cache[id(feats)]
         else:
-            attached = feats.with_accelerator_paths()
+            # Around the call, not inside ops/fast_sparse.py: the span also
+            # covers reading idx/val back and placing the tables, and it
+            # is a span only of a build (off the accelerator, over the
+            # memory budget or already attached, the features come back
+            # as they went in).
+            with trace_span("data.accel_tables", cat="data",
+                            entries=feats.idx.size, dim=feats.dim) as span:
+                attached = feats.with_accelerator_paths()
+                if attached is feats:
+                    span.discard()
+                else:
+                    span.set(formulation="pallas" if attached.pallas
+                             is not None else "fast")
             if cache is not None:
                 cache[id(feats)] = attached
         if attached is feats:

@@ -13,6 +13,7 @@ from photon_tpu.estimators.game_estimator import (
     GameEstimator,
     GameFitResult,
     build_re_dataset_from_bundle,
+    fit_breakdown,
     select_best,
 )
 from photon_tpu.estimators.game_transformer import GameTransformer
@@ -29,5 +30,6 @@ __all__ = [
     "GameFitResult",
     "GameTransformer",
     "build_re_dataset_from_bundle",
+    "fit_breakdown",
     "select_best",
 ]

@@ -59,6 +59,12 @@ from photon_tpu.game.descent import (
     ValidationData,
 )
 from photon_tpu.io.data_reader import GameDataBundle
+from photon_tpu.obs import (
+    current_trace_id,
+    new_trace_id,
+    trace_context,
+    trace_span,
+)
 from photon_tpu.types import TaskType
 
 Array = jax.Array
@@ -208,7 +214,21 @@ class GameEstimator:
         completed configuration is snapshotted, and a fresh ``fit`` over the
         same inputs auto-resumes from the newest snapshot, reproducing the
         uninterrupted result bit-identically.
+
+        A fit is one request of the tracing system (docs/observability.md):
+        the ``estimator.fit`` span is the root of every span below it, they
+        share one trace id, and its finished tree is kept
+        (``obs.recent_trees("estimator.fit")``; :func:`fit_breakdown`).
         """
+        with trace_context(current_trace_id() or new_trace_id()), \
+                trace_span("estimator.fit", cat="estimator",
+                           rows=data.n_rows,
+                           configs=len(configs)).keep_tree():
+            return self._fit(data, validation_data, configs, initial_model,
+                             checkpoint_manager)
+
+    def _fit(self, data, validation_data, configs, initial_model,
+             checkpoint_manager) -> list[GameFitResult]:
         if not configs:
             raise ValueError("at least one GameOptimizationConfiguration required")
         for cfg in configs:
@@ -290,10 +310,12 @@ class GameEstimator:
 
                 clear_executable_caches(f"config boundary {i}")
             logger.info("=== configuration %d/%d ===", i + 1, len(configs))
-            coordinates = self._build_coordinates(
-                prep, cfg, config_index=i, initial_model=initial_model,
-                accel_cache=accel_cache,
-            )
+            with trace_span("estimator.build_coordinates", cat="estimator",
+                            config_index=i):
+                coordinates = self._build_coordinates(
+                    prep, cfg, config_index=i, initial_model=initial_model,
+                    accel_cache=accel_cache,
+                )
             descent = CoordinateDescent(
                 update_sequence=tuple(self.update_sequence),
                 n_sweeps=self.n_sweeps,
@@ -313,11 +335,10 @@ class GameEstimator:
                 extra_state={"completed_results": results},
             )
             descent_resume = None
-            evaluation = (
-                self._evaluate(model, validation, suite)
-                if validation is not None
-                else None
-            )
+            evaluation = None
+            if validation is not None:
+                with trace_span("estimator.evaluate", cat="estimator"):
+                    evaluation = self._evaluate(model, validation, suite)
             results.append(GameFitResult(model, evaluation, cfg, tracker))
             if checkpoint_manager is not None:
                 checkpoint_manager.save(
@@ -351,7 +372,10 @@ class GameEstimator:
             old_cache = cached[1].get("device_cache")
             if old_cache is not None:
                 old_cache.release()
-        prep = self._prepare(data)
+        with trace_span("estimator.prepare", cat="estimator",
+                        rows=data.n_rows,
+                        shards=len(data.features)):
+            prep = self._prepare(data)
         self._prep_cache = (data, prep)
         return prep
 
@@ -361,7 +385,9 @@ class GameEstimator:
         cached = getattr(self, "_validation_cache", None)
         if cached is not None and cached[0] is vdata and cached[1] == suite:
             return cached[2]
-        v = self._prepare_validation(vdata, suite)
+        with trace_span("estimator.prepare_validation", cat="estimator",
+                        rows=vdata.n_rows):
+            v = self._prepare_validation(vdata, suite)
         self._validation_cache = (vdata, suite, v)
         return v
 
@@ -646,6 +672,35 @@ class GameEstimator:
             validation.group_ids_by_column,
             validation.num_groups_by_column,
         )
+
+
+_BREAKDOWN_PARTS = {
+    "estimator.prepare": "prepare",
+    "estimator.prepare_validation": "prepare",
+    "data.accel_tables": "tables",
+    "descent.validate": "validate",
+    "estimator.evaluate": "validate",
+}
+
+
+def fit_breakdown(tree: Sequence[tuple]) -> dict[str, float]:
+    """Where one fit's seconds went, from its kept span tree
+    (``obs.recent_trees("estimator.fit")[-1]``), in this order: ``fit``
+    (the whole call), ``prepare`` (datasets and validation structures:
+    the first fit on a bundle only), ``tables`` (the fast-path layouts),
+    one entry per trained coordinate (its steps), ``validate``, and
+    ``descent``: what is left, the host work between them. The parts add
+    up to ``fit``; a part that took no time is left out."""
+    parts = {"prepare": 0.0, "tables": 0.0}
+    for name, _, _, start, end, args in tree:
+        part = (args.get("coordinate") if name == "descent.step"
+                else _BREAKDOWN_PARTS.get(name))
+        if part is not None:
+            parts[part] = parts.get(part, 0.0) + (end - start)
+    parts["validate"] = parts.pop("validate", 0.0)   # after the coordinates
+    fit = tree[-1][4] - tree[-1][3]
+    parts["descent"] = fit - sum(parts.values())
+    return {"fit": fit, **{k: v for k, v in parts.items() if v}}
 
 
 def select_best(

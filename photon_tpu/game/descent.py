@@ -125,6 +125,16 @@ class CoordinateDescent:
         ``load_latest`` whose position is fast-forwarded past. Resumed runs
         reproduce the uninterrupted run bit-identically.
         """
+        with trace_span("descent.run", cat="descent", sweeps=self.n_sweeps,
+                        coordinates=len(self.update_sequence)):
+            return self._run(
+                coordinates, n_rows, base_offsets, validation, suite,
+                initial_models, checkpointer, resume, step_base,
+                checkpoint_meta, extra_state)
+
+    def _run(self, coordinates, n_rows, base_offsets, validation, suite,
+             initial_models, checkpointer, resume, step_base,
+             checkpoint_meta, extra_state):
         for cid in self.update_sequence:
             if cid not in coordinates:
                 raise ValueError(f"update sequence names unknown coordinate {cid!r}")
@@ -213,8 +223,8 @@ class CoordinateDescent:
         rearm_sweep = None
         for sweep in range(self.n_sweeps):
             # Manual span, not ``with`` (the inner loop body is long): on a
-            # mid-sweep exception the sweep span is simply not emitted — the
-            # failing step span records the error for the timeline.
+            # mid-sweep exception it is still open when ``descent.run``
+            # exits, which ends it there with the error (obs/trace.py).
             sweep_span = trace_span("descent.sweep", cat="descent",
                                     sweep=sweep).__enter__()
             for ci, cid in enumerate(self.update_sequence):
@@ -325,26 +335,29 @@ class CoordinateDescent:
                     sweep, cid, dt,
                     convergence=_solver_outcome(solve_result))
                 if validation is not None:
-                    v_cache[cid] = validation.scorers[cid](model)
-                    v_scores = sum(v_cache.values())
-                    record.validation = suite.evaluate(
-                        validation.offsets + v_scores,
-                        validation.labels,
-                        validation.weights,
-                        validation.group_ids_by_column,
-                        validation.num_groups_by_column,
-                    )
-                    primary = record.validation.primary
-                    # Only a complete model (every coordinate trained at least
-                    # once) is eligible for best-model tracking — a partial
-                    # GameModel would break scoring downstream.
-                    complete = all(c in models for c in self.update_sequence)
-                    if complete and (
-                        best_metric is None
-                        or suite.primary.better_than(primary, best_metric)
-                    ):
-                        best_metric = primary
-                        best_models = dict(models)
+                    with trace_span("descent.validate", cat="descent",
+                                    coordinate=cid):
+                        v_cache[cid] = validation.scorers[cid](model)
+                        v_scores = sum(v_cache.values())
+                        record.validation = suite.evaluate(
+                            validation.offsets + v_scores,
+                            validation.labels,
+                            validation.weights,
+                            validation.group_ids_by_column,
+                            validation.num_groups_by_column,
+                        )
+                        primary = record.validation.primary
+                        # Only a complete model (every coordinate trained at
+                        # least once) is eligible for best-model tracking —
+                        # a partial GameModel would break scoring downstream.
+                        complete = all(
+                            c in models for c in self.update_sequence)
+                        if complete and (
+                            best_metric is None
+                            or suite.primary.better_than(primary, best_metric)
+                        ):
+                            best_metric = primary
+                            best_models = dict(models)
                     logger.info(
                         "sweep %d coord %s: %s (%.2fs)",
                         sweep, cid, record.validation, dt,

@@ -308,6 +308,7 @@ def dual_eligible(problem, bucket, normalization, u_max: int,
     return _dual_need_bytes(e_dev, s, p, u_max, esize) <= _budget_bytes()
 
 
+@jax.named_scope("newton.design")
 def _dense_design(batches, dtype):
     """Dense local design [E,S,P+1] via scatter-add — the ELL ghost column
     (== P) lands in the extra zero column. ONE buffer replaces per-probe
@@ -382,9 +383,10 @@ def _newton_loop(x0, z0, cfg, value_at, grad_at, hess_at, lin_map,
         x, z, f, g, reason, it, values, gnorms, passes, iters = st
         active = reason == NOT_CONVERGED
 
-        h = hess_at(x, z)
-        scale = 1.0 + jax.vmap(jnp.trace)(h) / t_dim
-        h_damped = h + (ridge * scale)[:, None, None] * eye
+        with jax.named_scope("newton.hessian"):
+            h = hess_at(x, z)
+            scale = 1.0 + jax.vmap(jnp.trace)(h) / t_dim
+            h_damped = h + (ridge * scale)[:, None, None] * eye
         # The damped Hessian is symmetric PD by construction, so a batched
         # Cholesky halves the factorization cost vs generic LU (measured
         # 2x on the [E,17,17] dual systems, CPU backend). Under --debug-nans
@@ -393,12 +395,13 @@ def _newton_loop(x0, z0, cfg, value_at, grad_at, hess_at, lin_map,
         # debug_nans would escalate to FloatingPointError on an otherwise
         # healthy run — LU returns a finite non-descent direction the same
         # guard handles. Trace-time read: the flag is process-static.
-        if jax.config.jax_debug_nans:
-            d = -jnp.linalg.solve(h_damped, g[..., None])[..., 0]
-        else:
-            chol = jnp.linalg.cholesky(h_damped)
-            d = -jax.scipy.linalg.cho_solve(
-                (chol, True), g[..., None])[..., 0]
+        with jax.named_scope("newton.solve"):
+            if jax.config.jax_debug_nans:
+                d = -jnp.linalg.solve(h_damped, g[..., None])[..., 0]
+            else:
+                chol = jnp.linalg.cholesky(h_damped)
+                d = -jax.scipy.linalg.cho_solve(
+                    (chol, True), g[..., None])[..., 0]
         dg = jnp.sum(d * g, axis=1)
         # H is PD(+ridge) so d is descent; a numerically non-descent lane —
         # including a failed factorization (NaN Cholesky of a lane whose
@@ -408,18 +411,20 @@ def _newton_loop(x0, z0, cfg, value_at, grad_at, hess_at, lin_map,
         d = jnp.where(bad[:, None], -g, d)
         dg = jnp.where(bad, -jnp.sum(g * g, axis=1), dg)
 
-        zd = lin_map(d)                                        # [E, S]
-        ft = probe_values(x, z, d, zd, ts)                     # [L, E]
-        armijo = jnp.isfinite(ft) & (ft <= f[None] + c1 * ts[:, None]
-                                     * dg[None])
-        any_ok = jnp.any(armijo, axis=0)
-        first = jnp.argmax(armijo, axis=0)                     # largest t
-        # No probe passes: smallest step that still decreases f (same
-        # terminal fallback as the streamed L-BFGS), else freeze the lane.
-        last = ft[-1]
-        salvage = (~any_ok) & jnp.isfinite(last) & (last < f)
-        t_pick = jnp.where(any_ok, ts[first],
-                           jnp.where(salvage, ts[-1], 0.0))
+        with jax.named_scope("newton.line_search"):
+            zd = lin_map(d)                                    # [E, S]
+            ft = probe_values(x, z, d, zd, ts)                 # [L, E]
+            armijo = jnp.isfinite(ft) & (ft <= f[None] + c1 * ts[:, None]
+                                         * dg[None])
+            any_ok = jnp.any(armijo, axis=0)
+            first = jnp.argmax(armijo, axis=0)                 # largest t
+            # No probe passes: smallest step that still decreases f (same
+            # terminal fallback as the streamed L-BFGS), else freeze the
+            # lane.
+            last = ft[-1]
+            salvage = (~any_ok) & jnp.isfinite(last) & (last < f)
+            t_pick = jnp.where(any_ok, ts[first],
+                               jnp.where(salvage, ts[-1], 0.0))
         stepped = active & (t_pick > 0.0)
 
         x_new = jnp.where(stepped[:, None], x + t_pick[:, None] * d, x)

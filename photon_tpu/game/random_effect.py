@@ -35,18 +35,14 @@ from photon_tpu.types import TaskType
 Array = jax.Array
 
 # Per-bucket record of the MOST RECENT train_random_effects call:
-# [{bucket, entities, entities_padded, rows, local_dim, solver,
-#   h2d_seconds, solve_seconds}]. Module-level on purpose — host_resident
-# streaming makes the H2D-vs-solve split the number that decides whether
-# bucket streaming is overhead-bound (VERDICT r4 ask #3's "per-bucket
-# H2D/solve timing"); the dress rehearsal and profiling scripts read it
-# after a fit without threading a collector through the estimator stack.
-# The sync-gated TIMING fields are populated only under PHOTON_RE_TIMINGS=1:
-# splitting H2D from solve needs two blocking device syncs per bucket, which
-# would serialize the transfer/compute overlap of every production sweep —
-# the solver-choice and compile/calibration fields cost nothing and are
-# always recorded (compile time is host-synchronous dispatch wall, no device
-# sync needed — see obs.retrace.compile_watch).
+# [{bucket, entities, entities_padded, row_slots, local_dim, solver, chunk,
+#   routing, compile_seconds, compile_by_solver, calibration_seconds,
+#   calibrated}]. Module-level on purpose: tests and bench.py read the
+# routing after a fit without threading a collector through the estimator
+# stack. Every field costs nothing (compile time is host-synchronous
+# dispatch wall, no device sync needed — see obs.retrace.compile_watch).
+# Seconds per bucket are the ``optim.re_bucket`` span's; completed compute
+# is ``descent.step``'s, which ends in the device-to-host read.
 LAST_BUCKET_TIMINGS: list = []
 
 # Process-global routing/compile counters (obs registry → /metrics): the
@@ -814,13 +810,9 @@ def train_random_effects(
     """
     from photon_tpu.data.normalization import project_context
 
-    import os as _os
-    import time as _time
-
     coefs_out, var_out, results = [], [], []
     want_var = problem.variance_type.name != "NONE"
     LAST_BUCKET_TIMINGS.clear()
-    _want_timings = _os.environ.get("PHOTON_RE_TIMINGS") == "1"
 
     # A shard lost earlier in this run degraded the mesh stickily; apply it
     # before any placement so this call never re-discovers the dead device.
@@ -830,7 +822,6 @@ def train_random_effects(
 
     for b_i, bucket in enumerate(dataset.buckets):
         orig_e = bucket.n_entities
-        _t_start = _time.perf_counter()
         if mesh is not None:
             axis_size = axes_size(mesh, entity_axis)
             bucket = _pad_bucket(bucket, axis_size, dataset.n_rows, dataset.global_dim)
@@ -871,18 +862,6 @@ def train_random_effects(
         # Placement now happens INSIDE _solve_bucket (full-bucket plans
         # place once; chunked plans slice host-side and fan each chunk's
         # device_put out per shard with the transfer double-buffered).
-
-        # H2D boundary: with host_resident buckets the arrays above are
-        # still host numpy; under PHOTON_RE_TIMINGS=1 force the transfer
-        # here (the tiny D2H fetch forces completion) to split per-bucket time
-        # into transfer vs solve. NOT default: the two syncs per bucket
-        # would serialize the async dispatcher's transfer/compute overlap.
-        # Mesh runs skip it: committing to the default device here would
-        # double-transfer everything the sharded placement re-puts.
-        if _want_timings and mesh is None:
-            batches = jax.tree.map(jnp.asarray, batches)
-            np.asarray(batches.features.val.ravel()[:1])
-        _t_h2d = _time.perf_counter()
 
         from photon_tpu.obs import trace_span as _trace_span
 
@@ -976,21 +955,13 @@ def train_random_effects(
         if want_var:
             var_out.append(models.coefficients.variances[:orig_e])
         results.append(jax.tree.map(lambda a: a[:orig_e], result))
-        if _want_timings:
-            np.asarray(coefs_out[-1][:1])  # completed-solve sync
-        _t_solve = _time.perf_counter()
         LAST_BUCKET_TIMINGS.append({
             "bucket": b_i,
             "entities": orig_e,
             "entities_padded": e,
             # SLOTS, not rows: [E, S] includes per-entity padding (weight-0
-            # rows). The true row count needs a reduction over weights, so
-            # it is computed only in sync-gated timing mode.
+            # rows); the true row count would need a reduction over weights.
             "row_slots": int(bucket.max_samples) * orig_e,
-            "rows": (
-                int(float(jnp.sum(bucket.weights[:orig_e] > 0)))
-                if _want_timings else None
-            ),
             "local_dim": p,
             "solver": info["solver"],
             "chunk": info["chunk"],
@@ -1003,17 +974,6 @@ def train_random_effects(
             "compile_by_solver": info.get("compile_by_solver", {}),
             "calibration_seconds": info["calibration_seconds"],
             "calibrated": info["calibrated"],
-            # Without the sync gate these splits would time async dispatch,
-            # not work — record them only when they mean something.
-            # ``solve_seconds`` is EXECUTION-only: the sync-gated wall minus
-            # the compile + calibration time measured above (BENCH schema
-            # note in docs/scaling.md).
-            "h2d_seconds": round(_t_h2d - _t_start, 3)
-            if _want_timings else None,
-            "solve_seconds": round(
-                max(0.0, (_t_solve - _t_h2d) - info["compile_seconds"]
-                    - info["calibration_seconds"]), 3)
-            if _want_timings else None,
         })
 
     model = RandomEffectModel(

@@ -46,6 +46,7 @@ from photon_tpu.obs.trace import (
     instant,
     new_trace_id,
     process_role,
+    recent_trees,
     set_process_role,
     start_tracing,
     stop_tracing,
@@ -74,6 +75,7 @@ __all__ = [
     "instant",
     "new_trace_id",
     "process_role",
+    "recent_trees",
     "retrace",
     "set_process_role",
     "start_tracing",

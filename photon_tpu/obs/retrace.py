@@ -102,8 +102,8 @@ class compile_watch:
     non-empty the dispatch wall time is (to enqueue overhead, microseconds)
     the compile time, and when it is empty the wall time is pure dispatch.
     This is how ``train_random_effects`` stamps ``compile_seconds`` into
-    ``LAST_BUCKET_TIMINGS`` / bench artifacts / trace spans WITHOUT the two
-    blocking device syncs per bucket that full timing mode needs.
+    ``LAST_BUCKET_TIMINGS`` / bench artifacts / trace spans without a
+    blocking device sync per bucket.
 
     ``cw.seconds`` — dispatch wall. ``cw.compiled`` — {kernel: new traces}
     for watched kernels that compiled inside the block. ``cw.compile_seconds``

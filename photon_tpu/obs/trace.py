@@ -24,6 +24,18 @@ Design constraints (docs/observability.md):
 * **One artifact.** Events buffer in memory (bounded) and
   :func:`stop_tracing` writes a single ``{"traceEvents": [...]}`` JSON
   object; ``scripts/obs_smoke.py`` validates the format in CI.
+* **Causality.** Every span records the span that was open on its thread
+  when it was entered (``parent_id``; ``span_id`` is taken at enter), so a
+  layer's self time — its span less what its children cover — can be
+  computed from a trace. A root span may ask for its finished tree to be
+  kept (:meth:`trace_span.keep_tree`): a bounded ring holds the last
+  trees as plain tuples, read back with :func:`recent_trees`, with no
+  collector installed. ``estimator.fit`` is the one root that asks.
+* **One clock with the device.** While a ``jax.profiler`` session is
+  recording, an open span also holds a ``jax.profiler.TraceAnnotation`` of
+  its name, so the program's spans lie in the profile's ``/host:CPU`` plane
+  beside the device planes. Nothing to switch on; a process that has not
+  imported ``jax`` is never made to (the serving front line's workers).
 * **Mergeable across processes.** Every collector stamps a
   :data:`ANCHOR_EVENT` metadata instant at install — the wall-clock ↔
   ``perf_counter`` correspondence plus pid/hostname/role — so
@@ -37,6 +49,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import zlib
@@ -52,6 +65,7 @@ __all__ = [
     "uninstall_tail_sampler",
     "tail_sampler",
     "trace_span",
+    "recent_trees",
     "instant",
     "process_role",
     "set_process_role",
@@ -483,7 +497,10 @@ class TailSampler:
             "dur": round(dur_s * 1e6, 1),
             "pid": os.getpid(),
             "tid": threading.get_ident() & 0xFFFFFFFF,
-            "args": {**a, "span_id": next(_span_ids)},
+            # A trace_span brings its own id (taken at enter, so children
+            # can name it); a bare ``complete`` call gets one here.
+            "args": a if "span_id" in a else {**a,
+                                              "span_id": next(_span_ids)},
         }
         span = _BufferedSpan(event)
         hit = False
@@ -690,6 +707,57 @@ class tracing:
             self.collector.write(self.path)
 
 
+# Kept span trees (docs/observability.md §"Kept trees"): the last roots that
+# asked (:meth:`trace_span.keep_tree`), oldest dropped first. Bounded three
+# ways so an always-on ring cannot grow host memory: roots held, spans held
+# in all, spans of one tree (a tree over its cap says so on its root).
+_KEPT_ROOTS = 256
+_KEPT_SPANS = 65_536
+_KEPT_SPANS_PER_TREE = 16_384
+_kept: deque = deque()          # (root name, [span tuples]), oldest first
+_kept_spans = 0
+_kept_lock = threading.Lock()
+
+
+def _keep(name: str, records: list) -> None:
+    global _kept_spans
+    with _kept_lock:
+        _kept.append((name, records))
+        _kept_spans += len(records)
+        while len(_kept) > 1 and (len(_kept) > _KEPT_ROOTS
+                                  or _kept_spans > _KEPT_SPANS):
+            _kept_spans -= len(_kept.popleft()[1])
+
+
+def recent_trees(root_name: str, last: Optional[int] = None) -> list:
+    """The kept trees whose root is named ``root_name``, oldest first (the
+    ``last`` of them, if given). A tree is the list of its finished spans
+    in the order they ended, the root last, each a tuple ``(name, span_id,
+    parent_id, start_s, end_s, args)``: seconds on this module's clock,
+    ``parent_id`` None on the root, ``args`` the span's own (with its
+    ``trace_id``, and ``error`` if an exception escaped it)."""
+    with _kept_lock:
+        trees = [records for name, records in _kept if name == root_name]
+    if last is None:
+        return trees
+    return trees[-last:] if last > 0 else []
+
+
+# ``jax.profiler.TraceAnnotation``, once this process has imported jax.
+_annotation = None
+
+
+def _find_annotation():
+    """The profiler's annotation class if jax is already imported here,
+    else None. Never imports jax: a span must not pull it into a process
+    that runs without it."""
+    global _annotation
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    # (None while jax is still being imported)
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
 class trace_span:
     """``with trace_span("descent.step", cat="descent", sweep=0) as sp:``
 
@@ -700,9 +768,18 @@ class trace_span:
     trace roots. ``sp.set(key=value)`` adds result attributes (iteration
     counts, row counts) before exit. An escaping exception is recorded as
     ``args["error"]``.
+
+    ``sp.span_id`` is taken at enter and ``sp.parent_id`` is the id of the
+    span then open on the same thread (None at a root); both go into the
+    event's ``args``. A span entered by hand and still open when an outer
+    span of its thread exits (an exception unwound past it) ends there,
+    with that exception as its error. While a ``jax.profiler`` session
+    records, the span is also a ``TraceAnnotation`` of the same name.
     """
 
-    __slots__ = ("name", "cat", "args", "trace_id", "seconds", "_t0")
+    __slots__ = ("name", "cat", "args", "trace_id", "seconds", "span_id",
+                 "parent_id", "_t0", "_keep", "_discarded", "_records",
+                 "_annotation")
 
     def __init__(self, name: str, cat: str = "app",
                  trace_id: Optional[str] = None, **args):
@@ -711,19 +788,70 @@ class trace_span:
         self.args = args
         self.trace_id = trace_id
         self.seconds = 0.0
+        self._keep = False
+        self._discarded = False
 
     def set(self, **args) -> "trace_span":
         self.args.update(args)
         return self
 
+    def keep_tree(self) -> "trace_span":
+        """Ask, before entering, that this span and every span entered
+        under it on this thread be kept when it ends, for
+        :func:`recent_trees`. (A kept root under another keeps a tree of
+        its own; the outer tree does not hold it.)"""
+        self._keep = True
+        return self
+
+    def discard(self) -> None:
+        """Call inside the span: it turned out to cover no work (a cache
+        hit found only by trying). It leaves the stack as usual and is
+        recorded nowhere but in a running profile, as a span of no
+        length to speak of."""
+        self._discarded = True
+
     def __enter__(self) -> "trace_span":
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _tls.stack = []
+        self.span_id = next(_span_ids)
+        if stack:
+            parent = stack[-1]
+            self.parent_id = parent.span_id
+            self._records = parent._records
+        else:
+            self.parent_id = None
+            self._records = None
+        if self._keep:
+            self._records = []
+        stack.append(self)
+        annotation = _annotation
+        if annotation is None and "jax" in sys.modules:
+            annotation = _find_annotation()
+        if annotation is not None and annotation.is_enabled():
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.seconds = time.perf_counter() - self._t0
+        t1 = time.perf_counter()
+        self.seconds = t1 - self._t0
+        stack = _tls.stack
+        if stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            while stack[-1] is not self:
+                stack[-1].__exit__(exc_type, exc, tb)
+            stack.pop()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         col = _ACTIVE
-        if col is None:
+        records = self._records
+        if (col is None and records is None) or self._discarded:
             return
         args = self.args
         tid = self.trace_id or current_trace_id()
@@ -731,8 +859,21 @@ class trace_span:
             args = {"trace_id": tid, **args}
         if exc_type is not None:
             args = {**args, "error": exc_type.__name__}
-        col.complete(self.name, self.cat, self._t0, self.seconds,
-                     {**args, "span_id": next(_span_ids)})
+        if records is not None:
+            full = len(records) >= _KEPT_SPANS_PER_TREE
+            if self._keep or not full:
+                if full:
+                    args = {**args, "truncated": True}
+                records.append((self.name, self.span_id, self.parent_id,
+                                self._t0 - _EPOCH, t1 - _EPOCH, args))
+            if self._keep:
+                _keep(self.name, records)
+        if col is not None:
+            ids = {"span_id": self.span_id}
+            if self.parent_id is not None:
+                ids["parent_id"] = self.parent_id
+            col.complete(self.name, self.cat, self._t0, self.seconds,
+                         {**args, **ids})
 
 
 def instant(name: str, cat: str = "event", **args) -> None:

@@ -74,6 +74,7 @@ def make_dot(axis_name=None):
     return lambda a, b: lax.psum(jnp.dot(a, b), axis_name)
 
 
+@jax.named_scope("lbfgs.direction")
 def two_loop_direction(g: Array, hist: LBFGSHistory, dot=jnp.dot) -> Array:
     """Compute −H·g via the standard two-loop recursion over the masked buffer.
 
@@ -117,6 +118,7 @@ def two_loop_direction(g: Array, hist: LBFGSHistory, dot=jnp.dot) -> Array:
     return -r
 
 
+@jax.named_scope("lbfgs.update")
 def update_history(
     hist: LBFGSHistory, s: Array, y: Array, dot=jnp.dot
 ) -> LBFGSHistory:
@@ -137,6 +139,7 @@ def update_history(
     return jax.tree.map(lambda a, b: jnp.where(ok, a, b), pushed, hist)
 
 
+@jax.named_scope("lbfgs.line_search")
 def armijo_backtrack(
     probe,
     f: Array,

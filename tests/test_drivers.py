@@ -6,6 +6,7 @@ datasets in a temp dir; assert outputs exist, parse, and metrics are sane.
 """
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -118,6 +119,13 @@ def test_training_and_scoring_drivers_end_to_end(game_data, tmp_path):
     assert os.path.exists(out / "models" / "0")
     assert os.path.exists(out / "index" / "global")
     assert os.path.exists(out / "photon.log")
+    # the fit's own account of its seconds, from its span tree
+    m = re.search(r"fit (\S+) s = (.*)", open(out / "photon.log").read())
+    terms = dict(t.rsplit(" ", 1) for t in m.group(2).split(" + "))
+    assert list(terms)[:1] == ["prepare"] and list(terms)[-1] == "descent"
+    assert {"fixed", "perUser", "validate"} <= set(terms)
+    assert sum(map(float, terms.values())) == pytest.approx(
+        float(m.group(1)), abs=0.01 * len(terms))
     metrics = [json.loads(l) for l in open(out / "metrics.jsonl")]
     assert len(metrics) == 2 * 2 * 2  # configs x sweeps x coordinates
     assert all("AUC" in m for m in metrics)
