@@ -372,32 +372,27 @@ class LabeledBatch:
     def add_to_offsets(self, scores: Array) -> "LabeledBatch":
         return dataclasses.replace(self, offsets=self.offsets + scores)
 
-    def with_accelerator_paths(self, cache: Optional[dict] = None) -> "LabeledBatch":
+    def with_accelerator_paths(self) -> "LabeledBatch":
         """Sparse features gain the MXU layouts (see
         ``SparseFeatures.with_accelerator_paths``); dense features no-op.
-        ``cache`` (id(features) -> attached features) lets config sweeps
-        reuse one host-side table build per distinct feature object."""
+        Every call that attaches is a host-side table build: a caller that
+        comes back to the same features keeps the result beside them
+        (``GameEstimator`` does, with its prepared bundle)."""
         feats = self.features
         if not hasattr(feats, "with_accelerator_paths"):
             return self
-        if cache is not None and id(feats) in cache:
-            attached = cache[id(feats)]
-        else:
-            # Around the call, not inside ops/fast_sparse.py: the span also
-            # covers reading idx/val back and placing the tables, and it
-            # is a span only of a build (off the accelerator, over the
-            # memory budget or already attached, the features come back
-            # as they went in).
-            with trace_span("data.accel_tables", cat="data",
-                            entries=feats.idx.size, dim=feats.dim) as span:
-                attached = feats.with_accelerator_paths()
-                if attached is feats:
-                    span.discard()
-                else:
-                    span.set(formulation="pallas" if attached.pallas
-                             is not None else "fast")
-            if cache is not None:
-                cache[id(feats)] = attached
+        # Around the call, not inside ops/fast_sparse.py: the span also
+        # covers reading idx/val back and placing the tables, and it is a
+        # span only of a build (off the accelerator, over the memory budget
+        # or already attached, the features come back as they went in).
+        with trace_span("data.accel_tables", cat="data",
+                        entries=feats.idx.size, dim=feats.dim) as span:
+            attached = feats.with_accelerator_paths()
+            if attached is feats:
+                span.discard()
+            else:
+                span.set(formulation="pallas" if attached.pallas
+                         is not None else "fast")
         if attached is feats:
             return self
         return dataclasses.replace(self, features=attached)

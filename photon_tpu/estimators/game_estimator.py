@@ -292,9 +292,6 @@ class GameEstimator:
 
         # Each config owns steps_per_config descent steps + 1 config-done slot.
         steps_per_config = self.n_sweeps * len(self.update_sequence)
-        # One host-side MXU-layout build per distinct feature object across
-        # the whole config sweep (id(features) -> attached features).
-        accel_cache: dict = {}
         for i, cfg in enumerate(configs):
             if i < start_config:
                 continue
@@ -311,10 +308,10 @@ class GameEstimator:
                 clear_executable_caches(f"config boundary {i}")
             logger.info("=== configuration %d/%d ===", i + 1, len(configs))
             with trace_span("estimator.build_coordinates", cat="estimator",
-                            config_index=i):
+                            config_index=i) as span:
                 coordinates = self._build_coordinates(
-                    prep, cfg, config_index=i, initial_model=initial_model,
-                    accel_cache=accel_cache,
+                    prep, cfg, config_index=i, span=span,
+                    initial_model=initial_model,
                 )
             descent = CoordinateDescent(
                 update_sequence=tuple(self.update_sequence),
@@ -362,16 +359,21 @@ class GameEstimator:
         """Per-bundle preparation cache (size 1, identity-keyed): repeated
         fits on the same bundle — hyperparameter tuning calls fit once per
         proposed config — reuse the datasets/statistics instead of
-        regrouping random effects every iteration."""
+        regrouping random effects every iteration, and the fast-path
+        tables of its fixed-effect shards (``_with_tables``) instead of
+        building them in every fit. All of it lives as long as the
+        estimator does, or until it prepares another bundle."""
         cached = getattr(self, "_prep_cache", None)
         if cached is not None and cached[0] is data:
             return cached[1]
         if cached is not None:
-            # New bundle: drop the old bundle's device pins (a tuning loop
+            # New bundle: drop the old bundle's device pins and fast-path
+            # tables before the new one is prepared (a tuning loop
             # switching datasets must not hold both residencies).
             old_cache = cached[1].get("device_cache")
             if old_cache is not None:
                 old_cache.release()
+            cached[1]["tables"].clear()
         with trace_span("estimator.prepare", cat="estimator",
                         rows=data.n_rows,
                         shards=len(data.features)):
@@ -395,7 +397,9 @@ class GameEstimator:
         """Build per-coordinate datasets + per-shard normalization ONCE."""
         from photon_tpu.data.device_cache import DeviceSweepCache
 
-        prep: dict = {"train": {}, "norm": {}, "batches": {}}
+        # "tables": shard -> the shard's features with their fast-path
+        # tables attached, filled by the first fit that builds them.
+        prep: dict = {"train": {}, "norm": {}, "batches": {}, "tables": {}}
         # One sweep cache per prepared bundle, shared across the whole
         # config sweep (same data ⇒ one upload for every λ). Mesh-attached:
         # pins shard over the entity axis (per-shard residency, per-device
@@ -435,14 +439,18 @@ class GameEstimator:
         prep: dict,
         cfg: GameOptimizationConfiguration,
         config_index: int,
+        span: trace_span,
         initial_model: Optional[GameModel] = None,
-        accel_cache: Optional[dict] = None,
     ) -> dict[str, Coordinate]:
+        """``span`` (the caller's ``estimator.build_coordinates``) is told
+        how many fixed effects took kept fast-path tables and how many
+        built theirs: ``tables_reused``, ``tables_built``."""
         # Coordinates are built for EVERY data config, not just the update
         # sequence: coordinates outside the sequence are scoring-only (locked
         # warm-start models — reference partial retraining) and use a default
         # problem that never runs.
         coordinates: dict[str, Coordinate] = {}
+        tables = {"reused": 0, "built": 0}
         for cid in self.coordinate_data_configs:
             dcfg = self.coordinate_data_configs[cid]
             ocfg = cfg.get(cid, GLMOptimizationConfiguration())
@@ -508,10 +516,13 @@ class GameEstimator:
                 if self.mesh is None:
                     # Single-device solve: attach the MXU-friendly sparse
                     # layouts (no-op off-accelerator; one host-side build
-                    # per distinct feature object across the sweep). Mesh
+                    # per prepared shard, kept with the bundle). Mesh
                     # runs shard rows, which the global tables cannot
                     # follow — those keep the shardable plain formulation.
-                    batch = batch.with_accelerator_paths(accel_cache)
+                    batch, how = self._with_tables(
+                        prep, dcfg.feature_shard, batch)
+                    if how is not None:
+                        tables[how] += 1
                 coordinates[cid] = FixedEffectCoordinate(
                     batch=batch,
                     problem=problem,
@@ -607,7 +618,38 @@ class GameEstimator:
                         if ocfg.down_sampling_rate >= 1.0 else None
                     ),
                 )
+        span.set(tables_reused=tables["reused"], tables_built=tables["built"])
         return coordinates
+
+    @staticmethod
+    def _with_tables(
+        prep: dict, shard: str, batch: LabeledBatch
+    ) -> tuple[LabeledBatch, Optional[str]]:
+        """``batch`` with the fast-path tables of its sparse features
+        attached, and where they came from: ``"reused"``, ``"built"`` or
+        None (no tables: off the accelerator, dense features, over the
+        ``PHOTON_ACCEL_AUX_BUDGET_GB`` guard).
+
+        The tables are a pure function of the feature object, so those of
+        a prepared shard are built once, by the first fit that needs them,
+        kept in ``prep["tables"]`` beside the batch they were built from
+        and dropped with it (``_prepare_cached``). A hit is an identity,
+        never a number: kept tables go only to the very feature object
+        ``prep["batches"][shard]`` holds (a down-sampled batch shares it —
+        sampling replaces the weights alone), and ``prep`` keeps that
+        object alive for as long as it keeps the tables, so no later object
+        can come by them through a reused ``id``. Any other feature object
+        builds inside the call and nothing of it outlives the call."""
+        prepared = batch.features is prep["batches"][shard].features
+        kept = prep["tables"].get(shard) if prepared else None
+        if kept is not None:
+            return dataclasses.replace(batch, features=kept), "reused"
+        attached = batch.with_accelerator_paths()
+        if attached is batch:
+            return batch, None
+        if prepared:
+            prep["tables"][shard] = attached.features
+        return attached, "built"
 
     def _prepare_validation(
         self,
@@ -687,7 +729,8 @@ def fit_breakdown(tree: Sequence[tuple]) -> dict[str, float]:
     """Where one fit's seconds went, from its kept span tree
     (``obs.recent_trees("estimator.fit")[-1]``), in this order: ``fit``
     (the whole call), ``prepare`` (datasets and validation structures:
-    the first fit on a bundle only), ``tables`` (the fast-path layouts),
+    the first fit on a bundle only), ``tables`` (the fast-path layouts:
+    the first fit on a bundle that needs them only),
     one entry per trained coordinate (its steps), ``validate``, and
     ``descent``: what is left, the host work between them. The parts add
     up to ``fit``; a part that took no time is left out."""

@@ -141,9 +141,12 @@ def test_every_child_lies_inside_its_parent(two_fits):
 
 
 def test_second_fit_on_the_same_bundle_prepares_nothing(two_fits):
+    """Datasets, validation structures and the fast-path tables are kept
+    with the prepared bundle (tests/test_kept_tables.py has the rest)."""
     second = set(_names(two_fits[1]))
     assert second == set(FIT_TREE) - {"estimator.prepare",
-                                      "estimator.prepare_validation"}
+                                      "estimator.prepare_validation",
+                                      "data.accel_tables"}
 
 
 def test_span_arguments_say_what_the_work_was(two_fits):
@@ -153,6 +156,10 @@ def test_span_arguments_say_what_the_work_was(two_fits):
     assert by_name["estimator.prepare"]["shards"] == 2
     assert by_name["data.accel_tables"]["entries"] == 72 * 5
     assert by_name["data.accel_tables"]["formulation"] == "fast"
+    build = [{s[NAME]: s[ARGS] for s in tree}["estimator.build_coordinates"]
+             for tree in two_fits]
+    assert [(b["tables_built"], b["tables_reused"]) for b in build] == [
+        (1, 0), (0, 1)]
     assert by_name["descent.run"] == {
         "trace_id": by_name["estimator.fit"]["trace_id"],
         "sweeps": 2, "coordinates": 2}
@@ -160,11 +167,11 @@ def test_span_arguments_say_what_the_work_was(two_fits):
 
 
 def test_fit_breakdown_adds_up_to_the_fit(two_fits):
-    for tree, has_prepare in zip(two_fits, (True, False)):
+    for tree, first in zip(two_fits, (True, False)):
         parts = fit_breakdown(tree)
         assert list(parts)[0] == "fit" and list(parts)[-1] == "descent"
-        assert ("prepare" in parts) == has_prepare
-        assert {"tables", "fixed", "perUser", "validate"} <= set(parts)
+        assert ("prepare" in parts) == ("tables" in parts) == first
+        assert {"fixed", "perUser", "validate"} <= set(parts)
         fit = parts.pop("fit")
         assert fit == pytest.approx(tree[-1][END] - tree[-1][START])
         assert sum(parts.values()) == pytest.approx(fit)
@@ -262,7 +269,7 @@ def test_off_the_accelerator_no_table_span():
     feats = ell_from_rows([(np.arange(3), np.ones(3))] * 4, 3)
     lb = LabeledBatch(feats, jnp.zeros(4), jnp.zeros(4), jnp.ones(4))
     with trace_span("test.tables_root", cat="test").keep_tree():
-        assert lb.with_accelerator_paths({}) is lb
+        assert lb.with_accelerator_paths() is lb
     assert _names(recent_trees("test.tables_root")[-1]) == ["test.tables_root"]
 
 
