@@ -52,16 +52,19 @@ class CoordinateStepRecord:
     coordinate_id: str
     seconds: float
     validation: Optional[EvaluationResults] = None
-    # Solver outcome of the step: {iterations, data_passes, reasons} — see
-    # ``_solver_outcome``.
+    # Solver outcome of the step: {iterations, data_passes, reasons}, and
+    # for a TRON step {hvp, cg_steps, rejected} — see ``_solver_outcome``.
     convergence: Optional[dict] = None
 
 
-def _solver_outcome(result) -> Optional[dict]:
+def _solver_outcome(result, tron_counters: Optional[dict] = None
+                    ) -> Optional[dict]:
     """Host-side summary of one step's ``OptimizerResult``(s): a fixed
     effect returns one, a random effect one per bucket with ``[E]`` leaves.
     ``reasons`` counts solves per convergence-reason name; ``iterations`` is
-    the longest solve; ``data_passes`` sums the on-device pass counters."""
+    the longest solve; ``data_passes`` sums the on-device pass counters. A
+    fixed effect solved by TRON adds that solver's own three counters
+    (``tron_counters``, as ``_tron_counters`` read them)."""
     from photon_tpu.optim.base import CONVERGENCE_REASON_NAMES
 
     results = result if isinstance(result, (list, tuple)) else [result]
@@ -78,7 +81,18 @@ def _solver_outcome(result) -> Optional[dict]:
             np.asarray(r.data_passes).sum() for r in results)),
         "reasons": {CONVERGENCE_REASON_NAMES[int(c)]: int(n)
                     for c, n in zip(codes, counts)},
+        **(tron_counters or {}),
     }
+
+
+def _tron_counters(result) -> dict:
+    """``{hvp, cg_steps, rejected}`` of a step solved by TRON (the
+    solver's own on-device counters, read to the host); empty for any other
+    solve, so that only a TRON step carries them."""
+    if getattr(result, "hvp", None) is None:
+        return {}
+    return {k: int(np.asarray(getattr(result, k)))
+            for k in ("hvp", "cg_steps", "rejected")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,6 +283,11 @@ class CoordinateDescent:
                             # new_score <- model <- solve forces the whole
                             # step — and is the commit gate above.
                             np.asarray(new_score[:1])
+                            # The step is done: what TRON counted on the
+                            # device goes on the span (no other solver's
+                            # step carries these arguments).
+                            tron_counters = _tron_counters(solve_result)
+                            step_span.set(**tron_counters)
                         total = new_total
                         scores[cid] = new_score
                         models[cid] = model
@@ -333,7 +352,7 @@ class CoordinateDescent:
 
                 record = CoordinateStepRecord(
                     sweep, cid, dt,
-                    convergence=_solver_outcome(solve_result))
+                    convergence=_solver_outcome(solve_result, tron_counters))
                 if validation is not None:
                     with trace_span("descent.validate", cat="descent",
                                     coordinate=cid):

@@ -80,6 +80,11 @@ class OptimizerResult:
     ``GLMObjective.bind_hvp_at`` hoists out of the CG loop explicitly; a test
     cross-checks this counter against a host-callback counter at the
     feature-op level (``ops/pass_counter.py``).
+
+    ``hvp``, ``cg_steps`` and ``rejected`` are TRON's own counters (the
+    Hessian-vector products of its CG solves, the CG steps, and the trial
+    steps the trust region refused), counted on the device where each
+    happens; an optimizer that has no such thing leaves them ``None``.
     """
 
     x: Array
@@ -90,6 +95,9 @@ class OptimizerResult:
     values: Array                # [max_iterations + 1] tracked objective values
     grad_norms: Array            # [max_iterations + 1] tracked gradient norms
     data_passes: Array           # int32 scalar — instrumented data-pass count
+    hvp: Optional[Array] = None        # int32 scalar — TRON only
+    cg_steps: Optional[Array] = None   # int32 scalar — TRON only
+    rejected: Optional[Array] = None   # int32 scalar — TRON only
 
     def reason_name(self) -> str:
         return CONVERGENCE_REASON_NAMES[int(self.converged_reason)]

@@ -12,6 +12,15 @@ the reference's ⟦HessianVectorAggregator⟧ — it is forward-over-reverse aut
 loop, inner CG, and the radius logic all live in nested ``lax.while_loop``s, so
 a full TRON solve is one XLA program (vs. one Spark job per CG step in the
 reference, SURVEY.md §3.4).
+
+What a solve says of itself (``docs/observability.md``): the device work
+lies in the named scopes ``tron.cg`` (the CG solve, with the margins and
+curvature hoisted to its head), ``tron.hvp`` (one Hessian-vector product,
+inside it), ``tron.trial`` (the objective and gradient at the trial point)
+and ``tron.radius`` (the radius update and the accept test); and the loop
+state counts, where each happens, the Hessian-vector products, the CG steps
+and the trial steps refused, which ``OptimizerResult`` carries beside
+``data_passes``.
 """
 from __future__ import annotations
 
@@ -53,9 +62,11 @@ def steihaug_cg(hvp, g: Array, delta: Array, max_iters: int, tol: Array,
                 dot=jnp.dot):
     """Truncated CG for H p = −g inside ‖p‖ ≤ delta.
 
-    Returns (p, Hp, n_hvp) — Hp is maintained incrementally so the caller can
-    compute the predicted reduction without another Hessian pass; n_hvp is the
-    number of Hessian-vector products performed (for pass accounting).
+    Returns (p, Hp, n_hvp, n_steps) — Hp is maintained incrementally so the
+    caller can compute the predicted reduction without another Hessian pass;
+    n_hvp is the number of Hessian-vector products performed (for pass
+    accounting), counted at the product, and n_steps the CG steps taken
+    (today one product a step, so the two are equal).
     ``dot`` abstracts the inner product (a psum-reduced one when vectors are
     shards over a mesh axis).
     """
@@ -67,20 +78,22 @@ def steihaug_cg(hvp, g: Array, delta: Array, max_iters: int, tol: Array,
         hp: Array     # H @ p
         rr: Array
         it: Array
+        n_hvp: Array
         done: Array
 
     r0 = -g
     init = CGState(
         p=jnp.zeros_like(g), r=r0, d=r0, hp=jnp.zeros_like(g),
         rr=dot(r0, r0), it=jnp.zeros((), jnp.int32),
-        done=jnp.zeros((), bool),
+        n_hvp=jnp.zeros((), jnp.int32), done=jnp.zeros((), bool),
     )
 
     def cond(st: CGState):
         return (~st.done) & (st.it < max_iters) & (jnp.sqrt(st.rr) > tol)
 
     def body(st: CGState) -> CGState:
-        hd = hvp(st.d)
+        with jax.named_scope("tron.hvp"):
+            hd = hvp(st.d)
         dhd = dot(st.d, hd)
         alpha = st.rr / jnp.where(dhd > 1e-30, dhd, 1.0)
         # Negative curvature or singular direction → walk to the boundary.
@@ -98,11 +111,11 @@ def steihaug_cg(hvp, g: Array, delta: Array, max_iters: int, tol: Array,
         d_new = r_new + beta * st.d
         return CGState(
             p=p_new, r=r_new, d=d_new, hp=hp_new, rr=rr_new,
-            it=st.it + 1, done=hit_boundary,
+            it=st.it + 1, n_hvp=st.n_hvp + 1, done=hit_boundary,
         )
 
     st = lax.while_loop(cond, body, init)
-    return st.p, st.hp, st.it
+    return st.p, st.hp, st.n_hvp, st.it
 
 
 class _LoopState(NamedTuple):
@@ -116,6 +129,9 @@ class _LoopState(NamedTuple):
     values: Array
     grad_norms: Array
     passes: Array   # int32 — instrumented data-pass counter
+    hvp: Array      # int32 — Hessian-vector products of the CG solves
+    cg_steps: Array  # int32 — CG steps
+    rejected: Array  # int32 — trial steps the trust region refused
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,6 +184,8 @@ class TRON(Optimizer):
             reason=jnp.asarray(NOT_CONVERGED, jnp.int32),
             gnorm0=gnorm0, values=values, grad_norms=gnorms,
             passes=jnp.asarray(2, jnp.int32),  # init fused value+grad
+            hvp=jnp.zeros((), jnp.int32), cg_steps=jnp.zeros((), jnp.int32),
+            rejected=jnp.zeros((), jnp.int32),
         )
 
         def cond(st: _LoopState):
@@ -176,35 +194,41 @@ class TRON(Optimizer):
         def body(st: _LoopState) -> _LoopState:
             gnorm = norm(st.g)
             cg_tol = 0.1 * gnorm
-            p, hp, n_hvp = steihaug_cg(
-                hvp_at(st.x), st.g, st.delta,
-                cfg.max_cg_iterations, cg_tol, dot=dot,
-            )
-            # Predicted reduction of the quadratic model: −(gᵀp + ½ pᵀHp).
-            pred = -(dot(st.g, p) + 0.5 * dot(p, hp))
-            x_try = st.x + p
-            f_try, g_try = value_and_grad(x_try)
-            actual = st.f - f_try
-            rho = actual / jnp.where(jnp.abs(pred) > 1e-30, pred, 1.0)
-            # A non-finite trial value must take the shrink branch.
-            rho = jnp.where(jnp.isfinite(f_try), rho, -jnp.inf)
+            with jax.named_scope("tron.cg"):
+                p, hp, n_hvp, n_cg = steihaug_cg(
+                    hvp_at(st.x), st.g, st.delta,
+                    cfg.max_cg_iterations, cg_tol, dot=dot,
+                )
+            with jax.named_scope("tron.trial"):
+                # Predicted reduction of the quadratic model:
+                # −(gᵀp + ½ pᵀHp).
+                pred = -(dot(st.g, p) + 0.5 * dot(p, hp))
+                x_try = st.x + p
+                f_try, g_try = value_and_grad(x_try)
+                actual = st.f - f_try
+                rho = actual / jnp.where(jnp.abs(pred) > 1e-30, pred, 1.0)
+                # A non-finite trial value must take the shrink branch.
+                rho = jnp.where(jnp.isfinite(f_try), rho, -jnp.inf)
 
-            pnorm = norm(p)
-            # LIBLINEAR radius update: shrink on poor agreement, halve on
-            # moderate, expand (bounded) on good.
-            delta = jnp.where(
-                rho < _ETA1,
-                jnp.maximum(_SIGMA1 * jnp.minimum(pnorm, st.delta), 1e-12),
-                jnp.where(
-                    rho < _ETA2,
-                    _SIGMA2 * st.delta,
-                    jnp.clip(_SIGMA3 * pnorm, st.delta, _SIGMA3 * st.delta),
-                ),
-            )
-            accept = rho > _ETA0
-            x_new = jnp.where(accept, x_try, st.x)
-            f_new = jnp.where(accept, f_try, st.f)
-            g_new = jnp.where(accept, g_try, st.g)
+            with jax.named_scope("tron.radius"):
+                pnorm = norm(p)
+                # LIBLINEAR radius update: shrink on poor agreement, halve
+                # on moderate, expand (bounded) on good.
+                delta = jnp.where(
+                    rho < _ETA1,
+                    jnp.maximum(
+                        _SIGMA1 * jnp.minimum(pnorm, st.delta), 1e-12),
+                    jnp.where(
+                        rho < _ETA2,
+                        _SIGMA2 * st.delta,
+                        jnp.clip(_SIGMA3 * pnorm, st.delta,
+                                 _SIGMA3 * st.delta),
+                    ),
+                )
+                accept = rho > _ETA0
+                x_new = jnp.where(accept, x_try, st.x)
+                f_new = jnp.where(accept, f_try, st.f)
+                g_new = jnp.where(accept, g_try, st.g)
 
             it = st.it + 1
             gnorm_new = norm(g_new)
@@ -231,6 +255,8 @@ class TRON(Optimizer):
                 # margin matvec for GLMs), hvp_passes per CG HVP, and 2 for
                 # the fused trial value+grad.
                 passes=st.passes + factory_passes + hvp_passes * n_hvp + 2,
+                hvp=st.hvp + n_hvp, cg_steps=st.cg_steps + n_cg,
+                rejected=st.rejected + (~accept).astype(jnp.int32),
             )
 
         st = lax.while_loop(cond, body, init)
@@ -240,4 +266,5 @@ class TRON(Optimizer):
             iterations=st.it, converged_reason=reason,
             values=st.values, grad_norms=st.grad_norms,
             data_passes=st.passes,
+            hvp=st.hvp, cg_steps=st.cg_steps, rejected=st.rejected,
         )

@@ -172,6 +172,10 @@ def fit_model_parallel(
         x=P(), value=P(), grad_norm=P(), iterations=P(),
         converged_reason=P(), values=P(), grad_norms=P(), data_passes=P(),
     )
+    if problem.optimizer_type == OptimizerType.TRON:
+        # TRON's own counters (None on the other optimizers' results).
+        res_specs = dataclasses.replace(
+            res_specs, hvp=P(), cg_steps=P(), rejected=P())
 
     norm_arrays = (norm_f, norm_s, norm_onehot)
 
