@@ -13,6 +13,9 @@ This module replaces both with formulations XLA compiles to vector/MXU code:
   entry fetches its 128-wide row slice (``w2[idx >> 7]`` — a contiguous-slice
   gather XLA vectorizes) and selects its lane with a fused
   ``where(lo == iota)`` reduction.  Measured ≈ 55 ms vs 150 ms.
+  ``matvec`` selects on the flat ``[rows*nnz, 128]`` array the gather writes: the
+  TPU tiles the last two dimensions (8, 128), so a ``[N, K, 128]`` view of it
+  is a physical copy whenever K is no multiple of 8 (76 pads to 80).
 
 * ``rmatvec`` reduction: **column-sorted one-hot matmul**.  Entries are
   pre-sorted (host-side, once — indices are static data) by column and grouped
@@ -35,6 +38,7 @@ Ghost-padding entries (column id == dim) are mapped to a zero row with value
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +47,11 @@ import numpy as np
 Array = jax.Array
 
 LANE = 128
+# The digit streams cover whole blocks of this many rows, so the flat
+# ``[rows*nnz]`` lane-select result is whole 1024-element tiles at any nnz:
+# off that grid the TPU compiler's ``[rows*nnz] -> [rows, nnz]`` reshape takes
+# minutes to compile (70 s at 1,002,640 x 8, 1 s at 1,003,520 x 8).
+ROW_PAD = 1024
 
 
 @jax.tree_util.register_dataclass
@@ -50,10 +59,12 @@ LANE = 128
 class FastSparseAux:
     """Static auxiliary layouts for the fast paths.
 
-    Row-major digit split (for matvec's row-slice gather):
-      ``hi[N, K]`` int16/int32 — column id >> 7 (ghost entries point at the
+    Row-major digit split (for matvec's row-slice gather), flat in the
+    order of ``val.ravel()`` so the program reshapes no index stream, over
+    ``Np`` rows (N rounded up to ``ROW_PAD``, the added rows all ghosts):
+      ``hi[Np*K]`` int16/int32 — column id >> 7 (ghost entries point at the
       zero row appended to the coefficient table; int16 when the block count
-      fits, halving that index stream's HBM traffic); ``lo[N, K]`` int8 —
+      fits, halving that index stream's HBM traffic); ``lo[Np*K]`` int8 —
       column & 127.
 
     Column-sorted table (for rmatvec's one-hot reduce): ``B`` rows of capacity
@@ -63,8 +74,8 @@ class FastSparseAux:
     ``cs_val`` is the feature value (0 in padding slots).
     """
 
-    hi: Array        # [N, K] int16 or int32 (see _digit_dtype)
-    lo: Array        # [N, K] int8
+    hi: Array        # [Np*K] int16 or int32 (see _digit_dtype)
+    lo: Array        # [Np*K] int8
     cs_rhi: Array    # [B, Q] int16 or int32
     cs_rlo: Array    # [B, Q] int8
     cs_clo: Array    # [B, Q] int8
@@ -99,12 +110,14 @@ def build_fast_aux(
     n_row_blocks = -(-n // LANE)
     n_col_blocks = -(-dim // LANE)
 
-    # Row-major digit split; ghost entries -> appended zero row of w table.
-    hi = (idx >> 7).astype(_digit_dtype(n_col_blocks))
-    lo = (idx & 127).astype(np.int8)
+    # Row-major digit split, flat; ghost entries, and the ghost rows that
+    # fill the stream to whole ROW_PAD blocks, -> appended zero row of w table.
     ghost = idx >= dim
-    hi[ghost] = n_col_blocks
-    lo[ghost] = 0
+    n_pad = -(-n // ROW_PAD) * ROW_PAD
+    hi = np.full((n_pad, k), n_col_blocks, _digit_dtype(n_col_blocks))
+    lo = np.zeros((n_pad, k), np.int8)
+    hi[:n] = np.where(ghost, n_col_blocks, idx >> 7)
+    lo[:n] = np.where(ghost, 0, idx & 127)
 
     # Column-sorted table.
     flat_col = idx.ravel()
@@ -144,8 +157,8 @@ def build_fast_aux(
             b += 1
 
     return FastSparseAux(
-        hi=jnp.asarray(hi),
-        lo=jnp.asarray(lo),
+        hi=jnp.asarray(hi.ravel()),
+        lo=jnp.asarray(lo.ravel()),
         cs_rhi=jnp.asarray(cs_rhi),
         cs_rlo=jnp.asarray(cs_rlo),
         cs_clo=jnp.asarray(cs_clo),
@@ -157,20 +170,28 @@ def build_fast_aux(
 
 
 def _lane_iota() -> Array:
-    return jax.lax.broadcasted_iota(jnp.int8, (1, 1, LANE), 2)
+    return jax.lax.broadcasted_iota(jnp.int8, (1, LANE), 1)
 
 
+@functools.partial(jax.jit, static_argnames="dim")
 def matvec_fast(aux: FastSparseAux, val: Array, w: Array, dim: int) -> Array:
-    """z[i] = Σ_k val[i,k] · w[idx[i,k]] via row-slice gather + lane select."""
+    """z[i] = Σ_k val[i,k] · w[idx[i,k]] via row-slice gather + lane select.
+
+    One program also where the caller runs op by op (the coordinate scorers):
+    eagerly every ``[entries, 128]`` intermediate below would be materialized.
+    """
     nblk = -(-dim // LANE)
     w2 = jnp.pad(w, (0, nblk * LANE - dim)).reshape(nblk, LANE)
     w2 = jnp.concatenate([w2, jnp.zeros((1, LANE), w.dtype)])  # ghost row
-    rows = w2[aux.hi]                                  # [N, K, 128]
-    sel = jnp.where(aux.lo[..., None] == _lane_iota(), rows, 0.0)
+    n, k = val.shape
+    rows = w2[aux.hi]                                  # [Np*K, 128]
+    sel = jnp.where(aux.lo[:, None] == _lane_iota(), rows, 0.0)
+    picked = jnp.sum(sel, axis=-1).reshape(-1, k)      # [Np, K]
     # Narrow-stored values (bfloat16 via with_value_dtype) upcast on load:
     # the accumulation stays in w's precision, only the HBM stream shrinks.
     valf = val.astype(jnp.promote_types(val.dtype, w.dtype))
-    return jnp.sum(jnp.sum(sel, axis=-1) * valf, axis=-1)
+    valf = jnp.pad(valf, ((0, picked.shape[0] - n), (0, 0)))
+    return jnp.sum(picked * valf, axis=-1)[:n]
 
 
 def rmatvec_fast(

@@ -1,5 +1,7 @@
 """Correctness of the MXU-friendly sparse fast paths (ops/fast_sparse.py)
 and the incremental-score L-BFGS variant, vs the generic implementations."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -273,3 +275,47 @@ def test_digit_dtype_narrows_and_results_match():
                          q_capacity=64)
     assert aux.hi.dtype == jnp.int16
     assert aux.cs_rhi.dtype == jnp.int16
+
+
+@pytest.mark.parametrize("value_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("nnz", [5, 8, 52, 75, 76])
+def test_matvec_fast_matches_float64_gather_at_row_width(nnz, value_dtype):
+    """The flat lane select against the plain gather in float64, at row
+    widths on and off a multiple of 8, an odd row count (72,309's kind),
+    ghost entries in some rows and values stored narrow."""
+    n, dim = 389, 3 * 128 + 77
+    rng = np.random.default_rng(nnz)
+    idx = np.full((n, nnz), dim, np.int32)
+    val = np.zeros((n, nnz), np.float32)
+    for i, count in enumerate(rng.integers(1, nnz + 1, size=n)):
+        count = nnz if i % 7 == 0 else count      # full rows and short ones
+        idx[i, :count] = rng.choice(dim, size=count, replace=False)
+        val[i, :count] = rng.normal(size=count)
+    assert (idx == dim).any()
+    sf = SparseFeatures(idx=jnp.asarray(idx), val=jnp.asarray(val),
+                        dim=dim).with_value_dtype(value_dtype)
+    aux = build_fast_aux(idx, np.asarray(sf.val), dim, q_capacity=64)
+    assert aux.hi.ndim == aux.lo.ndim == 1
+    w = np.random.default_rng(nnz + 1).normal(size=dim).astype(np.float32)
+    stored = np.asarray(sf.val.astype(jnp.float32), np.float64)
+    want = np.sum(stored * np.append(w.astype(np.float64), 0.0)[idx], axis=1)
+    got = matvec_fast(aux, sf.val, jnp.asarray(w), dim)
+    assert got.shape == (n,) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
+def test_matvec_fast_lowers_with_no_rank3_row_slices():
+    """At glm_fit's shape the gather writes ``[rows*nnz, 128]`` and the
+    lane select reads it as written: no ``[rows, nnz, 128]`` value (a
+    physical copy on the TPU, whose tiles pad 76 to 80) is in the program."""
+    n, k, dim = 65536, 76, 47237
+    aux = build_fast_aux(np.full((8, k), dim), np.zeros((8, k), np.float32),
+                         dim, q_capacity=8)
+    flat = jax.ShapeDtypeStruct((n * k,), jnp.int16)
+    aux = dataclasses.replace(
+        aux, hi=flat, lo=jax.ShapeDtypeStruct((n * k,), jnp.int8))
+    text = matvec_fast.lower(
+        aux, jax.ShapeDtypeStruct((n, k), jnp.float32),
+        jax.ShapeDtypeStruct((dim,), jnp.float32), dim).as_text()
+    assert f"tensor<{n * k}x128xf32>" in text
+    assert f"tensor<{n}x{k}x128x" not in text
