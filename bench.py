@@ -62,8 +62,6 @@ if SMOKE:
 # details artifact. None = no SLO judgment.
 SLO_CONFIG = None
 
-from photon_tpu.types import REAL_ACCELERATOR_BACKENDS
-
 N_ROWS, DIM, K, MAX_ITER = SMOKE_SHAPES if SMOKE else (1 << 19, 1 << 18, 32, 40)
 
 # Spark-cluster baseline model parameters (BASELINE.md §"Baseline model").
@@ -214,32 +212,6 @@ def measured_hbm_bandwidth() -> float:
 
 # ---------------------------------------------------------------- workloads
 
-def _pallas_kernels_work() -> bool:
-    """True iff the Pallas sparse kernels compile AND execute here."""
-    import jax
-
-    if jax.default_backend() not in REAL_ACCELERATOR_BACKENDS:
-        return False
-    try:
-        import jax.numpy as jnp
-
-        from photon_tpu.ops.pallas_sparse import build_pallas_aux, matvec_pallas
-
-        rng = np.random.default_rng(0)
-        idx = rng.integers(0, 256, size=(128, 4)).astype(np.int32)
-        val = rng.normal(size=(128, 4)).astype(np.float32)
-        aux = build_pallas_aux(idx, val, 256)
-        z = np.asarray(matvec_pallas(aux, jnp.ones(256, jnp.float32)))
-        ref = val.sum(axis=1)
-        return bool(np.allclose(z, ref, atol=1e-4))
-    except Exception as e:  # noqa: BLE001 - any failure means "don't use"
-        import sys
-
-        print(f"pallas probe failed ({type(e).__name__}: {e}); XLA path",
-              file=sys.stderr, flush=True)
-        return False
-
-
 def _live_backend() -> str:
     """Per-metric backend stamp (VERDICT r4 weak #6/#7): a cpu-fallback
     artifact's roofline/race figures LOOK like chip numbers unless the
@@ -319,7 +291,7 @@ def bench_fixed_effect_lbfgs(resume_head=None):
 
     # The headline stage solves ONLY the light-compile gather path: the
     # heavy one-hot MXU compile of the fast path is the costliest of the
-    # run, so the fast/Pallas compiles run as the LAST bench stage
+    # run, so the fast compile runs as the LAST bench stage
     # (``race`` below, invoked after every other stage has banked).
     # The headline is whichever path is fastest — a kernel must EARN its
     # place, not win by compiling. PHOTON_BENCH_SKIP_FAST=1 skips the race
@@ -348,7 +320,7 @@ def bench_fixed_effect_lbfgs(resume_head=None):
         del base  # free ~128 MB of device memory before the middle stages
 
     def race(on_better):
-        """Fast + Pallas solves; calls ``on_better(head)`` after each path
+        """The fast solve; calls ``on_better(head)`` after it
         so a death mid-race still leaves the faster-so-far banked.
         Device arrays are (re)built HERE from the host arrays, not captured:
         the closure outlives every intermediate stage (game_scale is sized
@@ -364,16 +336,6 @@ def bench_fixed_effect_lbfgs(resume_head=None):
                 state["backend"] = _live_backend()
             on_better(head(*state["best"], state["path"], timings,
                            state["backend"]))
-        if _pallas_kernels_work() and "pallas_seconds" not in timings:
-            sf = base.with_pallas_path()
-            if sf.pallas is not None:  # attach can no-op over table budget
-                dtp, itp, pap = solve(sf)
-                timings["pallas_seconds"] = round(dtp, 3)
-                if dtp < state["best"][0]:
-                    state["best"], state["path"] = (dtp, itp, pap), "pallas"
-                    state["backend"] = _live_backend()
-                on_better(head(*state["best"], state["path"], timings,
-                               state["backend"]))
 
     return (
         head(*state["best"], state["path"], timings, state["backend"]),
@@ -3358,7 +3320,7 @@ def main():
         return {}
 
     # ALL heavy compiles are corralled into the final race: the GAME /
-    # game_scale / tuner stages auto-attach MXU/Pallas layouts at call time
+    # game_scale / tuner stages auto-attach the fast layouts at call time
     # (with_accelerator_paths reads the env), and those compiles are the
     # same hazard class that has twice killed a recovery window. The middle
     # stages therefore run light-compile formulations unconditionally; the

@@ -28,6 +28,7 @@ reference's per-partition iteration just not seeing absent rows).
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Optional, Union
 
 import jax
@@ -40,7 +41,9 @@ from photon_tpu.types import REAL_ACCELERATOR_BACKENDS
 
 Array = jax.Array
 
-_WARNED_PALLAS_F64 = False
+# The ``fast`` tables cost 20 B an entry on the device beside the ELL arrays'
+# 8 B; ``SparseFeatures.with_accelerator_paths`` attaches none over this.
+ACCEL_TABLE_BUDGET_BYTES = 4e9
 
 
 @jax.tree_util.register_dataclass
@@ -87,25 +90,21 @@ class SparseFeatures:
     ``idx[N, K]`` holds column ids in [0, D]; id == D marks padding (its value
     must be 0). ``dim`` (static) is the true feature dimension D.
 
-    ``fast`` (optional, see ``ops/fast_sparse.py``) carries precomputed
-    MXU-friendly layouts; when present, matvec/rmatvec take the fast path
-    (row-slice gather + one-hot reduce) instead of XLA's slow generic
-    gather/scatter lowering. Attach with ``with_fast_path()``.
-
-    ``pallas`` (optional, see ``ops/pallas_sparse.py``) carries the Pallas
-    slot tables; when attached, f32 matvec/rmatvec on a TPU backend run as
-    the hand-written kernels, compiled — never interpreted. Attach only
-    explicitly with ``with_pallas_path()`` (the TPU compiler does not accept
-    the kernels today, so nothing attaches them by default); off-TPU the
-    XLA paths are used unless ``PHOTON_PALLAS_INTERPRET=1`` sends the
-    kernels through the Pallas interpreter (CPU tests only).
+    ``fast`` (optional) is the one table object the features may carry, and
+    the one seam of the sparse pass: each op runs the ``fast`` formulation
+    when it is there (``ops/fast_sparse.py``: row-slice gather + one-hot
+    reduce over precomputed layouts) and the ``plain`` one when it is not
+    (gather / ``segment_sum``, inline below; also the reference the tests
+    hold ``fast`` to). ``with_accelerator_paths`` decides whether to attach
+    it; ``with_fast_path()`` attaches it whatever the platform. A further
+    formulation is another table type behind this field and one more arm an
+    op.
     """
 
     idx: Array
     val: Array
     dim: int = dataclasses.field(metadata=dict(static=True))
     fast: Optional[object] = None
-    pallas: Optional[object] = None
 
     @property
     def n_rows(self) -> int:
@@ -137,80 +136,46 @@ class SparseFeatures:
             )
         return dataclasses.replace(self, fast=aux)
 
-    def with_pallas_path(self) -> "SparseFeatures":
-        """Build the Pallas slot tables (host-side, once) and attach them,
-        plus the XLA fast path that serves off-TPU. Large datasets chunk
-        (512K-row / 256K-feature table slices); no-op (XLA fast path only)
-        if the packed tables would blow the device-memory budget."""
-        from photon_tpu.ops.pallas_sparse import build_pallas_aux
-
-        out = self.with_fast_path()
-        if out.pallas is not None:
-            return out
-        try:
-            aux = build_pallas_aux(
-                jax.device_get(self.idx), jax.device_get(self.val), self.dim
-            )
-        except ValueError:  # over the table-memory budget
-            return out
-        return dataclasses.replace(out, pallas=aux)
-
     def with_accelerator_paths(self) -> "SparseFeatures":
-        """Attach the MXU-friendly XLA layouts (``with_fast_path``) where
-        they can actually win: accelerator backend + unsharded features
-        (row-sharding drops them — the column-sorted tables are not
-        partitionable along rows). The estimator/transformer call this so
-        driver-trained models run the fast formulation on TPU without
-        callers knowing about layouts; off-accelerator this is a no-op
-        (XLA's plain CPU lowerings beat the fast-path formulations there,
-        and the host-side table builds are pure overhead).
-
-        The Pallas tables are never attached here: the TPU compiler refuses
-        the kernels as written (``ops/pallas_sparse.py`` module doc, ROADMAP
-        S1), and a default path must be one the chip accepts. They stay
-        reachable through an explicit ``with_pallas_path()``."""
-        import os
-
-        import jax
-
+        """The one place that decides which formulation a program holds:
+        attach the ``fast`` tables (``with_fast_path``) on an accelerator
+        backend, when the tables fit the byte budget; else return the
+        features as they are, on ``plain``. Off the accelerator XLA's CPU
+        lowerings of ``plain`` beat the ``fast`` formulation and the
+        host-side table build is pure overhead. The third thing the code
+        observes, a mesh, is seen where the rows are distributed:
+        ``parallel/mesh.py`` ``strip_unshardable_aux`` takes the tables off
+        again, because the column table does not shard by rows. The
+        estimator, the transformer and the training driver call this, so
+        no caller knows about layouts."""
         if jax.default_backend() not in REAL_ACCELERATOR_BACKENDS:
             return self
         if os.environ.get("PHOTON_DISABLE_ACCEL_PATHS") == "1":
-            # Operator kill switch: the fast path's one-hot MXU program is
-            # the heaviest compile of a fixed-effect solve. Disables every
-            # AUTO-attach (drivers/estimators route through here); code
-            # that calls with_fast_path()/with_pallas_path() explicitly —
-            # e.g. bench.py's sparse race — honors the same variable at its
-            # own call site, keeping explicit requests explicit.
+            # The ``plain`` reference on a chip: ``chip_smoke.py --chips 4``
+            # sets it on the one-device side of its comparison, whose other
+            # side is a mesh and so on ``plain`` too.
             return self
-        # HBM guard: the layouts cost ~20 bytes/entry on device on top of
-        # the 8 bytes/entry ELL data. At config-5 scale (1.3e9 entries)
-        # they would crowd out the batch itself; past the budget the solve
-        # keeps the plain formulation (and P3/row sharding remain the
-        # intended scale paths). Tunable: PHOTON_ACCEL_AUX_BUDGET_GB.
         entries = int(self.idx.shape[0]) * int(self.idx.shape[1])
-        budget_gb = float(os.environ.get("PHOTON_ACCEL_AUX_BUDGET_GB", "4"))
-        if 20 * entries > budget_gb * 1e9:
+        if 20 * entries > ACCEL_TABLE_BUDGET_BYTES:
             return self
         vd = os.environ.get("PHOTON_VALUE_DTYPE")
         if vd is not None and jnp.dtype(vd) != jnp.dtype(self.val.dtype):
-            # Opt-in narrow value storage (e.g. PHOTON_VALUE_DTYPE=bfloat16):
-            # ~27% less hot-loop HBM traffic; see with_value_dtype. Tables
-            # build in f32 first, then storage casts.
+            # Opt-in narrow value storage (e.g. PHOTON_VALUE_DTYPE=bfloat16;
+            # see with_value_dtype). Tables build in f32 first, then storage
+            # casts.
             return self.with_fast_path().with_value_dtype(vd)
         return self.with_fast_path()
 
     def with_value_dtype(self, dtype) -> "SparseFeatures":
         """Store feature VALUES in a narrower dtype (e.g. ``jnp.bfloat16``).
 
-        The fused GLM pass is HBM-bound and values are 8 B of its 15 B
-        per-entry stream (with int16 digit splits; 19 B at int32), so
-        bfloat16 storage cuts hot-loop traffic ~27% on TPU; the ops upcast
-        on load and accumulate in the operand precision, so only storage
-        narrows. One-hot / binary / small-integer features are EXACT in
-        bfloat16; continuous features round to 8 mantissa bits — opting in
-        accepts that quantization. The Pallas tables are f32-only and are
-        dropped; the XLA fast path's column table is re-cast to match.
+        The ops upcast on load and accumulate in the operand precision, so
+        only storage narrows: 2 B of the 4 B a stored value takes, in ``val``
+        and in the column table, which is re-cast to match. What that buys
+        a fit on the chip is not measured (the benchmark uses this path as
+        its failing control, PERF.md §2). One-hot / binary / small-integer
+        features are EXACT in bfloat16; continuous features round to 8
+        mantissa bits — opting in accepts that quantization.
         """
         dt = jnp.dtype(dtype)
         if jnp.dtype(self.val.dtype) == dt:
@@ -223,73 +188,32 @@ class SparseFeatures:
                     out.fast, cs_val=out.fast.cs_val.astype(dt)
                 ),
             )
-        if out.pallas is not None and dt != jnp.float32:
-            out = dataclasses.replace(out, pallas=None)
         return out
 
     def without_fast_path(self) -> "SparseFeatures":
-        """Drop the fast/pallas layouts (e.g. before row-sharding: the
-        column-sorted tables are not partitionable along the row axis)."""
-        if self.fast is None and self.pallas is None:
+        """Drop the ``fast`` tables (e.g. before row-sharding: the
+        column-sorted table is not partitionable along the row axis)."""
+        if self.fast is None:
             return self
-        return dataclasses.replace(self, fast=None, pallas=None)
+        return dataclasses.replace(self, fast=None)
 
-    def _pallas_mode(self, dtype) -> Optional[bool]:
-        """None = don't use the kernels; else the ``interpret`` flag."""
-        import os
-
-        if self.pallas is None:
-            return None
-        if jnp.dtype(dtype) != jnp.float32:
-            # The slot-table kernels are f32-only; --dtype float64 runs must
-            # not silently think they are on the Pallas path (VERDICT r3
-            # weak #5) — say so once, then use the XLA fast path.
-            global _WARNED_PALLAS_F64
-            if not _WARNED_PALLAS_F64:
-                _WARNED_PALLAS_F64 = True
-                import logging
-
-                # warning, not info: without a configured handler INFO is
-                # dropped and the downgrade would stay silent for direct
-                # estimator-API users.
-                logging.getLogger("photon_tpu.ops").warning(
-                    "Pallas tables attached but operand dtype is %s; the "
-                    "kernels are float32-only — using the XLA fast path",
-                    jnp.dtype(dtype),
-                )
-            return None
-        if jax.default_backend() in REAL_ACCELERATOR_BACKENDS:
-            return False  # on the chip the kernels compile or fail loudly
-        return (True if os.environ.get("PHOTON_PALLAS_INTERPRET") == "1"
-                else None)
-
-    def _use_pallas(self, dtype) -> bool:
-        return self._pallas_mode(dtype) is not None
-
-    def _formulation(self, op: str, dtype) -> tuple:
-        """``(kind, interpret)``: which formulation ``op`` puts into the
-        program being traced here — "pallas" (with its interpret flag),
-        "fast" or "plain". Counted once per trace (or eager call) in
+    def _formulation(self, op: str) -> str:
+        """Which formulation ``op`` puts into the program being traced
+        here: ``"fast"`` when the tables are attached, else ``"plain"``.
+        Counted once per trace (or eager call) in
         ``sparse_op_traces_total{op, formulation}``, so a run can say what
         its programs really hold, not what a look-alike batch would get."""
         pass_counter.record(op)
-        interp = self._pallas_mode(dtype)
-        kind = ("pallas" if interp is not None
-                else "fast" if self.fast is not None else "plain")
+        kind = "fast" if self.fast is not None else "plain"
         REGISTRY.counter(
             "sparse_op_traces_total",
             "sparse feature ops traced into programs, by formulation",
         ).inc(op=op, formulation=kind)
-        return kind, interp
+        return kind
 
     @jax.named_scope("sparse.matvec")
     def matvec(self, w: Array) -> Array:
-        kind, interp = self._formulation("matvec", w.dtype)
-        if kind == "pallas":
-            from photon_tpu.ops.pallas_sparse import matvec_pallas
-
-            return matvec_pallas(self.pallas, w, interpret=interp)
-        if kind == "fast":
+        if self._formulation("matvec") == "fast":
             from photon_tpu.ops.fast_sparse import matvec_fast
 
             return matvec_fast(self.fast, self.val, w, self.dim)
@@ -300,12 +224,7 @@ class SparseFeatures:
 
     @jax.named_scope("sparse.rmatvec")
     def rmatvec(self, v: Array) -> Array:
-        kind, interp = self._formulation("rmatvec", v.dtype)
-        if kind == "pallas":
-            from photon_tpu.ops.pallas_sparse import rmatvec_pallas
-
-            return rmatvec_pallas(self.pallas, v, interpret=interp)
-        if kind == "fast":
+        if self._formulation("rmatvec") == "fast":
             from photon_tpu.ops.fast_sparse import rmatvec_fast
 
             return rmatvec_fast(self.fast, v, self.dim)
@@ -317,13 +236,7 @@ class SparseFeatures:
 
     @jax.named_scope("sparse.sq_rmatvec")
     def sq_rmatvec(self, v: Array) -> Array:
-        kind, interp = self._formulation("sq_rmatvec", v.dtype)
-        if kind == "pallas":
-            from photon_tpu.ops.pallas_sparse import rmatvec_pallas
-
-            return rmatvec_pallas(self.pallas, v, square_vals=True,
-                                  interpret=interp)
-        if kind == "fast":
+        if self._formulation("sq_rmatvec") == "fast":
             from photon_tpu.ops.fast_sparse import rmatvec_fast
 
             return rmatvec_fast(self.fast, v, self.dim, square_vals=True)
@@ -391,8 +304,7 @@ class LabeledBatch:
             if attached is feats:
                 span.discard()
             else:
-                span.set(formulation="pallas" if attached.pallas
-                         is not None else "fast")
+                span.set(formulation="fast")
         if attached is feats:
             return self
         return dataclasses.replace(self, features=attached)

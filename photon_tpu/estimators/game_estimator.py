@@ -628,7 +628,7 @@ class GameEstimator:
         """``batch`` with the fast-path tables of its sparse features
         attached, and where they came from: ``"reused"``, ``"built"`` or
         None (no tables: off the accelerator, dense features, over the
-        ``PHOTON_ACCEL_AUX_BUDGET_GB`` guard).
+        table budget of ``data/batch.py``).
 
         The tables are a pure function of the feature object, so those of
         a prepared shard are built once, by the first fit that needs them,

@@ -1,18 +1,18 @@
-"""MXU-friendly sparse matvec/rmatvec paths for TPU.
+"""The ``fast`` formulation of the sparse pass: MXU-friendly matvec/rmatvec.
 
-Why this exists: XLA's generic ``gather``/``scatter-add`` lowerings on TPU run
-near one element per scalar-core cycle, so the ELL hot ops of a sparse GLM pass
-(`SparseFeatures.matvec`/`rmatvec`, SURVEY.md §7 hard-part #2) execute ~100×
-off the HBM roofline. Measured on a v5e (2^19 rows × 32 nnz over 2^18
-features): plain gather ≈ 150 ms, ``segment_sum`` scatter ≈ 118 ms per pass.
-
-This module replaces both with formulations XLA compiles to vector/MXU code:
+One of the two formulations behind ``SparseFeatures`` (``data/batch.py``):
+the ops run this one where the tables built here are attached, and the
+``plain`` gather / ``segment_sum`` where they are not. This module replaces
+XLA's generic gather and scatter-add with formulations it compiles to
+vector/MXU code. What a pass costs on the chip, operation by operation, is
+in ``PERF.md`` §5; which formulation a run's programs hold, in the counter
+``sparse_op_traces_total``.
 
 * ``matvec`` (and the gather side of ``rmatvec``): **row-slice gather +
   lane-select**.  The coefficient vector is viewed as ``[D/128, 128]``; each
   entry fetches its 128-wide row slice (``w2[idx >> 7]`` — a contiguous-slice
   gather XLA vectorizes) and selects its lane with a fused
-  ``where(lo == iota)`` reduction.  Measured ≈ 55 ms vs 150 ms.
+  ``where(lo == iota)`` reduction.
   ``matvec`` selects on the flat ``[rows*nnz, 128]`` array the gather writes: the
   TPU tiles the last two dimensions (8, 128), so a ``[N, K, 128]`` view of it
   is a physical copy whenever K is no multiple of 8 (76 pads to 80).
@@ -23,8 +23,7 @@ This module replaces both with formulations XLA compiles to vector/MXU code:
   128-column range.  The scatter-add then becomes
   ``einsum("bql,bq->bl", onehot(col & 127), contrib)`` — an MXU contraction
   with the one-hot fused from an int8 compare, never materialized — followed
-  by a tiny sorted segment-sum over ranges.  Measured ≈ 11 ms vs 118 ms for
-  the scatter itself.
+  by a tiny sorted segment-sum over ranges.
 
 The plan arrays are built on the host (NumPy), a pure function of the feature
 object, and ride along as an optional pytree on ``SparseFeatures``; all ops

@@ -157,23 +157,20 @@ def shard_batch_pytree(batch, mesh: Mesh, axis=DATA_AXIS):
 
 
 def strip_unshardable_aux(batch_or_features):
-    """Drop fast/Pallas aux tables before row distribution — their
-    column-sorted layouts are NOT partitionable along the row axis and
-    sharding them would corrupt results. Accepts a LabeledBatch or a bare
+    """Drop the ``fast`` tables before row distribution — their
+    column-sorted layout is NOT partitionable along the row axis and
+    sharding it would corrupt results. Accepts a LabeledBatch or a bare
     features container; the one definition every distribution path uses."""
     import dataclasses
 
+    from photon_tpu.data.batch import SparseFeatures
+
     obj = batch_or_features
-    feats = getattr(obj, "features", None)
-    if feats is not None:
-        if getattr(feats, "fast", None) is not None or \
-                getattr(feats, "pallas", None) is not None:
-            return dataclasses.replace(obj, features=feats.without_fast_path())
+    feats = getattr(obj, "features", obj)
+    if not isinstance(feats, SparseFeatures) or feats.fast is None:
         return obj
-    if getattr(obj, "fast", None) is not None or \
-            getattr(obj, "pallas", None) is not None:
-        return obj.without_fast_path()
-    return obj
+    bare = feats.without_fast_path()
+    return bare if feats is obj else dataclasses.replace(obj, features=bare)
 
 
 def pad_and_shard_batch(batch, mesh: Mesh, axis=DATA_AXIS):

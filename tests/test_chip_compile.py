@@ -130,10 +130,9 @@ def _bucket(sh, entities: int):
 @pytest.mark.parametrize("op,vec_len", [
     ("matvec", D), ("rmatvec", N), ("sq_rmatvec", N)])
 def test_default_sparse_path_compiles(one_chip, op, vec_len):
-    """The layouts ``with_accelerator_paths`` attaches on a TPU (the XLA
-    fast path) lower at 2^17 x 2^18 x 32."""
+    """The layouts ``with_accelerator_paths`` attaches on a TPU (``fast``)
+    lower at 2^17 x 2^18 x 32."""
     feats = _fixed_features(one_chip, fast=True)
-    assert feats._pallas_mode(jnp.float32) is None  # XLA formulation runs
     vec = _sds((vec_len,), "float32", one_chip)
     jax.jit(lambda f, x: getattr(f, op)(x)).lower(feats, vec).compile()
 
@@ -266,42 +265,20 @@ def test_entity_sharded_bucket_solver_compiles_on_four_devices(four_chips):
         _problem(PER_USER), batches, w0, mask, None).compile()
 
 
-# ------------------------------------- what the compiler refuses (ROADMAP S1)
-
-
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="the TPU compiler's words: 'Unimplemented primitive in Pallas "
-           "TPU lowering for KernelType.TC: dynamic_slice' — the value-level "
-           "lax.dynamic_slice_in_dim in _gather_onehot_kernel.chunk")
-@pytest.mark.parametrize("op", ["rmatvec", "matvec"])
-def test_pallas_sparse_kernel_compiles(one_chip, op):
-    from photon_tpu.ops import pallas_sparse as ps
-
-    nb = ps.TABLE_SUBLANES[op]
-    total = 4 * nb
-    tables = ps._OpTables(
-        hi=_sds((total, 128), "int32", one_chip),
-        lo=_sds((total, 128), "int32", one_chip),
-        val=_sds((total, 128), "float32", one_chip),
-        chunk_group=_sds((total // ps.CHUNK,), "int32", one_chip),
-        n_groups=D // 128)
-    jax.jit(lambda t, v: ps._run_op(t, v, nb, False, False)).lower(
-        tables, _sds((nb, 128), "float32", one_chip)).compile()
+# ----------------------------------- what the compiler refuses (ROADMAP R-a3)
 
 
 @pytest.mark.xfail(
     strict=True,
-    reason="behind the first refusal, the kernel's design: 'Mosaic failed "
-           "to compile TPU kernel: Not implemented: Multiple source vregs "
-           "along gather dimension' — the hardware gather reads within one "
-           "8x128 register, not across a [2048, 128] or [4096, 128] table")
-def test_pallas_table_wide_gather_compiles(one_chip):
+    reason="what the deleted Pallas kernels' central lookup asked of the "
+           "hardware: 'Mosaic failed to compile TPU kernel: Not implemented: "
+           "Multiple source vregs along gather dimension' — the hardware "
+           "gather reads within one 8x128 register, not across a [2048, 128] "
+           "table. A strict xfail: the day the compiler accepts it, this says")
+def test_gather_across_a_2048_row_vmem_table_is_refused(one_chip):
     from jax.experimental import pallas as pl
 
-    from photon_tpu.ops.pallas_sparse import TABLE_SUBLANES
-
-    nb = TABLE_SUBLANES["matvec"]
+    nb = 2048    # a coefficient table of 2048 x 128 = 256K features in VMEM
 
     def kernel(table_ref, idx_ref, out_ref):
         out_ref[:] = jnp.take_along_axis(

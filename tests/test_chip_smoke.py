@@ -161,16 +161,33 @@ def test_cpu_asked_for_reads_the_variable_before_jax_is_imported(
     assert guard._cpu_asked_for() is asked
 
 
+def _files_naming(name, *more):
+    """Files of the program, its drivers and scripts (and ``more``) that
+    still hold ``name``; binary files left out."""
+    return subprocess.run(
+        ["grep", "-rlI", name, "photon_tpu", "bench.py", "chip_smoke.py",
+         "scripts", "ci.sh", *more],
+        cwd=REPO, capture_output=True, text=True).stdout
+
+
 def test_no_cpu_masquerade_left():
     from photon_tpu import types
 
     assert types.REAL_ACCELERATOR_BACKENDS == ("tpu",)
     for gone in ("PHOTON_ACCEPT_CPU_AS_REAL", "PHOTON_AUTOPILOT_FAKE",
                  "PHOTON_BACKEND_LOCK_WAIT", "PHOTON_XLA_CACHE_DIR"):
-        hits = subprocess.run(
-            ["grep", "-rl", gone, "photon_tpu", "bench.py", "chip_smoke.py",
-             "scripts", "ci.sh"], cwd=REPO, capture_output=True, text=True)
-        assert hits.stdout == "", f"{gone} still read in {hits.stdout}"
+        assert _files_naming(gone) == "", f"{gone} still read"
+
+
+@pytest.mark.parametrize("gone", [
+    "PHOTON_PALLAS_INTERPRET", "PHOTON_ACCEL_AUX_BUDGET_GB",
+    "with_pallas_path", "pallas_sparse"])
+def test_names_that_are_gone(gone):
+    """PR 30: the sparse pass has two formulations and one table field; the
+    third, its switch and the budget variable are named by no code or
+    document a user reads."""
+    assert _files_naming(gone, "README.md", "docs") == "", (
+        f"{gone} still named")
 
 
 # ---------------------------------- step 6: one place for the compile cache

@@ -122,6 +122,36 @@ def test_uneven_rows_reject_or_pad(rng, mesh):
                                m_ref.coefficients.means, atol=1e-8)
 
 
+@pytest.mark.parametrize("holder", ["labeled_batch", "sparse", "dense"])
+def test_strip_unshardable_aux(rng, holder):
+    """Before rows are distributed the ``fast`` tables come off (the column
+    table does not shard by rows) and nothing else changes: a LabeledBatch
+    keeps its other leaves, bare sparse features their ELL arrays, and what
+    holds no tables comes back as it went in."""
+    from photon_tpu.parallel.mesh import strip_unshardable_aux
+
+    if holder == "dense":
+        batch = _data(rng, n=16)
+        assert strip_unshardable_aux(batch) is batch
+        assert strip_unshardable_aux(batch.features) is batch.features
+        return
+    rows = [(rng.choice(12, 3, replace=False), rng.normal(size=3))
+            for _ in range(16)]
+    bare = ell_from_rows(rows, dim=12)
+    assert strip_unshardable_aux(bare) is bare
+    fast = bare.with_fast_path(q_capacity=8)
+    if holder == "sparse":
+        out = strip_unshardable_aux(fast)
+        assert out.fast is None and out.idx is fast.idx and out.val is fast.val
+        return
+    batch = LabeledBatch(features=fast, labels=jnp.zeros(16),
+                         offsets=jnp.zeros(16), weights=jnp.ones(16))
+    out = strip_unshardable_aux(batch)
+    assert out.features.fast is None and out.features.idx is fast.idx
+    assert out.labels is batch.labels and out.weights is batch.weights
+    assert strip_unshardable_aux(out) is out
+
+
 class TestMultiSliceDCN:
     """2-level dcn x ici meshes (SURVEY.md §5.8): the 8 virtual devices play
     2 slices x 4 chips; psums over ("dcn", "data") lower hierarchically on

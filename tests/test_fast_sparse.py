@@ -34,9 +34,11 @@ def _random_sparse(n, dim, k, seed=0, skew=False):
     return ell_from_rows(rows, dim=dim)
 
 
-@pytest.mark.parametrize("skew", [False, True])
-def test_matvec_rmatvec_match_generic(skew):
-    n, dim, k = 300, 517, 9   # deliberately non-multiples of 128
+@pytest.mark.parametrize("n,dim,k,skew", [
+    (300, 517, 9, False), (300, 517, 9, True),
+    # rows and columns off the 128 and 1,024 grids
+    (300, 200, 4, False), (1000, 700, 6, False), (257, 129, 3, False)])
+def test_matvec_rmatvec_match_generic(n, dim, k, skew):
     sf = _random_sparse(n, dim, k, seed=1, skew=skew)
     aux = build_fast_aux(np.asarray(sf.idx), np.asarray(sf.val), dim,
                          q_capacity=64)
@@ -198,16 +200,11 @@ def test_value_dtype_bfloat16_close_for_continuous_features():
                                rtol=0.05, atol=0.05)
 
 
-def test_value_dtype_drops_pallas_and_is_idempotent():
+def test_value_dtype_is_idempotent():
     sf = _random_sparse(50, 64, 4, seed=14).with_fast_path(q_capacity=32)
-    # Fake an attached pallas aux: the cast must drop it (kernels are
-    # f32-only) rather than leave a stale-layout object behind.
-    import dataclasses as _dc
-
-    sf2 = _dc.replace(sf, pallas=object())
-    nf = sf2.with_value_dtype(jnp.bfloat16)
-    assert nf.pallas is None
+    nf = sf.with_value_dtype(jnp.bfloat16)
     assert nf.with_value_dtype(jnp.bfloat16) is nf  # no-op when already cast
+    assert sf.with_value_dtype(jnp.float32) is sf
 
 
 def test_glm_fit_with_bfloat16_values_converges_close():
@@ -319,3 +316,126 @@ def test_matvec_fast_lowers_with_no_rank3_row_slices():
         jax.ShapeDtypeStruct((dim,), jnp.float32), dim).as_text()
     assert f"tensor<{n * k}x128xf32>" in text
     assert f"tensor<{n}x{k}x128x" not in text
+
+
+def _ell(rng, n, d, k, ghost_frac=0.2):
+    """Raw ELL arrays with ghost entries anywhere in a row and column ids
+    free to repeat within one."""
+    idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
+    idx = np.where(rng.random((n, k)) < ghost_frac, d, idx)
+    val = np.where(idx < d, rng.normal(size=(n, k)), 0.0).astype(np.float32)
+    return idx, val
+
+
+def _scatter64(idx, val, dz, d, square=False):
+    """X^T.dz (or (X∘X)^T.dz) by a float64 scatter-add."""
+    v = val.astype(np.float64)
+    out = np.zeros(d + 1, np.float64)
+    np.add.at(out, idx.ravel(),
+              (dz.astype(np.float64)[:, None] * (v * v if square else v))
+              .ravel())
+    return out[:d]
+
+
+@pytest.mark.parametrize("case", ["duplicate_in_row", "column_in_every_row"])
+def test_fast_ops_match_float64_on_duplicate_and_hot_columns(case):
+    """A column id twice in one row: its entries add up. One column in
+    every row (as the intercept is in every cell): its 128-column range
+    holds more entries than ``q_capacity`` and spills over table rows."""
+    rng = np.random.default_rng(0)
+    n, d, k, q = 400, 100, 5, 64
+    idx, val = _ell(rng, n, d, k, ghost_frac=0.0)
+    if case == "duplicate_in_row":
+        idx[:, 1] = idx[:, 2]
+    else:
+        idx[:, 0] = 7
+    sf = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), d).with_fast_path(
+        q_capacity=q)
+    # d = 100 is one 128-column range; its 2,000 entries fill 32 table rows.
+    assert int((np.asarray(sf.fast.cs_range) == 0).sum()) == -(-n * k // q)
+    w = rng.normal(size=d).astype(np.float32)
+    dz = rng.normal(size=n).astype(np.float32)
+    z64 = np.sum(val.astype(np.float64)
+                 * np.append(w.astype(np.float64), 0.0)[idx], axis=1)
+    np.testing.assert_allclose(
+        np.asarray(sf.matvec(jnp.asarray(w))), z64, rtol=0, atol=5e-5)
+    np.testing.assert_allclose(
+        np.asarray(sf.rmatvec(jnp.asarray(dz))),
+        _scatter64(idx, val, dz, d), rtol=0, atol=5e-5)
+    np.testing.assert_allclose(
+        np.asarray(sf.sq_rmatvec(jnp.asarray(dz))),
+        _scatter64(idx, val, dz, d, square=True), rtol=0, atol=5e-5)
+
+
+@pytest.mark.parametrize("square_vals", [False, True])
+@pytest.mark.parametrize("n", [257, 1000, 1025])
+def test_rmatvec_fast_matches_float64_scatter_off_the_row_grids(
+        n, square_vals):
+    """X^T.r against a float64 scatter at row counts off the 128-row blocks
+    of the ``dz`` table and off ``ROW_PAD`` (one row past it at 1,025), ghost
+    entries in some rows: the mirror of the X.w test above."""
+    dim, k = 3 * 128 + 77, 6
+    rng = np.random.default_rng(n)
+    idx, val = _ell(rng, n, dim, k)
+    assert (idx == dim).any()
+    aux = build_fast_aux(idx, val, dim, q_capacity=64)
+    assert aux.n_row_blocks == -(-n // 128)
+    dz = rng.normal(size=n).astype(np.float32)
+    got = rmatvec_fast(aux, jnp.asarray(dz), dim, square_vals=square_vals)
+    assert got.shape == (dim,) and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got), _scatter64(idx, val, dz, dim, square=square_vals),
+        rtol=1e-5, atol=5e-5)
+
+
+def test_estimator_attaches_accelerator_paths(monkeypatch):
+    """On an accelerator backend the estimator attaches the ``fast`` tables
+    to fixed-effect batches by itself (drivers need no layout knowledge),
+    and nothing else; the fit matches the ``plain`` fit."""
+    from photon_tpu.estimators.config import (
+        FixedEffectDataConfig,
+        GLMOptimizationConfiguration,
+    )
+    from photon_tpu.estimators.game_estimator import GameEstimator
+    from photon_tpu.io.data_reader import GameDataBundle
+
+    rng = np.random.default_rng(9)
+    n, d, k = 400, 200, 6
+    idx, val = _ell(rng, n, d, k)
+    bundle = GameDataBundle(
+        features={"global": SparseFeatures(jnp.asarray(idx), jnp.asarray(val), d)},
+        labels=(rng.random(n) < 0.5).astype(np.float64),
+        offsets=np.zeros(n),
+        weights=np.ones(n),
+        uids=np.arange(n).astype(object),
+        id_tags={},
+    )
+    est = GameEstimator(
+        task=TaskType.LOGISTIC_REGRESSION,
+        coordinate_data_configs={"fixed": FixedEffectDataConfig("global")},
+        n_sweeps=1,
+    )
+    cfg = [{"fixed": GLMOptimizationConfiguration(
+        regularization=RegularizationContext(RegularizationType.L2),
+        reg_weight=1.0, max_iterations=10)}]
+
+    ref = est.fit(bundle, None, cfg)
+    w_plain = np.asarray(ref[0].model["fixed"].model.coefficients.means)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    attached = []
+    orig = SparseFeatures.with_accelerator_paths
+
+    def spy(self):
+        out = orig(self)
+        attached.append({f.name: getattr(out, f.name) is not None
+                         for f in dataclasses.fields(out)})
+        return out
+
+    monkeypatch.setattr(SparseFeatures, "with_accelerator_paths", spy)
+    got = est.fit(bundle, None, cfg)
+    w_acc = np.asarray(got[0].model["fixed"].model.coefficients.means)
+
+    assert attached == [
+        {"idx": True, "val": True, "dim": True, "fast": True}]
+    np.testing.assert_allclose(w_acc, w_plain, rtol=0, atol=2e-3)

@@ -284,7 +284,7 @@ def test_a_cpu_or_mesh_fit_keeps_nothing(where, monkeypatch):
 
 
 def test_features_over_the_budget_keep_nothing(builds, monkeypatch):
-    monkeypatch.setenv("PHOTON_ACCEL_AUX_BUDGET_GB", "0")
+    monkeypatch.setattr(batch_mod, "ACCEL_TABLE_BUDGET_BYTES", 0)
     estimator = _estimator()
     _, tree = _fit(estimator, _bundle(1))
     assert builds.calls == 0 and _table_counts(tree) == [(0, 0)]
