@@ -41,8 +41,10 @@ from photon_tpu.types import REAL_ACCELERATOR_BACKENDS
 
 Array = jax.Array
 
-# The ``fast`` tables cost 20 B an entry on the device beside the ELL arrays'
-# 8 B; ``SparseFeatures.with_accelerator_paths`` attaches none over this.
+# The tables cost at most 20 B an entry on the device beside the ELL arrays'
+# 8 B (``window``: 8 B a slot in each of two tables; ``fast``: 20 B);
+# ``SparseFeatures.with_accelerator_paths`` attaches none over this. Which of
+# the two an op gets is ``ops/fast_sparse.py`` ``WINDOW_BREAK_EVEN_PASSES``.
 ACCEL_TABLE_BUDGET_BYTES = 4e9
 
 
@@ -90,15 +92,19 @@ class SparseFeatures:
     ``idx[N, K]`` holds column ids in [0, D]; id == D marks padding (its value
     must be 0). ``dim`` (static) is the true feature dimension D.
 
-    ``fast`` (optional) is the one table object the features may carry, and
-    the one seam of the sparse pass: each op runs the ``fast`` formulation
-    when it is there (``ops/fast_sparse.py``: row-slice gather + one-hot
-    reduce over precomputed layouts) and the ``plain`` one when it is not
+    ``fast`` (optional) is the one table object the features may carry
+    (``ops/fast_sparse.py`` ``FastSparseAux``: a table for ``X.w`` and one
+    for ``X^T.r``), and the one seam of the sparse pass. Each op runs what
+    its table is: ``window`` (a ``WindowTable``: the windowed one-hot Pallas
+    kernel ``gather_reduce``, float32, nothing of the entries' length
+    written to HBM) or ``fast`` (a row-slice table: row-slice gather +
+    one-hot reduce, what the build keeps for an op whose entries do not sort
+    into narrow windows); and the ``plain`` one when no table is attached
     (gather / ``segment_sum``, inline below; also the reference the tests
-    hold ``fast`` to). ``with_accelerator_paths`` decides whether to attach
-    it; ``with_fast_path()`` attaches it whatever the platform. A further
-    formulation is another table type behind this field and one more arm an
-    op.
+    hold the others to, and what a ``window`` table's op runs on an operand
+    that is not float32). ``with_accelerator_paths`` decides whether to
+    attach tables; ``with_fast_path()`` attaches them whatever the platform;
+    ``build_fast_aux`` chooses each op's formulation from the table it built.
     """
 
     idx: Array
@@ -126,14 +132,12 @@ class SparseFeatures:
         )
         if jnp.dtype(self.val.dtype).itemsize < 4:
             # Values were already narrowed (with_value_dtype before attach):
-            # the column table must match or the rmatvec half of the
-            # bandwidth saving silently evaporates (builder emits f32).
-            # Only narrow-dtype casts: f64 runs keep the f32 table (the
+            # the tables' values must match or their half of the bandwidth
+            # saving silently evaporates (builder emits f32).
+            # Only narrow-dtype casts: f64 runs keep the f32 tables (the
             # builder already truncated through f32, so widening would
-            # double its memory for zero precision).
-            aux = dataclasses.replace(
-                aux, cs_val=aux.cs_val.astype(self.val.dtype)
-            )
+            # double their memory for zero precision).
+            aux = aux.cast_values(self.val.dtype)
         return dataclasses.replace(self, fast=aux)
 
     def with_accelerator_paths(self) -> "SparseFeatures":
@@ -145,7 +149,7 @@ class SparseFeatures:
         host-side table build is pure overhead. The third thing the code
         observes, a mesh, is seen where the rows are distributed:
         ``parallel/mesh.py`` ``strip_unshardable_aux`` takes the tables off
-        again, because the column table does not shard by rows. The
+        again, because the tables do not shard by rows. The
         estimator, the transformer and the training driver call this, so
         no caller knows about layouts."""
         if jax.default_backend() not in REAL_ACCELERATOR_BACKENDS:
@@ -171,7 +175,7 @@ class SparseFeatures:
 
         The ops upcast on load and accumulate in the operand precision, so
         only storage narrows: 2 B of the 4 B a stored value takes, in ``val``
-        and in the column table, which is re-cast to match. What that buys
+        and in the tables, which are re-cast to match. What that buys
         a fit on the chip is not measured (the benchmark uses this path as
         its failing control, PERF.md §2). One-hot / binary / small-integer
         features are EXACT in bfloat16; continuous features round to 8
@@ -182,29 +186,28 @@ class SparseFeatures:
             return self
         out = dataclasses.replace(self, val=self.val.astype(dt))
         if out.fast is not None:
-            out = dataclasses.replace(
-                out,
-                fast=dataclasses.replace(
-                    out.fast, cs_val=out.fast.cs_val.astype(dt)
-                ),
-            )
+            out = dataclasses.replace(out, fast=out.fast.cast_values(dt))
         return out
 
     def without_fast_path(self) -> "SparseFeatures":
-        """Drop the ``fast`` tables (e.g. before row-sharding: the
-        column-sorted table is not partitionable along the row axis)."""
+        """Drop the tables (e.g. before row-sharding: a table sorted by
+        column or by window is not partitionable along the row axis)."""
         if self.fast is None:
             return self
         return dataclasses.replace(self, fast=None)
 
-    def _formulation(self, op: str) -> str:
+    def _formulation(self, op: str, operand: Array) -> str:
         """Which formulation ``op`` puts into the program being traced
-        here: ``"fast"`` when the tables are attached, else ``"plain"``.
+        here: what the op's table is (``"window"`` or ``"fast"``) when the
+        tables are attached, else ``"plain"``; ``"plain"`` also for an
+        operand the float32 ``window`` kernel cannot take.
         Counted once per trace (or eager call) in
         ``sparse_op_traces_total{op, formulation}``, so a run can say what
         its programs really hold, not what a look-alike batch would get."""
         pass_counter.record(op)
-        kind = "fast" if self.fast is not None else "plain"
+        kind = "plain" if self.fast is None else self.fast.formulation(op)
+        if kind == "window" and operand.dtype != jnp.float32:
+            kind = "plain"
         REGISTRY.counter(
             "sparse_op_traces_total",
             "sparse feature ops traced into programs, by formulation",
@@ -213,7 +216,7 @@ class SparseFeatures:
 
     @jax.named_scope("sparse.matvec")
     def matvec(self, w: Array) -> Array:
-        if self._formulation("matvec") == "fast":
+        if self._formulation("matvec", w) != "plain":
             from photon_tpu.ops.fast_sparse import matvec_fast
 
             return matvec_fast(self.fast, self.val, w, self.dim)
@@ -224,7 +227,7 @@ class SparseFeatures:
 
     @jax.named_scope("sparse.rmatvec")
     def rmatvec(self, v: Array) -> Array:
-        if self._formulation("rmatvec") == "fast":
+        if self._formulation("rmatvec", v) != "plain":
             from photon_tpu.ops.fast_sparse import rmatvec_fast
 
             return rmatvec_fast(self.fast, v, self.dim)
@@ -236,7 +239,7 @@ class SparseFeatures:
 
     @jax.named_scope("sparse.sq_rmatvec")
     def sq_rmatvec(self, v: Array) -> Array:
-        if self._formulation("sq_rmatvec") == "fast":
+        if self._formulation("sq_rmatvec", v) != "plain":
             from photon_tpu.ops.fast_sparse import rmatvec_fast
 
             return rmatvec_fast(self.fast, v, self.dim, square_vals=True)
@@ -286,7 +289,7 @@ class LabeledBatch:
         return dataclasses.replace(self, offsets=self.offsets + scores)
 
     def with_accelerator_paths(self) -> "LabeledBatch":
-        """Sparse features gain the MXU layouts (see
+        """Sparse features gain the tables of the sparse pass (see
         ``SparseFeatures.with_accelerator_paths``); dense features no-op.
         Every call that attaches is a host-side table build: a caller that
         comes back to the same features keeps the result beside them
@@ -303,8 +306,8 @@ class LabeledBatch:
             attached = feats.with_accelerator_paths()
             if attached is feats:
                 span.discard()
-            else:
-                span.set(formulation="fast")
+            elif attached.fast is not None:
+                span.set(**attached.fast.span_arguments())
         if attached is feats:
             return self
         return dataclasses.replace(self, features=attached)

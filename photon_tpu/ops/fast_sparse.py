@@ -1,43 +1,69 @@
-"""The ``fast`` formulation of the sparse pass: MXU-friendly matvec/rmatvec.
+"""The table formulations of the sparse pass: ``window`` and ``fast``.
 
-One of the two formulations behind ``SparseFeatures`` (``data/batch.py``):
-the ops run this one where the tables built here are attached, and the
-``plain`` gather / ``segment_sum`` where they are not. This module replaces
-XLA's generic gather and scatter-add with formulations it compiles to
-vector/MXU code. What a pass costs on the chip, operation by operation, is
-in ``PERF.md`` §5; which formulation a run's programs hold, in the counter
-``sparse_op_traces_total``.
+Two of the three formulations behind ``SparseFeatures`` (``data/batch.py``):
+the ops run one of these where the tables built here are attached, and the
+``plain`` gather / ``segment_sum`` where they are not. Both replace XLA's
+generic gather and scatter-add, which the TPU runs one element at a time,
+with layouts built once on the host from the (static) indices. What a pass
+costs on the chip, operation by operation, is in ``PERF.md`` §5; which
+formulation a run's programs hold, in the counter ``sparse_op_traces_total``.
 
-* ``matvec`` (and the gather side of ``rmatvec``): **row-slice gather +
-  lane-select**.  The coefficient vector is viewed as ``[D/128, 128]``; each
-  entry fetches its 128-wide row slice (``w2[idx >> 7]`` — a contiguous-slice
-  gather XLA vectorizes) and selects its lane with a fused
-  ``where(lo == iota)`` reduction.
-  ``matvec`` selects on the flat ``[rows*nnz, 128]`` array the gather writes: the
-  TPU tiles the last two dimensions (8, 128), so a ``[N, K, 128]`` view of it
-  is a physical copy whenever K is no multiple of 8 (76 pads to 80).
+``build_fast_aux`` builds one table for each op (``X.w``, and ``X^T.r`` with
+its squared twin) and chooses the formulation of each from a number it can
+observe, the mean MXU passes a slot of the ``window`` table
+(``WINDOW_BREAK_EVEN_PASSES``):
 
-* ``rmatvec`` reduction: **column-sorted one-hot matmul**.  Entries are
-  pre-sorted (host-side, once — indices are static data) by column and grouped
-  into rows of a ``[B, Q]`` table whose columns all fall in one aligned
-  128-column range.  The scatter-add then becomes
-  ``einsum("bql,bq->bl", onehot(col & 127), contrib)`` — an MXU contraction
-  with the one-hot fused from an int8 compare, never materialized — followed
-  by a tiny sorted segment-sum over ranges.
+* ``window`` (``WindowTable``, ``gather_reduce``): **one Pallas kernel for
+  both ops, no gather at all.** A table is ``[B, Q]`` slots; every slot of
+  table row ``b`` reduces into the same aligned 128-range of the output and
+  carries, in one int32, where in the gathered vector its operand lives and
+  which of the 128 outputs it adds to; a second stream carries its value
+  (0 in padding slots, so the hot loop masks nothing). ``X^T.r`` reduces
+  into column ranges and gathers ``dz`` by row; ``X.w`` is its mirror:
+  128-row ranges, ``w`` gathered by column. Entries are sorted by (range,
+  gathered index), so a chunk of ``CHUNK`` consecutive slots reads a narrow
+  span of the vector. The "which 128-block" half of the lookup is a one-hot
+  product on the MXU against a *window* of ``WINDOW_BLOCKS`` blocks of the
+  vector, which is resident in VMEM as three bfloat16 parts; the "which
+  lane" half is a compare-select on the VPU. A float32 splits exactly into
+  three bfloat16 parts and a one-hot is exact in bfloat16, so the product
+  (float32 accumulation) **returns the float32 bits of the vector**: no
+  precision is given up. The multiply by the value and the reduction into
+  the 128 outputs are float32 on the VPU. Nothing of an entries' length is
+  written to HBM: a slot costs 8 B of table read.
 
-The plan arrays are built on the host (NumPy), a pure function of the feature
-object, and ride along as an optional pytree on ``SparseFeatures``; all ops
-stay pure/jittable. ``build_fast_aux`` itself keeps nothing: each call is a
-build. "Once per dataset" is the caller's to hold — ``GameEstimator`` keeps
-the tables with its prepared bundle, so every fit on that bundle after the
-first attaches them; the one-shot drivers build once a run.
-Ghost-padding entries (column id == dim) are mapped to a zero row with value
-0, so no masking is needed in the hot loop.
+* ``fast`` (``RowSliceXw``, ``RowSliceXtr``): **row-slice gather +
+  lane-select**, the formulation before the kernel and what an op keeps
+  whose entries do not sort into narrow windows (``chip_smoke.py``'s uniform
+  random columns), or whose vector is too long for VMEM. The vector is
+  viewed as ``[D/128, 128]``; each entry fetches its 128-wide row slice
+  (a contiguous-slice gather XLA vectorizes) and selects its lane with a
+  fused ``where(lo == iota)`` reduction: 512 B written to HBM and read back
+  an entry. ``X^T.r`` then reduces with a one-hot contraction per
+  128-column range.
+
+The tables are built on the host (NumPy, vectorised), a pure function of
+the feature object, and ride along as an optional pytree on
+``SparseFeatures``; all ops stay pure/jittable. ``build_fast_aux`` itself
+keeps nothing: each call is a build. "Once per dataset" is the caller's to
+hold — ``GameEstimator`` keeps the tables with its prepared bundle, so every
+fit on that bundle after the first attaches them; the one-shot drivers build
+once a run. Ghost-padding entries (column id == dim, value 0) are in no
+``window`` table and point at a zero row in the ``fast`` ones.
+
+One thing ``window`` does differently from a gather: the one-hot product
+multiplies every element of a window by 0 or 1, so a non-finite element
+makes every slot that reads its window NaN, not only the slots that read
+the element. A solver rejects such a trial point by its value either way.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
+import sys
+import threading
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -52,29 +78,95 @@ LANE = 128
 # minutes to compile (70 s at 1,002,640 x 8, 1 s at 1,003,520 x 8).
 ROW_PAD = 1024
 
+# --- the ``window`` formulation's geometry -------------------------------
+# A window is this many 128-blocks of the gathered vector: the three
+# bfloat16 parts of its blocks lie side by side on the contraction axis of
+# one MXU pass (3 x 42 = 126 of 128 rows), so the pass sums the parts.
+WINDOW_BLOCKS = 42
+# Slots a pass handles at once; table rows are whole chunks, and each chunk
+# carries the contiguous run of windows its (sorted) slots read.
+CHUNK = 1024
+# Table rows a grid step of the kernel walks (a loop, not an unrolled body).
+ROWS_PER_STEP = 8
+# Field widths of a slot's int32: window | block in window | lane | out lane.
+_WIN_SHIFT, _BLK_SHIFT, _LANE_SHIFT = 20, 14, 7
+MAX_WINDOWS = 1 << (31 - _WIN_SHIFT)
+MAX_PASSES_A_CHUNK = 255
+# The gathered vector's three parts are resident in VMEM, 6 B an element.
+WINDOW_VMEM_VECTOR_BYTES = 48 << 20
+# Mean MXU passes a slot over which an op keeps the row-slice formulation:
+# a chunk of 1,024 slots costs ``window`` about 0.22 us and 0.30 us a pass on
+# a v5e where 1,024 entries cost ``fast`` 2.7 to 3.0 us (PERF.md §6, PR 31:
+# the readings of ``scripts/sparse_formulation_check.py ops`` on the chip; the
+# three cells read 1.0 to 1.9 passes, chip_smoke.py's shape 10.7 and 13.0).
+WINDOW_BREAK_EVEN_PASSES = 8.0
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class FastSparseAux:
-    """Static auxiliary layouts for the fast paths.
+class WindowTable:
+    """One op's ``window`` table: ``out[range[b]*128 + l] += val * vec[g]``
+    for every slot of table row ``b``.
 
-    Row-major digit split (for matvec's row-slice gather), flat in the
-    order of ``val.ravel()`` so the program reshapes no index stream, over
-    ``Np`` rows (N rounded up to ``ROW_PAD``, the added rows all ghosts):
-      ``hi[Np*K]`` int16/int32 — column id >> 7 (ghost entries point at the
-      zero row appended to the coefficient table; int16 when the block count
-      fits, halving that index stream's HBM traffic); ``lo[Np*K]`` int8 —
-      column & 127.
-
-    Column-sorted table (for rmatvec's one-hot reduce): ``B`` rows of capacity
-    ``Q``; every slot in row b carries an entry whose column lies in the
-    128-aligned range ``cs_range[b]``. ``cs_rhi``/``cs_rlo`` split the entry's
-    ROW id for the dz gather; ``cs_clo`` is its lane within the range;
-    ``cs_val`` is the feature value (0 in padding slots).
+    ``word[B, Q]`` int32 packs, per slot, the window of ``vec`` its operand
+    lies in, the 128-block within that window, the lane within the block,
+    and the lane ``l`` of the output range; ``val[B, Q]`` is its value (0 in
+    padding slots; may be stored narrower, widened on load). ``passes``
+    holds, per ``CHUNK`` slots of a row, ``first window << 8 | windows`` the
+    chunk reads (0 for an all-padding chunk: the kernel skips it).
+    ``range[B]`` is sorted (``n_ranges`` for the rows that pad ``B`` to whole
+    grid steps).
     """
+
+    word: Array      # [B, Q] int32
+    val: Array       # [B, Q] float32 (or the features' narrower value dtype)
+    passes: Array    # [B * Q / CHUNK] int32
+    range: Array     # [B] int32, sorted
+    n_ranges: int = dataclasses.field(metadata=dict(static=True))
+    n_windows: int = dataclasses.field(metadata=dict(static=True))
+
+    formulation = "window"
+
+    def passes_per_slot(self) -> float:
+        """Mean MXU passes over the chunks that hold an entry: what
+        ``build_fast_aux`` chose by (a host read of ``passes``; not for
+        traced code)."""
+        n_pass = np.asarray(self.passes) & 255
+        return float(n_pass[n_pass > 0].mean()) if n_pass.any() else 0.0
+
+    def cast_values(self, dtype) -> "WindowTable":
+        return dataclasses.replace(self, val=self.val.astype(dtype))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RowSliceXw:
+    """``X.w`` by row-slice gather: the row-major digit split of the column
+    ids, flat in the order of ``val.ravel()`` so the program reshapes no
+    index stream, over ``Np`` rows (N rounded up to ``ROW_PAD``, the added
+    rows all ghosts). ``hi[Np*K]`` is column id >> 7 (ghost entries point at
+    the zero row appended to the coefficient table; int16 when the block
+    count fits, halving that index stream's HBM traffic); ``lo[Np*K]`` int8
+    is column & 127. The values are the features' own ``val``."""
 
     hi: Array        # [Np*K] int16 or int32 (see _digit_dtype)
     lo: Array        # [Np*K] int8
+
+    formulation = "fast"
+
+    def cast_values(self, dtype) -> "RowSliceXw":
+        return self
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RowSliceXtr:
+    """``X^T.r`` by row-slice gather and one-hot reduce: ``B`` rows of
+    capacity ``Q``; every slot in row b carries an entry whose column lies in
+    the 128-aligned range ``cs_range[b]``. ``cs_rhi``/``cs_rlo`` split the
+    entry's ROW id for the dz gather; ``cs_clo`` is its lane within the
+    range; ``cs_val`` is the feature value (0 in padding slots)."""
+
     cs_rhi: Array    # [B, Q] int16 or int32
     cs_rlo: Array    # [B, Q] int8
     cs_clo: Array    # [B, Q] int8
@@ -82,6 +174,42 @@ class FastSparseAux:
     cs_range: Array  # [B] int32 (sorted; == n_ranges for padding rows)
     n_ranges: int = dataclasses.field(metadata=dict(static=True))
     n_row_blocks: int = dataclasses.field(metadata=dict(static=True))
+
+    formulation = "fast"
+
+    def cast_values(self, dtype) -> "RowSliceXtr":
+        return dataclasses.replace(self, cs_val=self.cs_val.astype(dtype))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class FastSparseAux:
+    """The tables ``SparseFeatures.fast`` holds: one for ``X.w`` and one for
+    ``X^T.r`` (and its squared twin), each in the formulation
+    ``build_fast_aux`` chose for it."""
+
+    xw: Union[WindowTable, RowSliceXw]
+    xtr: Union[WindowTable, RowSliceXtr]
+
+    def formulation(self, op: str) -> str:
+        """``"window"`` or ``"fast"``: what ``op`` runs on these tables."""
+        return (self.xw if op == "matvec" else self.xtr).formulation
+
+    def span_arguments(self) -> dict:
+        """What the ``data.accel_tables`` span says of a build: each op's
+        formulation, and the mean MXU passes a slot of a ``window`` table."""
+        out = {}
+        for op, table in (("matvec", self.xw), ("rmatvec", self.xtr)):
+            out[f"formulation_{op}"] = table.formulation
+            if isinstance(table, WindowTable):
+                out[f"passes_per_slot_{op}"] = round(table.passes_per_slot(), 4)
+        return out
+
+    def cast_values(self, dtype) -> "FastSparseAux":
+        """The tables with their stored values in ``dtype`` (the ops widen
+        on load): ``SparseFeatures.with_value_dtype``'s half of the tables."""
+        return FastSparseAux(xw=self.xw.cast_values(dtype),
+                             xtr=self.xtr.cast_values(dtype))
 
 
 def _digit_dtype(n_blocks: int):
@@ -93,79 +221,336 @@ def _digit_dtype(n_blocks: int):
     return np.int16 if n_blocks + 1 <= np.iinfo(np.int16).max else np.int32
 
 
-def build_fast_aux(
-    idx: np.ndarray, val: np.ndarray, dim: int, q_capacity: int = 2048
-) -> FastSparseAux:
-    """Host-side construction of both static layouts from ELL arrays.
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    ``idx``/``val`` are the ``SparseFeatures`` arrays ([N, K], ghost column ==
-    ``dim`` with value 0). ``q_capacity`` bounds the column-table row width; a
-    popular column range simply occupies several table rows (so skewed or
-    dense columns — e.g. the intercept — need no special casing).
-    """
-    idx = np.asarray(idx)
-    val = np.asarray(val)
+
+# ------------------------------------------------------------- the builders
+
+
+def _sorted_by_column_block(idx: np.ndarray, val: np.ndarray, dim: int):
+    """The live entries as ``(col, row, val)``, sorted by (column >> 7, row):
+    a stable sort of the row-major entries by column block alone."""
+    k = idx.shape[1]
+    flat = idx.ravel()
+    keep = np.flatnonzero(flat < dim).astype(np.int32)
+    col = flat[keep].astype(np.int32)
+    # 16-bit keys take NumPy's radix sort.
+    blk = (col >> 7).astype(np.uint16 if dim <= LANE << 16 else np.int32)
+    at = keep[np.argsort(blk, kind="stable")]
+    return flat[at].astype(np.int32), at // k, val.ravel()[at]
+
+
+def _sorted_by_row_block(idx: np.ndarray, val: np.ndarray, dim: int):
+    """The live entries as ``(row, col, val)``, sorted by (row >> 7, column):
+    the entries of 128 rows are contiguous already, so one sort along the
+    second axis of ``[row blocks, 128 * K]`` (ghosts sort last in each)."""
     n, k = idx.shape
-    n_row_blocks = -(-n // LANE)
-    n_col_blocks = -(-dim // LANE)
+    width = LANE * k
+    # Column above, position in the block below: sorting the packed key
+    # sorts the positions with it.
+    bits = max(int(width - 1).bit_length(), 1)
+    key = np.full((_round_up(n, LANE), k), dim, np.int64)
+    key[:n] = np.minimum(idx, dim)
+    key = key.reshape(-1, width)
+    key <<= bits
+    key |= np.arange(width, dtype=np.int64)
+    key.sort(axis=1)
+    col = (key >> bits).astype(np.int32).ravel()
+    key &= (1 << bits) - 1
+    key += np.arange(key.shape[0], dtype=np.int64)[:, None] * width
+    live = np.flatnonzero(col < dim)
+    at = key.ravel()[live].astype(np.int32)
+    return at // k, col[live], val.ravel()[at]
 
-    # Row-major digit split, flat; ghost entries, and the ghost rows that
-    # fill the stream to whole ROW_PAD blocks, -> appended zero row of w table.
+
+def _slots(counts: np.ndarray, q: int, rows_floor: int):
+    """Where the entries of a table go, in their sorted order, given how
+    many each range holds: each range fills whole rows of ``q`` slots from
+    the left, at least ``rows_floor`` of them. ``(flat slot of every entry,
+    range of every table row)``, the rows padded to whole grid steps with
+    the range one past the last."""
+    rows_of = np.maximum(rows_floor, -(-counts // q))
+    # A range's entries are consecutive: its first slot less its first
+    # entry's position is what every one of them is shifted by.
+    shift = (np.cumsum(rows_of) - rows_of) * q - (np.cumsum(counts) - counts)
+    flat = np.repeat(shift.astype(np.int32), counts)
+    flat += np.arange(len(flat), dtype=np.int32)
+    b_live = int(rows_of.sum())
+    ranges = np.full(_round_up(max(b_live, 1), ROWS_PER_STEP), len(counts),
+                     np.int32)
+    ranges[:b_live] = np.repeat(np.arange(len(counts), dtype=np.int32),
+                                rows_of)
+    return flat, ranges
+
+
+def _window_table(red, gat, val, n_red: int, n_gat: int,
+                  q_capacity: int) -> Optional[WindowTable]:
+    """The ``window`` table of entries that reduce into index ``red`` (of
+    ``n_red``) and gather index ``gat`` (of ``n_gat``), both int32 and sorted
+    by (``red >> 7``, ``gat``); None where the geometry cannot hold them
+    (the vector too long, a chunk over too many windows)."""
+    n_ranges = -(-n_red // LANE)
+    n_windows = max(1, -(-n_gat // (WINDOW_BLOCKS * LANE)))
+    if (n_windows > MAX_WINDOWS
+            or 6 * n_windows * WINDOW_BLOCKS * LANE > WINDOW_VMEM_VECTOR_BYTES):
+        return None
+    counts = np.bincount(red >> 7, minlength=n_ranges)
+    # Q from the shape: no wider than the fullest range needs.
+    q = max(CHUNK, min(_round_up(q_capacity, CHUNK),
+                       _round_up(int(counts.max(initial=0)), CHUNK)))
+    flat, ranges = _slots(counts, q, rows_floor=0)
+    b = len(ranges)
+
+    win = gat // (WINDOW_BLOCKS * LANE)
+    packed = gat - win * (WINDOW_BLOCKS * LANE)     # block in window | lane
+    packed <<= _LANE_SHIFT
+    packed |= red & 127
+    packed |= win << _WIN_SHIFT
+    word = np.zeros(b * q, np.int32)
+    word[flat] = packed
+    values = np.zeros(b * q, np.float32)
+    values[flat] = val
+
+    # A chunk's slots are sorted by gathered index: it reads the windows
+    # from its first slot's to its last live slot's.
+    passes = np.zeros(b * q // CHUNK, np.int32)
+    if len(flat):
+        flat //= CHUNK
+        starts = np.flatnonzero(np.r_[True, flat[1:] != flat[:-1]])
+        ends = np.r_[starts[1:], len(flat)] - 1
+        n_pass = win[ends] - win[starts] + 1
+        if int(n_pass.max()) > MAX_PASSES_A_CHUNK:
+            return None
+        passes[flat[starts]] = (win[starts] << 8) | n_pass
+    return WindowTable(
+        word=jnp.asarray(word.reshape(b, q)),
+        val=jnp.asarray(values.reshape(b, q)),
+        passes=jnp.asarray(passes), range=jnp.asarray(ranges),
+        n_ranges=n_ranges, n_windows=n_windows)
+
+
+def _row_slice_xw(idx: np.ndarray, dim: int) -> RowSliceXw:
+    n, k = idx.shape
+    n_col_blocks = -(-dim // LANE)
+    # Ghost entries, and the ghost rows that fill the stream to whole
+    # ROW_PAD blocks, -> appended zero row of the w table.
     ghost = idx >= dim
-    n_pad = -(-n // ROW_PAD) * ROW_PAD
+    n_pad = _round_up(n, ROW_PAD)
     hi = np.full((n_pad, k), n_col_blocks, _digit_dtype(n_col_blocks))
     lo = np.zeros((n_pad, k), np.int8)
     hi[:n] = np.where(ghost, n_col_blocks, idx >> 7)
     lo[:n] = np.where(ghost, 0, idx & 127)
+    return RowSliceXw(hi=jnp.asarray(hi.ravel()), lo=jnp.asarray(lo.ravel()))
 
-    # Column-sorted table.
-    flat_col = idx.ravel()
-    keep = flat_col < dim
-    cols = flat_col[keep].astype(np.int64)
-    rows = np.repeat(np.arange(n, dtype=np.int64), k)[keep]
-    vals = val.ravel()[keep]
-    order = np.argsort(cols, kind="stable")
-    cols, rows, vals = cols[order], rows[order], vals[order]
 
-    rng_of = (cols >> 7).astype(np.int64)
-    counts = np.bincount(rng_of, minlength=n_col_blocks)
-    rows_per_range = np.maximum(1, -(-counts // q_capacity))
-    b_total = int(rows_per_range.sum())
-    b_pad = -(-b_total // 8) * 8
+def _row_slice_xtr(col, row, val, n: int, dim: int,
+                   q_capacity: int) -> RowSliceXtr:
+    """From the entries sorted by column block. A popular column range
+    simply occupies several table rows (so skewed or dense columns — e.g.
+    the intercept — need no special casing)."""
+    n_row_blocks = -(-n // LANE)
+    n_col_blocks = -(-dim // LANE)
+    flat, cs_range = _slots(np.bincount(col >> 7, minlength=n_col_blocks),
+                            q_capacity, rows_floor=1)
+    b = len(cs_range)
 
-    cs_rhi = np.zeros((b_pad, q_capacity), _digit_dtype(n_row_blocks))
-    cs_rlo = np.zeros((b_pad, q_capacity), np.int8)
-    cs_clo = np.zeros((b_pad, q_capacity), np.int8)
-    cs_val = np.zeros((b_pad, q_capacity), np.float32)
-    cs_range = np.full((b_pad,), n_col_blocks, np.int32)
+    def table(dtype, values):
+        out = np.zeros(b * q_capacity, dtype)
+        out[flat] = values
+        return jnp.asarray(out.reshape(b, q_capacity))
 
-    starts = np.concatenate([[0], np.cumsum(counts)])
-    b = 0
-    for r in range(n_col_blocks):
-        lo_e, hi_e = int(starts[r]), int(starts[r + 1])
-        for off in range(lo_e, max(hi_e, lo_e + 1), q_capacity):
-            end = min(off + q_capacity, hi_e)
-            m = end - off
-            if m > 0:
-                sl = slice(off, end)
-                cs_rhi[b, :m] = (rows[sl] >> 7).astype(cs_rhi.dtype)
-                cs_rlo[b, :m] = (rows[sl] & 127).astype(np.int8)
-                cs_clo[b, :m] = (cols[sl] & 127).astype(np.int8)
-                cs_val[b, :m] = vals[sl]
-            cs_range[b] = r
-            b += 1
-
-    return FastSparseAux(
-        hi=jnp.asarray(hi.ravel()),
-        lo=jnp.asarray(lo.ravel()),
-        cs_rhi=jnp.asarray(cs_rhi),
-        cs_rlo=jnp.asarray(cs_rlo),
-        cs_clo=jnp.asarray(cs_clo),
-        cs_val=jnp.asarray(cs_val),
+    return RowSliceXtr(
+        cs_rhi=table(_digit_dtype(n_row_blocks), row >> 7),
+        cs_rlo=table(np.int8, row & 127),
+        cs_clo=table(np.int8, col & 127),
+        cs_val=table(np.float32, val),
         cs_range=jnp.asarray(cs_range),
-        n_ranges=n_col_blocks,
-        n_row_blocks=n_row_blocks,
-    )
+        n_ranges=n_col_blocks, n_row_blocks=n_row_blocks)
+
+
+def _keeps_window(table: Optional[WindowTable]) -> bool:
+    return (table is not None
+            and table.passes_per_slot() <= WINDOW_BREAK_EVEN_PASSES)
+
+
+def _import_the_kernels_modules_meanwhile() -> None:
+    """Pallas takes about a second to import, and the first trace of a
+    program that holds the kernel would wait for it (a fresh process's
+    first fit: ``setup_s``). A build's sorts release the interpreter lock,
+    so a thread imports it beside them; an import another thread has begun
+    is waited for, not repeated."""
+    name = "jax.experimental.pallas.tpu"
+    if name not in sys.modules:
+        threading.Thread(target=importlib.import_module, args=(name,),
+                         daemon=True).start()
+
+
+def build_fast_aux(
+    idx: np.ndarray, val: np.ndarray, dim: int, q_capacity: int = 2048
+) -> FastSparseAux:
+    """Host-side construction of both ops' tables from ELL arrays.
+
+    ``idx``/``val`` are the ``SparseFeatures`` arrays ([N, K], ghost column ==
+    ``dim`` with value 0). ``q_capacity`` bounds a table row's width. Each op
+    gets the ``window`` table unless its mean passes a slot lie over
+    ``WINDOW_BREAK_EVEN_PASSES`` (or the table cannot be built), and the
+    row-slice table then.
+    """
+    _import_the_kernels_modules_meanwhile()
+    idx = np.asarray(idx)
+    val = np.asarray(val).astype(np.float32, copy=False)
+    n, k = idx.shape
+
+    col, row, by_col = _sorted_by_column_block(idx, val, dim)
+    xtr = _window_table(col, row, by_col, dim, n, q_capacity)
+    if not _keeps_window(xtr):
+        xtr = _row_slice_xtr(col, row, by_col, n, dim, q_capacity)
+    del col, row, by_col
+    xw = _window_table(*_sorted_by_row_block(idx, val, dim), n, dim,
+                       q_capacity)
+    if not _keeps_window(xw):
+        xw = _row_slice_xw(idx, dim)
+    return FastSparseAux(xw=xw, xtr=xtr)
+
+
+# ------------------------------------------------------ ``window``: the kernel
+
+
+def _interpret() -> bool:
+    """Off the TPU the kernel runs in the Pallas interpreter (the CPU tests
+    and rehearsals; what the estimator attaches there is ``plain``)."""
+    return jax.devices()[0].platform != "tpu"
+
+
+def _truncate_to_bfloat16(x: Array) -> Array:
+    """``x`` with the low 16 bits of its float32 cleared: a bfloat16 value
+    exactly. By bits, not by a conversion pair, which the compiler may
+    elide under its excess-precision rule."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(
+        bits & jnp.uint32(0xFFFF0000), jnp.float32)
+
+
+def _window_parts(vec: Array, n_windows: int) -> Array:
+    """``vec`` as the kernel reads it: ``[128, n_windows * 128]`` bfloat16,
+    lane of the vector on rows; window ``w`` holds in its 128 columns the
+    three exact parts (``a + b + c == x`` bit for bit) of its 42 blocks
+    side by side, part ``p`` of block ``j`` at column ``42 p + j``."""
+    blocks = n_windows * WINDOW_BLOCKS
+    x = jnp.pad(vec.astype(jnp.float32), (0, blocks * LANE - vec.shape[0]))
+    a = _truncate_to_bfloat16(x)
+    b = _truncate_to_bfloat16(x - a)
+    c = (x - a) - b
+    parts = jnp.stack([a, b, c]).astype(jnp.bfloat16)
+    parts = parts.reshape(3, n_windows, WINDOW_BLOCKS, LANE)
+    parts = parts.transpose(3, 1, 0, 2).reshape(
+        LANE, n_windows, 3 * WINDOW_BLOCKS)
+    parts = jnp.pad(parts, ((0, 0), (0, 0), (0, LANE - 3 * WINDOW_BLOCKS)))
+    return parts.reshape(LANE, n_windows * LANE)
+
+
+def _window_kernel(passes_ref, word_ref, val_ref, vec_ref, out_ref, *, q: int):
+    from jax.experimental import pallas as pl
+
+    chunks = q // CHUNK
+    step = pl.program_id(0)
+    lane_of = jax.lax.broadcasted_iota(jnp.int32, (LANE, CHUNK), 0)
+    # Row k of a window holds block k mod 42 (rows 126, 127: none).
+    block_of = (lane_of - jnp.where(lane_of >= WINDOW_BLOCKS, WINDOW_BLOCKS, 0)
+                - jnp.where(lane_of >= 2 * WINDOW_BLOCKS, WINDOW_BLOCKS, 0))
+    block_of = jnp.where(lane_of < 3 * WINDOW_BLOCKS, block_of, -1)
+
+    def table_row(r, carry):
+        def chunk(c, acc):
+            at = pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+            word = word_ref[pl.ds(r, 1), at]                    # [1, CHUNK]
+            val = val_ref[pl.ds(r, 1), at]
+            out_lane = word & 127
+            lane = (word >> _LANE_SHIFT) & 127
+            block = (word >> _BLK_SHIFT) & 63
+            window = word >> _WIN_SHIFT
+            packed = passes_ref[(step * ROWS_PER_STEP + r) * chunks + c]
+            first = packed >> 8
+
+            def one_pass(p, got):
+                w = first + p
+                onehot = (block_of == jnp.where(window == w, block, 63)
+                          ).astype(jnp.bfloat16)                # [128, CHUNK]
+                rows = jnp.dot(
+                    vec_ref[:, pl.ds(pl.multiple_of(w * LANE, LANE), LANE)],
+                    onehot, preferred_element_type=jnp.float32)
+                # A slot is in one window: the other passes add 0 to it.
+                return got + jnp.sum(jnp.where(lane_of == lane, rows, 0.0),
+                                     axis=0, keepdims=True)
+
+            got = jax.lax.fori_loop(0, packed & 255, one_pass,
+                                    jnp.zeros((1, CHUNK), jnp.float32))
+            sel = jnp.where(lane_of == out_lane, got * val, 0.0)
+            for j in range(CHUNK // LANE):
+                acc = acc + sel[:, j * LANE:(j + 1) * LANE]
+            return acc
+
+        acc = jax.lax.fori_loop(
+            0, chunks, chunk, jnp.zeros((LANE, LANE), jnp.float32))
+        # acc[l, j]: output lane l, slot j mod 128; the row is its sum over j.
+        out_ref[pl.ds(r, 1), :] = jnp.sum(acc.T, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, ROWS_PER_STEP, table_row, 0)
+
+
+def gather_reduce(table: WindowTable, vec: Array,
+                  square_vals: bool = False) -> Array:
+    """``out[range[b]*128 + l] = Σ val · vec[g]`` over the slots of ``table``
+    that add to lane ``l`` of row ``b``'s range: ``[n_ranges * 128]`` float32.
+
+    The lookup returns ``vec``'s float32 bits (module docstring); the
+    products and both sums are float32. ``val * val`` under ``square_vals``;
+    values stored narrower are widened first.
+    """
+    return _gather_reduce(table, vec, square_vals, _interpret())
+
+
+# A program of its own inside the program that calls it: a fit holds the
+# kernel at four or five places (value and gradient, the line search, the
+# Hessian-vector product), and the places that share a table share one
+# lowering of it.
+@functools.partial(jax.jit, static_argnames=("square_vals", "interpret"))
+def _gather_reduce(table: WindowTable, vec: Array, square_vals: bool,
+                   interpret: bool) -> Array:
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, q = table.word.shape
+    val = table.val.astype(jnp.float32)
+    if square_vals:
+        val = val * val
+    parts = _window_parts(vec, table.n_windows)
+    rows = pl.BlockSpec((ROWS_PER_STEP, q), lambda i, passes: (i, 0))
+    out_b = pl.pallas_call(
+        functools.partial(_window_kernel, q=q),
+        out_shape=jax.ShapeDtypeStruct((b, LANE), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b // ROWS_PER_STEP,),
+            in_specs=[rows, rows, pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((ROWS_PER_STEP, LANE),
+                                   lambda i, passes: (i, 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(16 << 20) + 2 * parts.size),
+        name="sparse_gather_reduce",
+        interpret=interpret,
+    )(table.passes, table.word, val, parts)
+    out_r = jax.ops.segment_sum(
+        out_b, table.range, num_segments=table.n_ranges + 1,
+        indices_are_sorted=True)[: table.n_ranges]
+    return out_r.reshape(-1)
+
+
+# ------------------------------------------------------------------ the ops
 
 
 def _lane_iota() -> Array:
@@ -174,17 +559,19 @@ def _lane_iota() -> Array:
 
 @functools.partial(jax.jit, static_argnames="dim")
 def matvec_fast(aux: FastSparseAux, val: Array, w: Array, dim: int) -> Array:
-    """z[i] = Σ_k val[i,k] · w[idx[i,k]] via row-slice gather + lane select.
+    """z[i] = Σ_k val[i,k] · w[idx[i,k]] on the ``X.w`` table.
 
     One program also where the caller runs op by op (the coordinate scorers):
-    eagerly every ``[entries, 128]`` intermediate below would be materialized.
+    eagerly every intermediate below would be materialized.
     """
+    n, k = val.shape
+    if isinstance(aux.xw, WindowTable):
+        return gather_reduce(aux.xw, w)[:n]
     nblk = -(-dim // LANE)
     w2 = jnp.pad(w, (0, nblk * LANE - dim)).reshape(nblk, LANE)
     w2 = jnp.concatenate([w2, jnp.zeros((1, LANE), w.dtype)])  # ghost row
-    n, k = val.shape
-    rows = w2[aux.hi]                                  # [Np*K, 128]
-    sel = jnp.where(aux.lo[:, None] == _lane_iota(), rows, 0.0)
+    rows = w2[aux.xw.hi]                               # [Np*K, 128]
+    sel = jnp.where(aux.xw.lo[:, None] == _lane_iota(), rows, 0.0)
     picked = jnp.sum(sel, axis=-1).reshape(-1, k)      # [Np, K]
     # Narrow-stored values (bfloat16 via with_value_dtype) upcast on load:
     # the accumulation stays in w's precision, only the HBM stream shrinks.
@@ -196,31 +583,35 @@ def matvec_fast(aux: FastSparseAux, val: Array, w: Array, dim: int) -> Array:
 def rmatvec_fast(
     aux: FastSparseAux, dz: Array, dim: int, square_vals: bool = False
 ) -> Array:
-    """g[c] = Σ_{entries of column c} val · dz[row] — scatter-free.
+    """g[c] = Σ_{entries of column c} val · dz[row] on the ``X^T.r`` table —
+    scatter-free.
 
-    dz is gathered by row-slice + lane select (same trick as matvec), the
-    per-column reduction is a fused one-hot MXU contraction per 128-column
-    range, and ranges assemble with one small sorted segment-sum.
+    ``fast``: dz is gathered by row-slice + lane select (same trick as
+    matvec), the per-column reduction is a fused one-hot contraction per
+    128-column range, and ranges assemble with one small sorted segment-sum.
     """
+    t = aux.xtr
+    if isinstance(t, WindowTable):
+        return gather_reduce(t, dz, square_vals)[:dim]
     n = dz.shape[0]
-    nb = aux.n_row_blocks
+    nb = t.n_row_blocks
     dz2 = jnp.pad(dz, (0, nb * LANE - n)).reshape(nb, LANE)
-    rows = dz2[aux.cs_rhi]                             # [B, Q, 128]
+    rows = dz2[t.cs_rhi]                               # [B, Q, 128]
     iota = _lane_iota()
-    dz_at = jnp.sum(jnp.where(aux.cs_rlo[..., None] == iota, rows, 0.0), axis=-1)
+    dz_at = jnp.sum(jnp.where(t.cs_rlo[..., None] == iota, rows, 0.0), axis=-1)
     # Upcast BEFORE squaring: bfloat16-stored values must square in the
     # accumulation precision, not in 8 mantissa bits.
-    csv = aux.cs_val.astype(jnp.promote_types(aux.cs_val.dtype, dz.dtype))
+    csv = t.cs_val.astype(jnp.promote_types(t.cs_val.dtype, dz.dtype))
     v = csv * csv if square_vals else csv
     contrib = dz_at * v                                # [B, Q]
-    oh = jnp.where(aux.cs_clo[..., None] == iota, 1.0, 0.0)
+    oh = jnp.where(t.cs_clo[..., None] == iota, 1.0, 0.0)
     out_b = jnp.einsum(
         "bql,bq->bl", oh, contrib, preferred_element_type=jnp.float32
     )                                                  # [B, 128]
     out_r = jax.ops.segment_sum(
-        out_b, aux.cs_range, num_segments=aux.n_ranges + 1,
+        out_b, t.cs_range, num_segments=t.n_ranges + 1,
         indices_are_sorted=True,
-    )[: aux.n_ranges]
+    )[: t.n_ranges]
     return out_r.reshape(-1)[:dim]
 
 

@@ -1,15 +1,21 @@
 #!/usr/bin/env python3
-"""What the two XLA sparse formulations (plain gather/segment_sum and the
-fast one-hot path) give on the device in use, op by op and end to end.
+"""What the three sparse formulations (``plain`` gather/segment_sum, the
+``fast`` row-slice tables and the ``window`` one-hot kernel) give on the
+device in use, op by op and end to end.
 
     python scripts/sparse_formulation_check.py ops       # this process owns the device
     python scripts/sparse_formulation_check.py training  # children own it, one at a time
 
-``ops``: ``matvec``/``rmatvec``/``sq_rmatvec`` of both formulations at
-chip_smoke.py's fixed-effect shape against float64 NumPy (largest error
-relative to the largest entry of the answer), the first call's seconds and
-the median of five warm calls on the host's clock (dispatch, one
-``block_until_ready``; not kernel time).
+``ops``: ``matvec``/``rmatvec``/``sq_rmatvec`` of the three formulations at the
+fixed-effect shapes of the benchmark's three cells (their configurations'
+data, from the seed) and of chip_smoke.py, against float64 NumPy (largest
+error relative to the largest entry of the answer), the first call's seconds
+and the median of five warm calls on the host's clock (dispatch, one
+``block_until_ready``; not kernel time). One JSON line a shape, with the mean
+MXU passes a slot of each op's ``window`` table and what ``build_fast_aux``
+chooses by itself there: these readings are what
+``ops/fast_sparse.py`` ``WINDOW_BREAK_EVEN_PASSES`` was set from. A last line
+says whether the ``window`` lookup alone returned its operand's float32 bits.
 
 ``training``: two one-device ``game_training_driver`` runs of chip_smoke.py's
 data that differ only in the formulation — so only in the order float32
@@ -21,7 +27,8 @@ yardstick for the four-chip comparison's limits. Nothing is held to a limit
 here; every figure is printed, one JSON object per line.
 
 ``--rehearse`` runs either mode at a tiny size (on the CPU both trainings
-run the plain formulation, so it only rehearses the control flow).
+run the plain formulation, so it only rehearses the control flow; ``ops``
+runs the kernel in the Pallas interpreter).
 """
 from __future__ import annotations
 
@@ -37,52 +44,133 @@ sys.path.insert(0, HERE)
 import chip_smoke  # noqa: E402  (jax-free)
 
 
-def _ops(sizes: dict, seed: int) -> None:
-    import jax
+# The benchmark's cells, by the configuration file that holds their data.
+CELLS = {"glm_fit": "glm-logistic-l2", "glm_fit_tron": "glm-logistic-tron",
+         "game_fit": "game-logistic-user-re"}
+
+
+def _shapes(sizes: dict, seed: int, rehearse: bool):
+    """``(name, idx, val, dim)`` of every fixed-effect shape read."""
     import numpy as np
 
-    from photon_tpu.data.batch import SparseFeatures
+    from benchmarks import datagen
 
+    for cell, config in CELLS.items():
+        with open(os.path.join(HERE, "benchmarks", "configs",
+                               config + ".json")) as f:
+            data = json.load(f)["data"]
+        if rehearse:     # the cell's widths over a four-hundredth of its rows
+            data = {**data, **{key: max(1, data[key] // 400)
+                               for key in ("rows", "users") if key in data},
+                    "validation": {**data["validation"], "rows": 8}
+                    if "rows" in data["validation"] else data["validation"]}
+        train = datagen.generate(data, seed).train
+        yield (cell, train.gi.astype(np.int32), train.gv.astype(np.float32),
+               data["named_features"] + 1)
     n = sizes["n_users"] * sizes["rows_per_user"]
     k, dim = sizes["k_global"] + 1, sizes["d_global"] + 1
     rng = np.random.default_rng([seed, 9])
     idx = np.concatenate([                      # half head, half anywhere
         rng.integers(0, sizes["d_head"], size=(n, k // 2)),
         rng.integers(0, dim, size=(n, k - k // 2))], axis=1).astype(np.int32)
-    val = rng.normal(size=(n, k)).astype(np.float32)
-    w = rng.normal(size=dim).astype(np.float32)
-    v = rng.normal(size=n).astype(np.float32)
-    val64, flat = val.astype(np.float64), idx.ravel()
-    want = {
-        "matvec": (w.astype(np.float64)[idx] * val64).sum(1),
-        "rmatvec": np.bincount(
-            flat, (v.astype(np.float64)[:, None] * val64).ravel(), dim),
-        "sq_rmatvec": np.bincount(
-            flat, (v.astype(np.float64)[:, None] * val64 ** 2).ravel(), dim),
-    }
-    plain = SparseFeatures(idx=jax.device_put(idx), val=jax.device_put(val),
-                           dim=dim)
+    yield "smoke", idx, rng.normal(size=(n, k)).astype(np.float32), dim
+
+
+def _select_is_bit_exact(seed: int) -> bool:
+    """The ``window`` lookup alone (one entry a row, value 1) on float32
+    operands from 1e-30 to 1e30, zeros and negatives."""
+    import numpy as np
+
+    from photon_tpu.data.batch import SparseFeatures
+
+    rng = np.random.default_rng([seed, 10])
+    n, dim = 4096, 20000
+    x = (rng.normal(size=dim) * 10.0 ** rng.uniform(-30, 30, size=dim)
+         ).astype(np.float32)
+    x[::7] = 0.0
+    # Sorted, so that 128 rows read a narrow span and the build chooses
+    # ``window`` by itself.
+    idx = np.sort(rng.integers(0, dim, size=n)).astype(np.int32)[:, None]
+    feats = SparseFeatures(idx=idx, val=np.ones((n, 1), np.float32),
+                           dim=dim).with_fast_path()
+    assert feats.fast.formulation("matvec") == "window"
+    got = np.asarray(feats.matvec(x))
+    return bool((got.view(np.uint32) == x[idx[:, 0]].view(np.uint32)).all())
+
+
+def _ops(sizes: dict, seed: int, rehearse: bool) -> None:
+    import jax
+    import numpy as np
+
+    from photon_tpu.data.batch import SparseFeatures
+    from photon_tpu.ops import fast_sparse
+
     dev = jax.devices()[0]
-    out = {"mode": "ops", "device": dev.device_kind,
-           "platform": dev.platform, "shape": [n, k, dim]}
-    for name, feats in (("plain", plain), ("fast", plain.with_fast_path())):
-        for op, arg in (("matvec", w), ("rmatvec", v), ("sq_rmatvec", v)):
-            fn = jax.jit(lambda f, x, op=op: getattr(f, op)(x))
-            x = jax.device_put(arg)
-            t0 = time.monotonic()
-            got = fn(feats, x).block_until_ready()
-            first = time.monotonic() - t0
-            warm = []
-            for _ in range(5):
+    break_even = fast_sparse.WINDOW_BREAK_EVEN_PASSES
+    for shape, idx, val, dim in _shapes(sizes, seed, rehearse):
+        n, k = idx.shape
+        rng = np.random.default_rng([seed, 11])
+        w = rng.normal(size=dim).astype(np.float32)
+        v = rng.normal(size=n).astype(np.float32)
+        val64, flat = val.astype(np.float64), idx.ravel()
+        want = {
+            "matvec": (np.append(w.astype(np.float64), 0.0)[idx] * val64
+                       ).sum(1),
+            "rmatvec": np.bincount(
+                flat, (v.astype(np.float64)[:, None] * val64).ravel(),
+                dim + 1)[:dim],
+            "sq_rmatvec": np.bincount(
+                flat, (v.astype(np.float64)[:, None] * val64 ** 2).ravel(),
+                dim + 1)[:dim],
+        }
+        plain = SparseFeatures(idx=jax.device_put(idx),
+                               val=jax.device_put(val), dim=dim)
+        chosen = plain.with_fast_path().fast
+        out = {"mode": "ops", "device": dev.device_kind,
+               "platform": dev.platform, "shape_of": shape,
+               "shape": [n, k, dim], "break_even_passes": break_even,
+               "chosen": {op: chosen.formulation(op)
+                          for op in ("matvec", "rmatvec")}}
+        del chosen
+        # Each table formulation forced in turn, by the constant the build
+        # chooses by: a script's privilege, not an option of the program.
+        for name, forced in (("plain", None), ("fast", -1.0),
+                             ("window", float("inf"))):
+            feats = plain
+            if forced is not None:
+                fast_sparse.WINDOW_BREAK_EVEN_PASSES = forced
+                try:
+                    feats = plain.with_fast_path()
+                finally:
+                    fast_sparse.WINDOW_BREAK_EVEN_PASSES = break_even
+            if name == "window":
+                out["passes_per_slot"] = {
+                    key[len("passes_per_slot_"):]: value
+                    for key, value in feats.fast.span_arguments().items()
+                    if key.startswith("passes_per_slot_")}
+            for op, arg in (("matvec", w), ("rmatvec", v), ("sq_rmatvec", v)):
+                if feats.fast is not None and feats.fast.formulation(op) != name:
+                    continue     # no window table can hold this op's entries
+                fn = jax.jit(lambda f, x, op=op: getattr(f, op)(x))
+                x = jax.device_put(arg)
                 t0 = time.monotonic()
-                fn(feats, x).block_until_ready()
-                warm.append(time.monotonic() - t0)
-            err = np.abs(np.asarray(got, np.float64) - want[op]).max()
-            out[f"{name}.{op}"] = {
-                "max_err_rel_to_largest": float(err / np.abs(want[op]).max()),
-                "first_call_s": round(first, 3),
-                "median_warm_s": sorted(warm)[2]}
-    print(json.dumps(out), flush=True)
+                got = fn(feats, x).block_until_ready()
+                first = time.monotonic() - t0
+                warm = []
+                for _ in range(5):
+                    t0 = time.monotonic()
+                    fn(feats, x).block_until_ready()
+                    warm.append(time.monotonic() - t0)
+                err = np.abs(np.asarray(got, np.float64) - want[op]).max()
+                out[f"{name}.{op}"] = {
+                    "max_err_rel_to_largest":
+                        float(err / np.abs(want[op]).max()),
+                    "first_call_s": round(first, 3),
+                    "median_warm_s": sorted(warm)[2]}
+            del feats
+        print(json.dumps(out), flush=True)
+    print(json.dumps({"mode": "ops", "window_select_bit_exact":
+                      _select_is_bit_exact(seed)}), flush=True)
 
 
 def _training(sizes: dict, seed: int, out: str, platform: str) -> None:
@@ -134,7 +222,7 @@ def main() -> int:
     if args.mode == "ops":
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
-        _ops(sizes, args.seed)
+        _ops(sizes, args.seed, args.rehearse)
         return 0
     os.makedirs(args.out, exist_ok=True)
     try:

@@ -24,7 +24,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from photon_tpu.data.batch import LabeledBatch, SparseFeatures
-from photon_tpu.ops.fast_sparse import ROW_PAD, FastSparseAux
+from photon_tpu.ops import fast_sparse
+from photon_tpu.ops.fast_sparse import (
+    CHUNK,
+    ROW_PAD,
+    WINDOW_BLOCKS,
+    FastSparseAux,
+    RowSliceXtr,
+    RowSliceXw,
+    WindowTable,
+)
 
 N, K, D = 1 << 17, 32, 1 << 18          # fixed effect
 E, S, KU, PU = 8192, 16, 5, 16          # random-effect bucket
@@ -85,24 +94,72 @@ def _problem(spec):
 
 
 FIXED = "fixed:type=fixed,shard=global,reg=L2,reg_weights=1"
+FIXED_TRON = FIXED + ",optimizer=TRON"
 PER_USER = "perUser:type=random,re_type=userId,shard=user,reg=L2,reg_weights=1"
 
 
 def _fixed_features(sh, fast: bool, n=N, k=K, d=D,
                     cs_rows=CS_ROWS) -> SparseFeatures:
+    """The smoke's fixed effect; with ``fast`` the row-slice tables, which
+    is what ``build_fast_aux`` keeps at its uniform random columns."""
     digits = (-(-n // ROW_PAD) * ROW_PAD * k,)   # flat, whole row blocks
     aux = FastSparseAux(
-        hi=_sds(digits, "int16", sh), lo=_sds(digits, "int8", sh),
-        cs_rhi=_sds((cs_rows, Q), "int16", sh),
-        cs_rlo=_sds((cs_rows, Q), "int8", sh),
-        cs_clo=_sds((cs_rows, Q), "int8", sh),
-        cs_val=_sds((cs_rows, Q), "float32", sh),
-        cs_range=_sds((cs_rows,), "int32", sh),
-        n_ranges=-(-d // 128), n_row_blocks=-(-n // 128),
+        xw=RowSliceXw(hi=_sds(digits, "int16", sh),
+                      lo=_sds(digits, "int8", sh)),
+        xtr=RowSliceXtr(
+            cs_rhi=_sds((cs_rows, Q), "int16", sh),
+            cs_rlo=_sds((cs_rows, Q), "int8", sh),
+            cs_clo=_sds((cs_rows, Q), "int8", sh),
+            cs_val=_sds((cs_rows, Q), "float32", sh),
+            cs_range=_sds((cs_rows,), "int32", sh),
+            n_ranges=-(-d // 128), n_row_blocks=-(-n // 128)),
     ) if fast else None
     return SparseFeatures(
         idx=_sds((n, k), "int32", sh), val=_sds((n, k), "float32", sh),
         dim=d, fast=aux)
+
+
+def _window_table(sh, rows: int, q: int, n_red: int, n_gat: int):
+    return WindowTable(
+        word=_sds((rows, q), "int32", sh), val=_sds((rows, q), "float32", sh),
+        passes=_sds((rows * q // CHUNK,), "int32", sh),
+        range=_sds((rows,), "int32", sh), n_ranges=-(-n_red // 128),
+        n_windows=-(-n_gat // (WINDOW_BLOCKS * 128)))
+
+
+# The ``window`` tables as ``build_fast_aux`` shapes them from the cells'
+# data (and, forced, from the smoke's): rows, nnz, features, then the
+# [table rows, Q] of the X.w and of the X^T.r table.
+WINDOW_SHAPES = {
+    "glm_fit": (65536, 76, 47237, (2560, 2048), (2696, 2048)),
+    "glm_fit_tron": (72309, 52, 20959, (2264, 2048), (1904, 2048)),
+    "game_fit": (1002640, 8, 3765, (7840, 1024), (3936, 2048)),
+    "smoke": (N, K, D, (2048, 2048), (3072, 2048)),
+}
+
+
+def _window_features(sh, shape: str) -> SparseFeatures:
+    n, k, d, xw, xtr = WINDOW_SHAPES[shape]
+    return SparseFeatures(
+        idx=_sds((n, k), "int32", sh), val=_sds((n, k), "float32", sh), dim=d,
+        fast=FastSparseAux(xw=_window_table(sh, *xw, n, d),
+                           xtr=_window_table(sh, *xtr, d, n)))
+
+
+@pytest.fixture
+def compiled_kernel(monkeypatch):
+    """The kernel itself and not its interpreter, though the backend here is
+    a CPU (steered in the test, not by an option of the program)."""
+    monkeypatch.setattr(fast_sparse, "_interpret", lambda: False)
+
+
+def _holds_no_row_slices(compiled, n: int, k: int) -> None:
+    """The kernel is in the program, and no float32 ``[*, 128]`` value of
+    the entries' length (what the row-slice gather writes) is."""
+    text = compiled.as_text()
+    assert "sparse_gather_reduce" in text or "tpu_custom_call" in text
+    wide = [int(m) for m in re.findall(r"f32\[(\d+),128\]", text)]
+    assert all(rows < n * k // 8 for rows in wide), max(wide)
 
 
 def _fixed_batch(sh, fast: bool) -> LabeledBatch:
@@ -137,6 +194,49 @@ def test_default_sparse_path_compiles(one_chip, op, vec_len):
     jax.jit(lambda f, x: getattr(f, op)(x)).lower(feats, vec).compile()
 
 
+@pytest.mark.parametrize("op", ["matvec", "sq_rmatvec"])
+@pytest.mark.parametrize("shape", list(WINDOW_SHAPES))
+def test_window_ops_compile_and_write_no_row_slices(
+        one_chip, compiled_kernel, shape, op):
+    """``gather_reduce`` over either table at the three cells' shapes and
+    the smoke's: the chip's compiler takes it, and the program's
+    temporaries are the ``[table rows, 128]`` partials, not 512 B an entry
+    (2.87, 2.05 and 4.65 GB in the cells' fit programs before)."""
+    feats = _window_features(one_chip, shape)
+    n, k, d = WINDOW_SHAPES[shape][:3]
+    vec = _sds((d if op == "matvec" else n,), "float32", one_chip)
+    compiled = jax.jit(lambda f, x: getattr(f, op)(x)).lower(
+        feats, vec).compile()
+    _holds_no_row_slices(compiled, n, k)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("shape,spec", [
+    ("glm_fit", FIXED), ("game_fit", FIXED), ("smoke", FIXED),
+    ("glm_fit_tron", FIXED_TRON)])
+def test_fit_program_holds_the_kernel_and_no_row_slices(
+        one_chip, compiled_kernel, shape, spec):
+    """``_fit_jitted`` (L-BFGS, and TRON with its nested loops) over the
+    ``window`` tables: the kernel inside ``lax.while_loop``s compiles for
+    the chip, and the fit program's temporaries fall to a few vectors."""
+    from photon_tpu.functions.problem import _fit_jitted
+
+    feats = _window_features(one_chip, shape)
+    n, k, d = WINDOW_SHAPES[shape][:3]
+    batch = LabeledBatch(
+        features=feats, labels=_sds((n,), "float32", one_chip),
+        offsets=_sds((n,), "float32", one_chip),
+        weights=_sds((n,), "float32", one_chip))
+    vec = _sds((d,), "float32", one_chip)
+    compiled = _fit_jitted.lower(
+        _problem(spec), batch, vec, vec, None, None,
+        _sds((), "float32", one_chip)).compile()
+    _holds_no_row_slices(compiled, n, k)
+    # Read here: 19 MB (glm_fit), 31 MB (game_fit), 37 MB (smoke), 1 MB
+    # (glm_fit_tron).
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
 @pytest.mark.parametrize("n,k,d,cs_rows,temp_gb", [
     (65536, 76, 47237, 2696, 3.0),    # glm_fit: rcv1.binary's row width
     (72309, 52, 20959, 1904, 3.0),    # glm_fit_tron: real-sim whole, odd rows
@@ -144,7 +244,8 @@ def test_default_sparse_path_compiles(one_chip, op, vec_len):
 ])
 def test_matvec_reads_row_slices_as_gathered(one_chip, n, k, d, cs_rows,
                                              temp_gb):
-    """X.w at the benchmark's shapes: the gathered ``[rows*nnz, 128]`` row
+    """``fast`` X.w at the benchmark's shapes (what an op keeps over the
+    break-even): the gathered ``[rows*nnz, 128]`` row
     slices reach the lane select with no ``[rows, nnz, 128]`` relayout
     between (a physical copy where nnz is no multiple of 8: 5.23 GB of
     temporaries at the first shape before), and the flat result's reshape
@@ -158,7 +259,8 @@ def test_matvec_reads_row_slices_as_gathered(one_chip, n, k, d, cs_rows,
 
 
 def test_glm_fit_compiles(one_chip):
-    """One whole fixed-effect L-BFGS program over the fast-path batch."""
+    """One whole fixed-effect L-BFGS program over the smoke's batch, on the
+    row-slice tables its data keeps."""
     from photon_tpu.functions.problem import _fit_jitted
 
     vec = _sds((D,), "float32", one_chip)

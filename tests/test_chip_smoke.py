@@ -372,22 +372,37 @@ def test_solve_on_another_formulation_than_the_default_fails_the_smoke():
     chip_smoke._check_formulation(rep, "plain")
     with pytest.raises(chip_smoke.SmokeFailure, match="wanted the fast"):
         chip_smoke._check_formulation(rep, "fast")
+    # On the chip either table formulation will do, but one of them alone.
+    with pytest.raises(chip_smoke.SmokeFailure, match="window or fast"):
+        chip_smoke._check_formulation(rep, "window", "fast")
+    rep["sparse_op_traces"] = {"window": {"matvec": 3, "rmatvec": 2},
+                               "plain": {"matvec": 2}}
+    chip_smoke._check_formulation(rep, "window", "fast")
+    rep["sparse_op_traces"]["fast"] = {"rmatvec": 1}
+    with pytest.raises(chip_smoke.SmokeFailure, match="window or fast"):
+        chip_smoke._check_formulation(rep, "window", "fast")
 
 
-def test_sparse_ops_count_the_formulation_they_trace():
+def test_sparse_ops_count_the_formulation_they_trace(monkeypatch):
     import jax.numpy as jnp
 
     from photon_tpu.data.batch import SparseFeatures
     from photon_tpu.obs.metrics import REGISTRY
+    from photon_tpu.ops import fast_sparse
 
     counter = REGISTRY.counter("sparse_op_traces_total")
     before = {k: counter.value(op=k[0], formulation=k[1]) for k in (
-        ("matvec", "plain"), ("rmatvec", "fast"), ("sq_rmatvec", "fast"))}
+        ("matvec", "plain"), ("rmatvec", "window"), ("sq_rmatvec", "window"),
+        ("matvec", "fast"), ("rmatvec", "fast"))}
     plain = SparseFeatures(idx=jnp.zeros((8, 2), jnp.int32),
                            val=jnp.ones((8, 2), jnp.float32), dim=4)
     plain.matvec(jnp.ones(4, jnp.float32))
-    fast = plain.with_fast_path()
-    fast.rmatvec(jnp.ones(8, jnp.float32))
-    fast.sq_rmatvec(jnp.ones(8, jnp.float32))
+    tables = plain.with_fast_path()      # one window a side: the kernel
+    tables.rmatvec(jnp.ones(8, jnp.float32))
+    tables.sq_rmatvec(jnp.ones(8, jnp.float32))
+    monkeypatch.setattr(fast_sparse, "WINDOW_BREAK_EVEN_PASSES", -1.0)
+    row_slices = plain.with_fast_path()
+    row_slices.matvec(jnp.ones(4, jnp.float32))
+    row_slices.rmatvec(jnp.ones(8, jnp.float32))
     for (op, kind), n in before.items():
         assert counter.value(op=op, formulation=kind) == n + 1

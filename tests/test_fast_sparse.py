@@ -1,5 +1,7 @@
-"""Correctness of the MXU-friendly sparse fast paths (ops/fast_sparse.py)
-and the incremental-score L-BFGS variant, vs the generic implementations."""
+"""Correctness of the table formulations of the sparse pass
+(ops/fast_sparse.py: the ``window`` Pallas kernel, here in the interpreter,
+and the ``fast`` row-slice tables) and the incremental-score L-BFGS variant,
+vs the generic implementations."""
 import dataclasses
 
 import jax
@@ -10,11 +12,34 @@ import pytest
 from photon_tpu.data.batch import LabeledBatch, SparseFeatures, ell_from_rows
 from photon_tpu.functions.objective import GLMObjective
 from photon_tpu.functions.problem import GLMOptimizationProblem
-from photon_tpu.ops.fast_sparse import build_fast_aux, matvec_fast, rmatvec_fast
+from photon_tpu.ops import fast_sparse
+from photon_tpu.ops.fast_sparse import (
+    RowSliceXtr,
+    RowSliceXw,
+    WindowTable,
+    build_fast_aux,
+    gather_reduce,
+    matvec_fast,
+    rmatvec_fast,
+)
 from photon_tpu.ops.losses import loss_for_task
 from photon_tpu.optim import (LBFGS, OptimizerConfig, OptimizerType,
                               RegularizationContext, RegularizationType)
 from photon_tpu.types import TaskType
+
+
+FORMULATIONS = ["window", "fast"]
+
+
+@pytest.fixture
+def force(monkeypatch):
+    """Make ``build_fast_aux`` give every op the named table formulation,
+    by the constant it chooses by."""
+    def _force(formulation):
+        monkeypatch.setattr(
+            fast_sparse, "WINDOW_BREAK_EVEN_PASSES",
+            {"window": float("inf"), "fast": -1.0}[formulation])
+    return _force
 
 
 def _random_sparse(n, dim, k, seed=0, skew=False):
@@ -34,14 +59,17 @@ def _random_sparse(n, dim, k, seed=0, skew=False):
     return ell_from_rows(rows, dim=dim)
 
 
+@pytest.mark.parametrize("formulation", FORMULATIONS)
 @pytest.mark.parametrize("n,dim,k,skew", [
     (300, 517, 9, False), (300, 517, 9, True),
     # rows and columns off the 128 and 1,024 grids
     (300, 200, 4, False), (1000, 700, 6, False), (257, 129, 3, False)])
-def test_matvec_rmatvec_match_generic(n, dim, k, skew):
+def test_matvec_rmatvec_match_generic(force, formulation, n, dim, k, skew):
+    force(formulation)
     sf = _random_sparse(n, dim, k, seed=1, skew=skew)
     aux = build_fast_aux(np.asarray(sf.idx), np.asarray(sf.val), dim,
                          q_capacity=64)
+    assert aux.formulation("matvec") == aux.formulation("rmatvec") == formulation
     rng = np.random.default_rng(2)
     w = jnp.asarray(rng.normal(size=dim).astype(np.float32))
     v = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -156,10 +184,18 @@ def test_problem_run_uses_scored_path_and_matches():
         np.asarray(m_slow.coefficients.means), rtol=0.05, atol=0.05)
 
 
-def test_value_dtype_bfloat16_exact_for_binary_features():
+def _table_values(aux):
+    """The arrays of the attached tables that hold feature values."""
+    return [t.val if isinstance(t, WindowTable) else t.cs_val
+            for t in (aux.xw, aux.xtr) if not isinstance(t, RowSliceXw)]
+
+
+@pytest.mark.parametrize("formulation", FORMULATIONS)
+def test_value_dtype_bfloat16_exact_for_binary_features(force, formulation):
     """One-hot/binary values are exactly representable in bfloat16, so the
     narrowed storage (with_value_dtype) must reproduce f32 results bit-for-
     bit on matvec/rmatvec/sq_rmatvec."""
+    force(formulation)
     n, dim = 200, 300
     rng = np.random.default_rng(11)
     rows = [(np.unique(rng.integers(0, dim, size=5)).tolist(), None)
@@ -168,7 +204,8 @@ def test_value_dtype_bfloat16_exact_for_binary_features():
     sf = ell_from_rows(rows, dim=dim).with_fast_path(q_capacity=128)
     nf = sf.with_value_dtype(jnp.bfloat16)
     assert nf.val.dtype == jnp.bfloat16
-    assert nf.fast.cs_val.dtype == jnp.bfloat16
+    assert [v.dtype for v in _table_values(nf.fast)] == (
+        [jnp.bfloat16] * (2 if formulation == "window" else 1))
 
     w = jnp.asarray(rng.normal(size=dim).astype(np.float32))
     v = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -179,10 +216,13 @@ def test_value_dtype_bfloat16_exact_for_binary_features():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_value_dtype_bfloat16_close_for_continuous_features():
+@pytest.mark.parametrize("formulation", FORMULATIONS)
+def test_value_dtype_bfloat16_close_for_continuous_features(force,
+                                                            formulation):
     """Continuous values round to 8 mantissa bits; results must stay within
     bf16 quantization error of the f32 path, including the square path
     (which must upcast BEFORE squaring)."""
+    force(formulation)
     n, dim, k = 300, 517, 9
     sf = _random_sparse(n, dim, k, seed=12).with_fast_path(q_capacity=64)
     nf = sf.with_value_dtype(jnp.bfloat16)
@@ -240,13 +280,15 @@ def test_glm_fit_with_bfloat16_values_converges_close():
         np.asarray(m32.coefficients.means), rtol=0.1, atol=0.1)
 
 
-def test_value_dtype_then_fast_path_casts_column_table():
+@pytest.mark.parametrize("formulation", FORMULATIONS)
+def test_value_dtype_then_fast_path_casts_the_tables(force, formulation):
     """Attach order must not matter: narrowing BEFORE with_fast_path still
-    yields a bf16 column-sorted table (the builder emits f32)."""
+    yields bf16 values in the tables (the builder emits f32)."""
+    force(formulation)
     sf = _random_sparse(80, 96, 5, seed=17)
     nf = sf.with_value_dtype(jnp.bfloat16).with_fast_path(q_capacity=32)
     assert nf.val.dtype == jnp.bfloat16
-    assert nf.fast.cs_val.dtype == jnp.bfloat16
+    assert all(v.dtype == jnp.bfloat16 for v in _table_values(nf.fast))
     rng = np.random.default_rng(18)
     w = jnp.asarray(rng.normal(size=96).astype(np.float32))
     # Same result as narrowing after attach.
@@ -255,7 +297,7 @@ def test_value_dtype_then_fast_path_casts_column_table():
                                   np.asarray(other.matvec(w)))
 
 
-def test_digit_dtype_narrows_and_results_match():
+def test_digit_dtype_narrows_and_results_match(force):
     """Small spaces store >>7 digits as int16 (pure-HBM-stream halving);
     the threshold leaves room for the ghost block, and results are
     unchanged vs the generic path (covered by the match tests, which now
@@ -267,19 +309,24 @@ def test_digit_dtype_narrows_and_results_match():
     assert _digit_dtype(np.iinfo(np.int16).max) == np.int32      # would clip
     assert _digit_dtype(1 << 20) == np.int32
 
+    force("fast")
     sf = _random_sparse(300, 517, 9, seed=19)
     aux = build_fast_aux(np.asarray(sf.idx), np.asarray(sf.val), 517,
                          q_capacity=64)
-    assert aux.hi.dtype == jnp.int16
-    assert aux.cs_rhi.dtype == jnp.int16
+    assert aux.xw.hi.dtype == jnp.int16
+    assert aux.xtr.cs_rhi.dtype == jnp.int16
 
 
+@pytest.mark.parametrize("formulation", FORMULATIONS)
 @pytest.mark.parametrize("value_dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("nnz", [5, 8, 52, 75, 76])
-def test_matvec_fast_matches_float64_gather_at_row_width(nnz, value_dtype):
-    """The flat lane select against the plain gather in float64, at row
-    widths on and off a multiple of 8, an odd row count (72,309's kind),
-    ghost entries in some rows and values stored narrow."""
+def test_matvec_fast_matches_float64_gather_at_row_width(
+        force, formulation, nnz, value_dtype):
+    """X.w (the kernel's row-range table, and the flat lane select) against
+    the plain gather in float64, at row widths on and off a multiple of 8,
+    an odd row count (72,309's kind), ghost entries in some rows and values
+    stored narrow."""
+    force(formulation)
     n, dim = 389, 3 * 128 + 77
     rng = np.random.default_rng(nnz)
     idx = np.full((n, nnz), dim, np.int32)
@@ -291,8 +338,11 @@ def test_matvec_fast_matches_float64_gather_at_row_width(nnz, value_dtype):
     assert (idx == dim).any()
     sf = SparseFeatures(idx=jnp.asarray(idx), val=jnp.asarray(val),
                         dim=dim).with_value_dtype(value_dtype)
-    aux = build_fast_aux(idx, np.asarray(sf.val), dim, q_capacity=64)
-    assert aux.hi.ndim == aux.lo.ndim == 1
+    aux = sf.with_fast_path(q_capacity=64).fast
+    if formulation == "fast":
+        assert aux.xw.hi.ndim == aux.xw.lo.ndim == 1
+    else:
+        assert aux.xw.val.dtype == value_dtype
     w = np.random.default_rng(nnz + 1).normal(size=dim).astype(np.float32)
     stored = np.asarray(sf.val.astype(jnp.float32), np.float64)
     want = np.sum(stored * np.append(w.astype(np.float64), 0.0)[idx], axis=1)
@@ -301,16 +351,17 @@ def test_matvec_fast_matches_float64_gather_at_row_width(nnz, value_dtype):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
 
 
-def test_matvec_fast_lowers_with_no_rank3_row_slices():
-    """At glm_fit's shape the gather writes ``[rows*nnz, 128]`` and the
-    lane select reads it as written: no ``[rows, nnz, 128]`` value (a
+def test_matvec_fast_lowers_with_no_rank3_row_slices(force):
+    """``fast`` at glm_fit's shape: the gather writes ``[rows*nnz, 128]`` and
+    the lane select reads it as written: no ``[rows, nnz, 128]`` value (a
     physical copy on the TPU, whose tiles pad 76 to 80) is in the program."""
+    force("fast")
     n, k, dim = 65536, 76, 47237
     aux = build_fast_aux(np.full((8, k), dim), np.zeros((8, k), np.float32),
                          dim, q_capacity=8)
-    flat = jax.ShapeDtypeStruct((n * k,), jnp.int16)
-    aux = dataclasses.replace(
-        aux, hi=flat, lo=jax.ShapeDtypeStruct((n * k,), jnp.int8))
+    aux = dataclasses.replace(aux, xw=RowSliceXw(
+        hi=jax.ShapeDtypeStruct((n * k,), jnp.int16),
+        lo=jax.ShapeDtypeStruct((n * k,), jnp.int8)))
     text = matvec_fast.lower(
         aux, jax.ShapeDtypeStruct((n, k), jnp.float32),
         jax.ShapeDtypeStruct((dim,), jnp.float32), dim).as_text()
@@ -337,11 +388,14 @@ def _scatter64(idx, val, dz, d, square=False):
     return out[:d]
 
 
+@pytest.mark.parametrize("formulation", FORMULATIONS)
 @pytest.mark.parametrize("case", ["duplicate_in_row", "column_in_every_row"])
-def test_fast_ops_match_float64_on_duplicate_and_hot_columns(case):
+def test_fast_ops_match_float64_on_duplicate_and_hot_columns(
+        force, formulation, case):
     """A column id twice in one row: its entries add up. One column in
     every row (as the intercept is in every cell): its 128-column range
     holds more entries than ``q_capacity`` and spills over table rows."""
+    force(formulation)
     rng = np.random.default_rng(0)
     n, d, k, q = 400, 100, 5, 64
     idx, val = _ell(rng, n, d, k, ghost_frac=0.0)
@@ -351,8 +405,15 @@ def test_fast_ops_match_float64_on_duplicate_and_hot_columns(case):
         idx[:, 0] = 7
     sf = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), d).with_fast_path(
         q_capacity=q)
-    # d = 100 is one 128-column range; its 2,000 entries fill 32 table rows.
-    assert int((np.asarray(sf.fast.cs_range) == 0).sum()) == -(-n * k // q)
+    # d = 100 is one 128-column range; its 2,000 entries fill 32 table rows
+    # of 64 (``fast``) or 4 of the kernel's narrowest, one chunk.
+    if formulation == "fast":
+        assert int((np.asarray(sf.fast.xtr.cs_range) == 0).sum()) == (
+            -(-n * k // q))
+    else:
+        assert sf.fast.xtr.word.shape[1] == fast_sparse.CHUNK
+        assert int((np.asarray(sf.fast.xtr.range) == 0).sum()) == (
+            -(-n * k // fast_sparse.CHUNK))
     w = rng.normal(size=d).astype(np.float32)
     dz = rng.normal(size=n).astype(np.float32)
     z64 = np.sum(val.astype(np.float64)
@@ -367,10 +428,11 @@ def test_fast_ops_match_float64_on_duplicate_and_hot_columns(case):
         _scatter64(idx, val, dz, d, square=True), rtol=0, atol=5e-5)
 
 
+@pytest.mark.parametrize("formulation", FORMULATIONS)
 @pytest.mark.parametrize("square_vals", [False, True])
 @pytest.mark.parametrize("n", [257, 1000, 1025])
 def test_rmatvec_fast_matches_float64_scatter_off_the_row_grids(
-        n, square_vals):
+        force, formulation, n, square_vals):
     """X^T.r against a float64 scatter at row counts off the 128-row blocks
     of the ``dz`` table and off ``ROW_PAD`` (one row past it at 1,025), ghost
     entries in some rows: the mirror of the X.w test above."""
@@ -378,8 +440,12 @@ def test_rmatvec_fast_matches_float64_scatter_off_the_row_grids(
     rng = np.random.default_rng(n)
     idx, val = _ell(rng, n, dim, k)
     assert (idx == dim).any()
+    force(formulation)
     aux = build_fast_aux(idx, val, dim, q_capacity=64)
-    assert aux.n_row_blocks == -(-n // 128)
+    if formulation == "fast":
+        assert aux.xtr.n_row_blocks == -(-n // 128)
+    else:
+        assert aux.xtr.n_ranges == -(-dim // 128)
     dz = rng.normal(size=n).astype(np.float32)
     got = rmatvec_fast(aux, jnp.asarray(dz), dim, square_vals=square_vals)
     assert got.shape == (dim,) and got.dtype == jnp.float32
@@ -439,3 +505,276 @@ def test_estimator_attaches_accelerator_paths(monkeypatch):
     assert attached == [
         {"idx": True, "val": True, "dim": True, "fast": True}]
     np.testing.assert_allclose(w_acc, w_plain, rtol=0, atol=2e-3)
+
+
+# ------------------------------------------- the ``window`` kernel (PR 31)
+
+
+def _cell_like(seed, n, k, dim, head, ghost_frac=0.05):
+    """ELL arrays in the benchmark generator's pattern: half of a row's
+    named entries in a popular head, half anywhere, the last column an
+    intercept in every row; some entries ghosts."""
+    rng = np.random.default_rng(seed)
+    named = k - 1
+    idx = np.concatenate([
+        rng.integers(0, head, size=(n, named // 2)),
+        rng.integers(0, dim - 1, size=(n, named - named // 2)),
+        np.full((n, 1), dim - 1)], axis=1).astype(np.int32)
+    val = rng.normal(size=(n, k)).astype(np.float32) / np.sqrt(k)
+    ghost = rng.random((n, k)) < ghost_frac
+    ghost[:, -1] = False
+    return (np.where(ghost, dim, idx).astype(np.int32),
+            np.where(ghost, 0, val).astype(np.float32))
+
+
+# Scaled-down shapes of the three cells and of the smoke: the row widths of
+# each (76, 52, 8, 32), a row count off every grid (72,309's kind), vectors
+# over more than one 5,376-element window on the side each cell has them.
+CELL_SHAPES = {
+    "glm_fit": (565, 76, 6001, 1024),
+    "glm_fit_tron": (723, 52, 2100, 1024),
+    "game_fit": (6003, 8, 377, 64),
+    "smoke": (1024, 32, 12000, 1024),
+}
+
+
+@pytest.mark.parametrize("op", ["matvec", "rmatvec", "sq_rmatvec"])
+@pytest.mark.parametrize("shape", list(CELL_SHAPES))
+def test_window_ops_match_plain_at_the_cells_shapes(force, shape, op):
+    force("window")
+    n, k, dim, head = CELL_SHAPES[shape]
+    idx, val = _cell_like(31, n, k, dim, head)
+    assert (idx == dim).any()
+    plain = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
+    feats = plain.with_fast_path()
+    assert feats.fast.formulation(op) == "window"
+    rng = np.random.default_rng(32)
+    x = jnp.asarray(rng.normal(size=dim if op == "matvec" else n)
+                    .astype(np.float32))
+    want = np.asarray(getattr(plain, op)(x))
+    got = getattr(feats, op)(x)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("where", ["jit", "while_loop"])
+def test_window_ops_under_jit_and_inside_a_while_loop(force, where):
+    """The kernel traced into a larger program, as ``_fit_jitted`` holds it
+    (L-BFGS's and TRON's loops are ``lax.while_loop``s)."""
+    force("window")
+    n, k, dim, head = CELL_SHAPES["glm_fit_tron"]
+    idx, val = _cell_like(33, n, k, dim, head)
+    plain = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
+    feats = plain.with_fast_path()
+    w0 = jnp.asarray(np.random.default_rng(34).normal(size=dim)
+                     .astype(np.float32))
+
+    def step(f, w):
+        return w - 0.01 * f.rmatvec(jnp.tanh(f.matvec(w)))
+
+    if where == "jit":
+        run = jax.jit(step)
+    else:
+        def run(f, w):
+            return jax.lax.while_loop(
+                lambda c: c[0] < 3, lambda c: (c[0] + 1, step(f, c[1])),
+                (0, w))[1]
+        run = jax.jit(run)
+    np.testing.assert_allclose(np.asarray(run(feats, w0)),
+                               np.asarray(run(plain, w0)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _one_entry_a_row(n, dim, seed):
+    """Features whose ``X.w`` is a pure lookup: row i reads ``w[idx[i]]``
+    with value 1. Sorted, so that 128 rows read a narrow span."""
+    rng = np.random.default_rng(seed)
+    idx = np.sort(rng.integers(0, dim, size=n)).astype(np.int32)[:, None]
+    return idx, SparseFeatures(jnp.asarray(idx), jnp.ones((n, 1), jnp.float32),
+                               dim)
+
+
+@pytest.mark.parametrize("values", [
+    "1e-30_to_1e30", "negatives", "zeros_between", "integers", "ulps_of_one"])
+def test_window_select_returns_the_float32_bits(force, values):
+    """The lookup alone: three exact bfloat16 parts through a one-hot
+    product give back the operand's float32, bit for bit."""
+    force("window")
+    n, dim = 700, 12000          # w over three windows
+    rng = np.random.default_rng(35)
+    x = rng.normal(size=dim) * 10.0 ** rng.uniform(-30, 30, size=dim)
+    if values == "negatives":
+        x = -np.abs(x)
+    elif values == "zeros_between":
+        x[rng.random(dim) < 0.5] = 0.0
+    elif values == "integers":
+        x = rng.integers(-2 ** 24, 2 ** 24, size=dim)
+    elif values == "ulps_of_one":
+        x = 1.0 + rng.integers(-64, 64, size=dim) * 2.0 ** -23
+    x = x.astype(np.float32)
+    idx, feats = _one_entry_a_row(n, dim, 36)
+    feats = feats.with_fast_path()
+    assert feats.fast.formulation("matvec") == "window"
+    got = np.asarray(feats.matvec(jnp.asarray(x)))
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  x[idx[:, 0]].view(np.uint32))
+
+
+@pytest.mark.parametrize("shape,chosen", [("narrow", "window"),
+                                          ("wide", "fast")])
+def test_build_counts_passes_a_slot_and_chooses_by_them(shape, chosen):
+    """Columns in a narrow head read one window a chunk; columns anywhere
+    in forty windows read twenty of them (a range's 1,024 sorted entries are
+    two chunks), and the op keeps the row-slice table."""
+    n, k, dim = 1024, 8, 40 * fast_sparse.WINDOW_BLOCKS * 128
+    rng = np.random.default_rng(37)
+    idx = rng.integers(0, dim, size=(n, k)).astype(np.int32)
+    if shape == "narrow":
+        idx %= 1024
+    val = rng.normal(size=(n, k)).astype(np.float32)
+    forced = fast_sparse._window_table(
+        *fast_sparse._sorted_by_row_block(idx, val, dim), n, dim, 2048)
+    if shape == "narrow":
+        assert forced.passes_per_slot() == 1.0
+    else:
+        assert forced.passes_per_slot() > 10
+    aux = build_fast_aux(idx, val, dim)
+    assert aux.formulation("matvec") == chosen
+    assert isinstance(aux.xw, WindowTable if chosen == "window" else RowSliceXw)
+    # X^T.r gathers by row: 1,024 rows are one window, whatever the columns.
+    assert aux.formulation("rmatvec") == "window"
+    assert aux.xtr.passes_per_slot() == 1.0
+    args = aux.span_arguments()
+    assert args["formulation_matvec"] == chosen
+    assert args["formulation_rmatvec"] == "window"
+    assert ("passes_per_slot_matvec" in args) == (chosen == "window")
+    assert args["passes_per_slot_rmatvec"] == 1.0
+
+
+def test_a_vector_too_long_for_vmem_keeps_the_row_slice_table(monkeypatch):
+    monkeypatch.setattr(fast_sparse, "WINDOW_VMEM_VECTOR_BYTES", 6 * 5376)
+    idx, val = _cell_like(38, 300, 6, 6000, 64)
+    aux = build_fast_aux(idx, val, 6000)
+    assert isinstance(aux.xw, RowSliceXw)          # w: two windows
+    assert isinstance(aux.xtr, WindowTable)        # dz: one
+
+
+@pytest.mark.parametrize("op", ["matvec", "rmatvec"])
+def test_padding_slots_contribute_nothing(force, op):
+    """A table is mostly padding at this size (3 entries a range in rows of
+    512 slots): the padding's value is 0 and its lookup lands on element 0
+    of the vector, which may be as large as float32 goes."""
+    force("window")
+    n, dim = 300, 400
+    idx = np.full((n, 2), dim, np.int32)
+    val = np.zeros((n, 2), np.float32)
+    idx[1::100, 0], val[1::100, 0] = [5, 140, 399], [1.0, 2.0, 3.0]
+    feats = SparseFeatures(jnp.asarray(idx), jnp.asarray(val),
+                           dim).with_fast_path()
+    table = feats.fast.xw if op == "matvec" else feats.fast.xtr
+    live = np.asarray(table.val) != 0
+    assert live.sum() == 3 and live.size >= 3 * fast_sparse.CHUNK
+    assert not np.asarray(table.word)[~live].any()
+    x = np.ones(dim if op == "matvec" else n, np.float32)
+    x[0] = 3e38
+    got = np.asarray(getattr(feats, op)(jnp.asarray(x)))
+    want = np.zeros_like(got)
+    if op == "matvec":
+        want[1::100] = [1.0, 2.0, 3.0]
+    else:
+        want[[5, 140, 399]] = [1.0, 2.0, 3.0]
+    np.testing.assert_array_equal(got, want)
+
+
+def test_gather_reduce_is_the_one_kernel_of_both_ops(force):
+    """``matvec`` and ``rmatvec`` are ``gather_reduce`` on mirrored tables."""
+    force("window")
+    n, k, dim, head = CELL_SHAPES["glm_fit_tron"]
+    idx, val = _cell_like(39, n, k, dim, head)
+    aux = build_fast_aux(idx, val, dim)
+    rng = np.random.default_rng(40)
+    w = jnp.asarray(rng.normal(size=dim).astype(np.float32))
+    dz = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(gather_reduce(aux.xw, w))[:n],
+        np.asarray(matvec_fast(aux, jnp.asarray(val), w, dim)))
+    np.testing.assert_array_equal(
+        np.asarray(gather_reduce(aux.xtr, dz, square_vals=True))[:dim],
+        np.asarray(rmatvec_fast(aux, dz, dim, square_vals=True)))
+    # Mirrors: the same live entries, each table sorted for its own side.
+    assert (np.asarray(aux.xw.val) != 0).sum() == (
+        np.asarray(aux.xtr.val) != 0).sum() == (val != 0).sum()
+
+
+def test_window_table_takes_plain_for_an_operand_that_is_not_float32(force):
+    """The kernel is float32; a float64 operand (x64 runs off the chip)
+    runs the op's ``plain`` arm in its own precision, and is counted so."""
+    from photon_tpu.obs.metrics import REGISTRY
+
+    force("window")
+    idx, val = _cell_like(41, 200, 6, 300, 32)
+    feats = SparseFeatures(jnp.asarray(idx), jnp.asarray(val),
+                           300).with_fast_path()
+    counter = REGISTRY.counter("sparse_op_traces_total", "")
+    before = {kind: counter.value(op="matvec", formulation=kind)
+              for kind in ("window", "plain")}
+    w64 = jnp.asarray(np.random.default_rng(42).normal(size=300), jnp.float64)
+    z64 = feats.matvec(w64)
+    assert z64.dtype == jnp.float64
+    feats.matvec(w64.astype(jnp.float32))
+    assert counter.value(op="matvec", formulation="plain") == before["plain"] + 1
+    assert counter.value(op="matvec", formulation="window") == (
+        before["window"] + 1)
+    want = (np.append(np.asarray(w64), 0.0)[idx] * val.astype(np.float64)).sum(1)
+    np.testing.assert_allclose(np.asarray(z64), want, rtol=1e-12, atol=1e-12)
+
+
+def _column_table_by_loop(idx, val, dim, q_capacity):
+    """The row-slice X^T.r table as a Python loop over column ranges built
+    it until PR 31: the reference the vectorised build is held to."""
+    n, k = idx.shape
+    n_col_blocks = -(-dim // 128)
+    flat_col = idx.ravel()
+    keep = flat_col < dim
+    cols = flat_col[keep].astype(np.int64)
+    rows = np.repeat(np.arange(n, dtype=np.int64), k)[keep]
+    vals = val.ravel()[keep]
+    order = np.argsort(cols >> 7, kind="stable")
+    cols, rows, vals = cols[order], rows[order], vals[order]
+    counts = np.bincount(cols >> 7, minlength=n_col_blocks)
+    rows_per_range = np.maximum(1, -(-counts // q_capacity))
+    b_pad = -(-int(rows_per_range.sum()) // 8) * 8
+    out = {name: np.zeros((b_pad, q_capacity), dt) for name, dt in (
+        ("cs_rhi", np.int64), ("cs_rlo", np.int64), ("cs_clo", np.int64),
+        ("cs_val", np.float32))}
+    cs_range = np.full((b_pad,), n_col_blocks, np.int32)
+    starts = np.concatenate([[0], np.cumsum(counts)])
+    b = 0
+    for r in range(n_col_blocks):
+        lo_e, hi_e = int(starts[r]), int(starts[r + 1])
+        for off in range(lo_e, max(hi_e, lo_e + 1), q_capacity):
+            sl = slice(off, min(off + q_capacity, hi_e))
+            m = sl.stop - sl.start
+            out["cs_rhi"][b, :m] = rows[sl] >> 7
+            out["cs_rlo"][b, :m] = rows[sl] & 127
+            out["cs_clo"][b, :m] = cols[sl] & 127
+            out["cs_val"][b, :m] = vals[sl]
+            cs_range[b] = r
+            b += 1
+    return out, cs_range
+
+
+@pytest.mark.parametrize("n,k,dim,q", [(400, 5, 300, 64), (1000, 6, 700, 32),
+                                       (257, 3, 129, 2048)])
+def test_vectorised_table_build_equals_the_loop_it_replaced(force, n, k, dim,
+                                                            q):
+    force("fast")
+    idx, val = _ell(np.random.default_rng(n), n, dim, k)
+    idx[:, 0] = 7                      # a column in every row: many table rows
+    val[:, 0] = 1.0
+    want, want_range = _column_table_by_loop(idx, val, dim, q)
+    got = build_fast_aux(idx, val, dim, q_capacity=q).xtr
+    np.testing.assert_array_equal(np.asarray(got.cs_range), want_range)
+    for name, table in want.items():
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)), table)

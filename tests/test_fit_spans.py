@@ -155,7 +155,12 @@ def test_span_arguments_say_what_the_work_was(two_fits):
     assert by_name["estimator.fit"]["configs"] == 1
     assert by_name["estimator.prepare"]["shards"] == 2
     assert by_name["data.accel_tables"]["entries"] == 72 * 5
-    assert by_name["data.accel_tables"]["formulation"] == "fast"
+    # 72 rows by 16 columns are one window on either side: the kernel.
+    tables = by_name["data.accel_tables"]
+    assert tables["formulation_matvec"] == "window"
+    assert tables["formulation_rmatvec"] == "window"
+    assert tables["passes_per_slot_matvec"] == 1.0
+    assert tables["passes_per_slot_rmatvec"] == 1.0
     build = [{s[NAME]: s[ARGS] for s in tree}["estimator.build_coordinates"]
              for tree in two_fits]
     assert [(b["tables_built"], b["tables_reused"]) for b in build] == [

@@ -1384,6 +1384,12 @@ def test_tail_sampler_promotes_through_real_request_path(trained):
                 status, _ = _post(host, port, "/score",
                                   _payload(recs[i % len(recs)]))
                 assert status == 200
+        # The handler closes its span after the response is on the wire:
+        # the last request may still be in flight when the client has it.
+        deadline = time.monotonic() + 10.0
+        while (sampler.snapshot()["inflight"]
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         snap = sampler.snapshot()
         assert snap["inflight"] == 0
         assert snap["promoted"] >= 1
