@@ -28,6 +28,7 @@ passive data, scored but not trained on.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -94,12 +95,49 @@ class EntityBucket:
     def scores(self, coefs: Array) -> Array:
         """Per-slot scores [E, S] from per-entity coefficients [E, P]
         (offsets NOT included — GAME composes scores additively)."""
+        return _bucket_scores(self.idx, self.val, coefs)
+
+
+# The widest local dimension at which an entry's column is picked by
+# compare-select over all the columns (``_bucket_scores``, and the dense
+# design of ``game/newton_re.py``). Both GAME cells run at 32, where the
+# pick is read on the chip; from 33 columns on the CPU's compiler no longer
+# fuses the scorer's pick and holds it whole, [E,S,K,P] float32 (0.44 GB at
+# 4,096 x 256 x 3 x 33 for nothing at 32; PERF.md §6, PR 33). Wider, the
+# gather and the scatter-add as they were: no cell runs there, and on the
+# chip alone the pick still wins at 256 (PERF.md §6; §7 item 11).
+SELECT_MAX_COLUMNS = 32
+
+
+@jax.jit
+def _bucket_scores(idx: Array, val: Array, coefs: Array) -> Array:
+    """``sum_k val[e,s,k] * coefs[e, idx[e,s,k]]``, the ghost column (== P)
+    counting for nothing. Up to ``SELECT_MAX_COLUMNS`` local columns the
+    coefficient of an entry is picked by compare-select over the columns,
+    fused into the sum, and not by a gather: a batched gather over [E,S,K]
+    indices compiles on the TPU's compiler in time that grows with the
+    slots (309 s and 3.37 GB of temporaries at 12,874 x 256 x 3 for a
+    described v5e; 0.75 s and none by the pick: PERF.md §6, PR 33). The
+    pick is exact: one coefficient and zeros."""
+    if coefs.shape[1] > SELECT_MAX_COLUMNS:
         ext = jnp.concatenate([coefs, jnp.zeros_like(coefs[:, :1])], axis=1)
+        return jax.vmap(lambda w, i, v: jnp.sum(w[i] * v, axis=-1))(
+            ext, idx, val)
+    columns = jnp.arange(coefs.shape[1], dtype=idx.dtype)
+    picked = jnp.sum(
+        jnp.where(idx[..., None] == columns, coefs[:, None, None, :], 0),
+        axis=-1)
+    return jnp.sum(picked * val, axis=-1)
 
-        def one(w_ext, idx, val):
-            return jnp.sum(w_ext[idx] * val, axis=-1)
 
-        return jax.vmap(one)(ext, self.idx, self.val)
+@functools.partial(jax.jit, static_argnames="n_rows")
+def _scatter_slots(per_bucket_scores, per_bucket_row_ids, n_rows: int):
+    """One scatter of every bucket's [E, S] slots into the ``n_rows`` rows
+    they came from; padding slots point at a ghost row past the last. One
+    program a dataset, where a scatter a bucket was one a size class."""
+    flat = jnp.concatenate([s.ravel() for s in per_bucket_scores])
+    rows = jnp.concatenate([r.ravel() for r in per_bucket_row_ids])
+    return jnp.zeros((n_rows + 1,), flat.dtype).at[rows].set(flat)[:n_rows]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,7 +145,9 @@ class RandomEffectDataset:
     """All buckets for one random-effect coordinate + host-side entity index.
 
     ``entity_to_slot`` maps entity key → (bucket_index, lane); ``n_rows`` is
-    the global sample count the ``row_ids`` refer to.
+    the global sample count the ``row_ids`` refer to. ``bucket_rows`` is the
+    number of real rows each bucket holds (its row slots less the padding),
+    as the builder counted them.
     """
 
     re_type: str                      # entity column name, e.g. "userId"
@@ -116,18 +156,31 @@ class RandomEffectDataset:
     entity_to_slot: dict              # dense REId -> (bucket, lane)
     n_rows: int
     global_dim: int
+    bucket_rows: Sequence[int]        # per bucket: real rows
 
     @property
     def n_entities(self) -> int:
         return len(self.entity_keys)
 
+    @property
+    def row_slots(self) -> int:
+        """Entities x padded rows, summed over the buckets."""
+        return sum(b.n_entities * b.max_samples for b in self.buckets)
+
+    def span_arguments(self) -> dict:
+        """``{buckets, rows, row_slots}``: how many buckets a step over
+        this dataset solves, the real rows in them and the padded row
+        slots."""
+        return {"buckets": len(self.buckets),
+                "rows": int(sum(self.bucket_rows)),
+                "row_slots": self.row_slots}
+
     def scatter_scores(self, per_bucket_scores: Sequence[Array]) -> Array:
         """Assemble a global [n_rows] score vector from per-bucket [E, S]
         scores (padding slots point at the ghost row and are dropped)."""
-        out = jnp.zeros((self.n_rows + 1,), per_bucket_scores[0].dtype)
-        for b, s in zip(self.buckets, per_bucket_scores):
-            out = out.at[b.row_ids.ravel()].set(s.ravel())
-        return out[: self.n_rows]
+        return _scatter_slots(
+            list(per_bucket_scores), [b.row_ids for b in self.buckets],
+            n_rows=self.n_rows)
 
 
 def down_sample_dataset(
@@ -244,7 +297,7 @@ def build_random_effect_dataset(
     if e_count == 0:
         return RandomEffectDataset(
             re_type=re_type, buckets=(), entity_keys=[], entity_to_slot={},
-            n_rows=n, global_dim=global_dim,
+            n_rows=n, global_dim=global_dim, bucket_rows=(),
         )
     new_id = np.full(len(keys), -1, np.int64)
     new_id[kept] = np.arange(e_count)
@@ -366,6 +419,7 @@ def build_random_effect_dataset(
     cols_flat = upairs % stride
 
     buckets = []
+    bucket_rows = []
     entity_keys_out = list(keys[kept][ent_sort])
     entity_to_slot = {}
     for b, (mb, me) in enumerate(zip(bucket_starts[:-1], bucket_starts[1:])):
@@ -415,6 +469,7 @@ def build_random_effect_dataset(
                     np.arange(mb + lo, mb + hi, dtype=np.int32)
                 ),
             ))
+            bucket_rows.append(int(rstarts[mb + hi] - rstarts[mb + lo]))
 
     return RandomEffectDataset(
         re_type=re_type,
@@ -423,6 +478,7 @@ def build_random_effect_dataset(
         entity_to_slot=entity_to_slot,
         n_rows=n,
         global_dim=global_dim,
+        bucket_rows=tuple(bucket_rows),
     )
 
 
@@ -580,6 +636,7 @@ def _build_reference_loop(
         bucket_map.setdefault(key, []).append(ent)
 
     buckets = []
+    bucket_rows = []
     entity_keys_out = []
     entity_to_slot = {}
     for (s_pad, p_pad), members in sorted(bucket_map.items()):
@@ -614,6 +671,7 @@ def _build_reference_loop(
             train_weights=jnp.asarray(b_tw), row_ids=jnp.asarray(b_rows),
             proj=jnp.asarray(b_proj), entity_ids=jnp.asarray(b_eids),
         ))
+        bucket_rows.append(sum(len(ent[1]) for ent in members))
 
     return RandomEffectDataset(
         re_type=re_type,
@@ -622,4 +680,5 @@ def _build_reference_loop(
         entity_to_slot=entity_to_slot,
         n_rows=n,
         global_dim=global_dim,
+        bucket_rows=tuple(bucket_rows),
     )

@@ -154,6 +154,11 @@ class RandomEffectCoordinate:
             priors=self.priors,
         )
 
+    def span_arguments(self) -> dict:
+        """What a step of this coordinate works on, for its
+        ``descent.step`` span."""
+        return self.dataset.span_arguments()
+
     def score(self, model: RandomEffectModel) -> Array:
         dataset = self._data()
         if self._same_structure(model):

@@ -285,9 +285,12 @@ class CoordinateDescent:
                             np.asarray(new_score[:1])
                             # The step is done: what TRON counted on the
                             # device goes on the span (no other solver's
-                            # step carries these arguments).
+                            # step carries these arguments), with what the
+                            # coordinate says of the data it stepped over.
                             tron_counters = _tron_counters(solve_result)
-                            step_span.set(**tron_counters)
+                            step_span.set(
+                                **tron_counters,
+                                **getattr(coord, "span_arguments", dict)())
                         total = new_total
                         scores[cid] = new_score
                         models[cid] = model

@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu.data.random_effect import SELECT_MAX_COLUMNS
 from photon_tpu.models.coefficients import Coefficients
 from photon_tpu.models.glm import GeneralizedLinearModel
 from photon_tpu.ops.losses import loss_for_task
@@ -310,18 +311,32 @@ def dual_eligible(problem, bucket, normalization, u_max: int,
 
 @jax.named_scope("newton.design")
 def _dense_design(batches, dtype):
-    """Dense local design [E,S,P+1] via scatter-add — the ELL ghost column
-    (== P) lands in the extra zero column. ONE buffer replaces per-probe
+    """Dense local design [E,S,P+1] — the ELL ghost column (== P) lands in
+    the extra column, whose values are zero. ONE buffer replaces per-probe
     ELL gathers for the whole solve. Also returns (y, off, tw) in ``dtype``
-    (the solve precision — f64 datasets keep full precision, ADVICE r5)."""
+    (the solve precision — f64 datasets keep full precision, ADVICE r5).
+
+    Up to ``SELECT_MAX_COLUMNS`` local columns it is built by
+    compare-select, not by a scatter: entry k of a slot goes to the column
+    its index names, ``sum_k where(idx_k == column, val_k, 0)``, one fused
+    elementwise pass over [E,S,K,P+1] that materializes only the design.
+    The vmapped scatter-add it replaces there compiled for 15.5 s with
+    1.69 GB of temporaries at 12,874 x 256 x 3 for a described v5e (0.4 s
+    and none by the pick; PERF.md §6, PR 33), a program a size class.
+    Entries of one slot that share a column add, either way."""
     idx = batches.features.idx
     val = batches.features.val.astype(dtype)
-    e, s, _ = idx.shape
     p = batches.features.dim
-    ei = jnp.arange(e)[:, None, None]
-    si = jnp.arange(s)[None, :, None]
-    x_ext = jnp.zeros((e, s, p + 1), dtype).at[ei, si, idx].add(val)
-    # Materialization boundary: without it XLA fuses the scatter into every
+    if p > SELECT_MAX_COLUMNS:
+        e, s, _ = idx.shape
+        ei = jnp.arange(e)[:, None, None]
+        si = jnp.arange(s)[None, :, None]
+        x_ext = jnp.zeros((e, s, p + 1), dtype).at[ei, si, idx].add(val)
+    else:
+        columns = jnp.arange(p + 1, dtype=idx.dtype)
+        x_ext = jnp.sum(
+            jnp.where(idx[..., None] == columns, val[..., None], 0), axis=2)
+    # Materialization boundary: without it XLA fuses the producer into every
     # downstream dot, and the batched GEMMs degrade to a scalar loop
     # (measured 6x slower at the game_scale shape on CPU — module doc).
     x_ext = jax.lax.optimization_barrier(x_ext)

@@ -148,6 +148,15 @@ class RandomEffectModel:
         ]
         return dataset.scatter_scores(per_bucket)
 
+    @functools.cached_property
+    def _slots(self) -> tuple[np.ndarray, np.ndarray]:
+        """(bucket, lane) of every dense REId, as two arrays."""
+        bucket = np.full(len(self.entity_keys), -1, np.int64)
+        lane = np.zeros(len(self.entity_keys), np.int64)
+        for dense, (b, ln) in self.entity_to_slot.items():
+            bucket[dense], lane[dense] = b, ln
+        return bucket, lane
+
     def _project_stacks(
         self,
         dataset: RandomEffectDataset,
@@ -156,13 +165,23 @@ class RandomEffectModel:
     ) -> list[list[Array]]:
         """Project per-entity [E, P] stacks (aligned with this model's bucket
         structure) into ``dataset``'s local subspaces, several value sets in
-        ONE pass over the entities. Host-side per-entity remap — the
-        reference's model-RDD join by REId (SURVEY.md §3.6).
+        ONE pass. Host-side remap — the reference's model-RDD join by REId
+        (SURVEY.md §3.6) — with no per-entity Python: the entities of one
+        (new bucket, trained bucket) pair are matched together, each new
+        local column against its entity's trained columns (ascending, as
+        the builders write them) by ONE ``searchsorted`` over keys
+        ``lane * stride + column``.
         Entities/columns absent from this model get the per-source fill.
         Returns one projected per-bucket list per source."""
         key_to_dense = self._key_to_dense
         old_proj = [np.asarray(p) for p in self.bucket_proj]
         old_vals = [[np.asarray(c) for c in src] for src in sources]
+        slot_bucket, slot_lane = self._slots
+        # dense REId of ``dataset`` -> dense REId here, -1 = never trained
+        old_of = np.fromiter(
+            (key_to_dense.get(k, -1) for k in dataset.entity_keys),
+            np.int64, len(dataset.entity_keys))
+        stride = self.global_dim + 1
         out: list[list[Array]] = [[] for _ in sources]
         for b in dataset.buckets:
             proj = np.asarray(b.proj)
@@ -171,26 +190,23 @@ class RandomEffectModel:
                 np.full(proj.shape, fill, src[0].dtype)
                 for src, fill in zip(old_vals, fills)
             ]
-            for lane in range(b.n_entities):
-                dense_new = eids[lane]
-                if dense_new < 0:
-                    continue
-                dense_old = key_to_dense.get(dataset.entity_keys[dense_new])
-                if dense_old is None:
-                    continue
-                bo, lo = self.entity_to_slot[dense_old]
-                pv = old_proj[bo][lo]
-                valid = pv < self.global_dim
-                gi = pv[valid]
-                if len(gi) == 0:
-                    continue
-                # match new local columns against the trained sparse vector
-                cols_new = proj[lane]
-                pos = np.clip(np.searchsorted(gi, cols_new), 0, len(gi) - 1)
-                hit = gi[pos] == cols_new
+            dense_old = np.where(eids >= 0, old_of[np.maximum(eids, 0)], -1)
+            from_bucket = np.where(
+                dense_old >= 0, slot_bucket[np.maximum(dense_old, 0)], -1)
+            for bo in np.unique(from_bucket[from_bucket >= 0]):
+                lanes = np.flatnonzero(from_bucket == bo)
+                from_lanes = slot_lane[dense_old[lanes]]
+                row = np.arange(len(lanes), dtype=np.int64)[:, None] * stride
+                # ascending: a lane's trained columns, its ghosts last
+                have = (old_proj[bo][from_lanes] + row).ravel()
+                want = proj[lanes] + row
+                pos = np.minimum(np.searchsorted(have, want.ravel()),
+                                 len(have) - 1).reshape(want.shape)
+                hit = (have[pos] == want) & (proj[lanes] < self.global_dim)
+                lane_at, col = np.nonzero(hit)
                 for s, src in enumerate(old_vals):
-                    gv = src[bo][lo][valid]
-                    vals[s][lane][hit] = gv[pos[hit]]
+                    vals[s][lanes[lane_at], col] = (
+                        src[bo][from_lanes].ravel()[pos[hit]])
             for s, v in enumerate(vals):
                 out[s].append(jnp.asarray(v))
         return out
@@ -865,9 +881,13 @@ def train_random_effects(
 
         from photon_tpu.obs import trace_span as _trace_span
 
+        # What the bucket's padding costs, on the span: real rows (as the
+        # dataset's builder counted them) beside the row slots solved.
+        row_slots = int(bucket.max_samples) * orig_e
         re_span = _trace_span(
             "optim.re_bucket", cat="optim", bucket=b_i, entities=orig_e,
-            local_dim=p,
+            local_dim=p, padded_rows=int(bucket.max_samples),
+            row_slots=row_slots, rows=int(dataset.bucket_rows[b_i]),
         ).__enter__()
         info = {"solver": None}
         # Span closes on dispatch, not completed compute (the async
@@ -942,8 +962,7 @@ def train_random_effects(
             compile_seconds=info["compile_seconds"],
             calibration_seconds=info["calibration_seconds"],
         ).__exit__(None, None, None)
-        _RE_ROWS_ROUTED.inc(int(bucket.max_samples) * orig_e,
-                            solver=info["solver"])
+        _RE_ROWS_ROUTED.inc(row_slots, solver=info["solver"])
         # Per-solver attribution: under measured routing the calibration
         # race compiles every candidate — the losers' compiles must land on
         # their own labels, not the winner's.
@@ -961,7 +980,7 @@ def train_random_effects(
             "entities_padded": e,
             # SLOTS, not rows: [E, S] includes per-entity padding (weight-0
             # rows); the true row count would need a reduction over weights.
-            "row_slots": int(bucket.max_samples) * orig_e,
+            "row_slots": row_slots,
             "local_dim": p,
             "solver": info["solver"],
             "chunk": info["chunk"],

@@ -100,6 +100,10 @@ WINDOW_VMEM_VECTOR_BYTES = 48 << 20
 # the readings of ``scripts/sparse_formulation_check.py ops`` on the chip; the
 # three cells read 1.0 to 1.9 passes, chip_smoke.py's shape 10.7 and 13.0).
 WINDOW_BREAK_EVEN_PASSES = 8.0
+# ``fast`` writes a float32 row slice (512 B) a slot, so ``rmatvec_fast``
+# reduces its table a block of table rows at a time, each block's slices
+# about this many bytes (the whole of a 40 M-entry table would be 20 GB).
+ROW_SLICE_STEP_BYTES = 1 << 30
 
 
 @jax.tree_util.register_dataclass
@@ -596,18 +600,25 @@ def rmatvec_fast(
     n = dz.shape[0]
     nb = t.n_row_blocks
     dz2 = jnp.pad(dz, (0, nb * LANE - n)).reshape(nb, LANE)
-    rows = dz2[t.cs_rhi]                               # [B, Q, 128]
     iota = _lane_iota()
-    dz_at = jnp.sum(jnp.where(t.cs_rlo[..., None] == iota, rows, 0.0), axis=-1)
     # Upcast BEFORE squaring: bfloat16-stored values must square in the
     # accumulation precision, not in 8 mantissa bits.
     csv = t.cs_val.astype(jnp.promote_types(t.cs_val.dtype, dz.dtype))
-    v = csv * csv if square_vals else csv
-    contrib = dz_at * v                                # [B, Q]
-    oh = jnp.where(t.cs_clo[..., None] == iota, 1.0, 0.0)
-    out_b = jnp.einsum(
-        "bql,bq->bl", oh, contrib, preferred_element_type=jnp.float32
-    )                                                  # [B, 128]
+
+    def reduce_rows(rhi, rlo, clo, val):
+        """``[..., Q]`` slots of table rows -> their ``[..., 128]`` sums."""
+        rows = dz2[rhi]                                # [..., Q, 128]
+        dz_at = jnp.sum(jnp.where(rlo[..., None] == iota, rows, 0.0), axis=-1)
+        contrib = dz_at * (val * val if square_vals else val)
+        oh = jnp.where(clo[..., None] == iota, 1.0, 0.0)
+        return jnp.einsum("...ql,...q->...l", oh, contrib,
+                          preferred_element_type=jnp.float32)
+
+    b, q = t.cs_rhi.shape
+    slice_bytes = 4 * LANE * q                         # of one table row
+    out_b = jax.lax.map(                               # [B, 128]
+        lambda row: reduce_rows(*row), (t.cs_rhi, t.cs_rlo, t.cs_clo, csv),
+        batch_size=max(1, min(b, ROW_SLICE_STEP_BYTES // slice_bytes)))
     out_r = jax.ops.segment_sum(
         out_b, t.cs_range, num_segments=t.n_ranges + 1,
         indices_are_sorted=True,

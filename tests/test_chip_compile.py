@@ -237,6 +237,38 @@ def test_fit_program_holds_the_kernel_and_no_row_slices(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
+def test_a_fit_over_ten_million_rows_holds_no_whole_table_of_row_slices(
+        one_chip, compiled_kernel):
+    """``game_fit_ragged``'s fixed effect (PR 33): 9,997,911 rows x 4 over
+    26,765 columns. X.w keeps ``window`` (five windows of ``w``); X^T.r
+    keeps the row-slice table (a column range's slots read rows all over a
+    vector too long for VMEM), whose 40 M slots would be 20 GB of row
+    slices at once. Walked a block of table rows at a time the fit program
+    read 1.6 GB of temporaries here, and compiles."""
+    from photon_tpu.functions.problem import _fit_jitted
+
+    n, k, d, cs_rows = 9997911, 4, 26765, 19932
+    sh = one_chip
+    xtr = RowSliceXtr(
+        cs_rhi=_sds((cs_rows, Q), "int32", sh),
+        cs_rlo=_sds((cs_rows, Q), "int8", sh),
+        cs_clo=_sds((cs_rows, Q), "int8", sh),
+        cs_val=_sds((cs_rows, Q), "float32", sh),
+        cs_range=_sds((cs_rows,), "int32", sh),
+        n_ranges=-(-d // 128), n_row_blocks=-(-n // 128))
+    feats = SparseFeatures(
+        idx=_sds((n, k), "int32", sh), val=_sds((n, k), "float32", sh), dim=d,
+        fast=FastSparseAux(xw=_window_table(sh, 78112, 1024, n, d), xtr=xtr))
+    batch = LabeledBatch(
+        features=feats, labels=_sds((n,), "float32", sh),
+        offsets=_sds((n,), "float32", sh), weights=_sds((n,), "float32", sh))
+    vec = _sds((d,), "float32", sh)
+    compiled = _fit_jitted.lower(
+        _problem(FIXED), batch, vec, vec, None, None,
+        _sds((), "float32", sh)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 @pytest.mark.parametrize("n,k,d,cs_rows,temp_gb", [
     (65536, 76, 47237, 2696, 3.0),    # glm_fit: rcv1.binary's row width
     (72309, 52, 20959, 1904, 3.0),    # glm_fit_tron: real-sim whole, odd rows
@@ -301,6 +333,76 @@ def test_random_effect_bucket_solver_compiles(one_chip, solver, entities):
         lowered = _fit_bucket_jitted.lower(
             problem, batches, w0, mask, None, None)
     lowered.compile()
+
+
+def test_a_ragged_size_class_compiles_in_seconds(one_chip):
+    """``game_fit_ragged``'s size class of 12,874 users x 256 padded rows
+    (3 entries a slot, 32 local columns): its Newton program and its
+    scorer. Both pick by compare-select since PR 33. At this shape, for
+    a described v5e, the design's vmapped scatter-add alone compiled for
+    15.5 s and the scorer's batched gather for 309 s, a program a size
+    class (the Newton program 3.9 s and the scorer 1.9 s now, all read
+    here, PR 33); the bound of 60 s tells the two apart on any machine."""
+    import time
+
+    from photon_tpu.data.random_effect import _bucket_scores
+    from photon_tpu.game.newton_re import fit_bucket_newton
+
+    e, s, k, p = 12874, 256, 3, 32
+    sh = one_chip
+
+    def a(*shape, dtype="float32"):
+        return _sds((e,) + shape, dtype, sh)
+
+    batches = LabeledBatch(
+        features=SparseFeatures(idx=a(s, k, dtype="int32"), val=a(s, k), dim=p),
+        labels=a(s), offsets=a(s), weights=a(s))
+    t0 = time.perf_counter()
+    solver = fit_bucket_newton.lower(
+        _problem(PER_USER), batches, a(p), a(p), None).compile()
+    scorer = _bucket_scores.lower(
+        a(s, k, dtype="int32"), a(s, k), a(p)).compile()
+    assert time.perf_counter() - t0 < 60.0
+    # Fused: the scorer holds nothing of the [E,S,K,P] pick, and the solver
+    # the design and its weighted copy (1.52 GB with the scatter).
+    assert scorer.memory_analysis().temp_size_in_bytes < 1e6
+    assert solver.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
+def test_a_wide_local_dimension_is_not_picked_by_compare_select(one_chip):
+    """4,096 entities x 64 rows x 32 entries in 1,024 local columns (the
+    L-BFGS and dual routes' widths): over ``SELECT_MAX_COLUMNS`` the
+    scorer gathers and the design scatters, an entry's work not growing
+    with the columns. The compare-select pick there is 1,024 compares an
+    entry, 34 GB if its [E,S,K,P] pick were ever held (the CPU's compiler
+    holds it). Compiled here in seconds; the temporaries are the inputs'
+    relayout and the design."""
+    import time
+
+    from photon_tpu.data.random_effect import (
+        SELECT_MAX_COLUMNS,
+        _bucket_scores,
+    )
+    from photon_tpu.game.newton_re import _dense_design
+
+    e, s, k, p = 4096, 64, 32, 1024
+    assert p > SELECT_MAX_COLUMNS
+    sh = one_chip
+
+    def a(*shape, dtype="float32"):
+        return _sds((e,) + shape, dtype, sh)
+
+    batches = LabeledBatch(
+        features=SparseFeatures(idx=a(s, k, dtype="int32"), val=a(s, k), dim=p),
+        labels=a(s), offsets=a(s), weights=a(s))
+    t0 = time.perf_counter()
+    scorer = _bucket_scores.lower(
+        a(s, k, dtype="int32"), a(s, k), a(p)).compile()
+    design = jax.jit(lambda b: _dense_design(b, jnp.float32)).lower(
+        batches).compile()
+    assert time.perf_counter() - t0 < 60.0
+    assert scorer.memory_analysis().temp_size_in_bytes < 1e9
+    assert design.memory_analysis().temp_size_in_bytes < 3e9
 
 
 def test_additive_score_rows_compiles(one_chip):

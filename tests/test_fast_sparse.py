@@ -652,6 +652,36 @@ def test_build_counts_passes_a_slot_and_chooses_by_them(shape, chosen):
     assert args["passes_per_slot_rmatvec"] == 1.0
 
 
+@pytest.mark.parametrize("square_vals", [False, True])
+@pytest.mark.parametrize("rows_a_step", [1, 4, 7, 64])
+def test_a_row_slice_table_is_reduced_a_block_at_a_time(
+        force, monkeypatch, rows_a_step, square_vals):
+    """``rmatvec_fast`` walks its table ``ROW_SLICE_STEP_BYTES`` of row
+    slices at a time. Whatever the step (one that divides the 30 table
+    rows, ones that do not, one that holds them all) the sums are those of
+    the whole table in one step, to float32's last bits (1e-6 relative:
+    the blocks change no order of summation inside a table row, XLA's
+    fusion may), and those of a float64 scatter to the tolerance the test
+    above holds the table to."""
+    dim, k, n, q = 3 * 128 + 77, 6, 1000, 64
+    rng = np.random.default_rng(rows_a_step)
+    idx, val = _ell(rng, n, dim, k)
+    force("fast")
+    aux = build_fast_aux(idx, val, dim, q_capacity=q)
+    b = aux.xtr.cs_rhi.shape[0]
+    assert b > rows_a_step or rows_a_step == 64
+    dz = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    assert b * 4 * 128 * q <= fast_sparse.ROW_SLICE_STEP_BYTES
+    whole = np.asarray(rmatvec_fast(aux, dz, dim, square_vals=square_vals))
+    monkeypatch.setattr(fast_sparse, "ROW_SLICE_STEP_BYTES",
+                        rows_a_step * 4 * 128 * q)
+    blocks = np.asarray(rmatvec_fast(aux, dz, dim, square_vals=square_vals))
+    np.testing.assert_allclose(blocks, whole, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        blocks, _scatter64(idx, val, np.asarray(dz), dim, square=square_vals),
+        rtol=1e-5, atol=5e-5)
+
+
 def test_a_vector_too_long_for_vmem_keeps_the_row_slice_table(monkeypatch):
     monkeypatch.setattr(fast_sparse, "WINDOW_VMEM_VECTOR_BYTES", 6 * 5376)
     idx, val = _cell_like(38, 300, 6, 6000, 64)
