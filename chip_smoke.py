@@ -275,7 +275,8 @@ def _check_formulation(report: dict, *want: str) -> None:
 def _phase_line(report: dict, **extra) -> dict:
     keep = ("phase", "phase_seconds", "driver_seconds", "device", "probe",
             "sparse_op_traces", "compile_cache", "kernel_traces",
-            "retraces_after_warmup", "peak_device_bytes", "sharded_bytes")
+            "retraces_after_warmup", "peak_device_bytes", "sharded_bytes",
+            "re_buckets")
     return {**{k: report[k] for k in keep if k in report}, **extra}
 
 
@@ -557,8 +558,23 @@ def _child_driver(spec: dict) -> dict:
             k: retrace.retraces_after_warmup(k) for k in traces},
         "peak_device_bytes": stats.get("peak_bytes_in_use"),
         "sharded_bytes": _by_kind(snap.get("sharded_bytes_per_device")),
+        "re_buckets": _re_buckets(),
     }
     return report
+
+
+def _re_buckets() -> list:
+    """What the last fit's ``optim.re_bucket`` spans say of each bucket
+    solve: its shape, the solver it was routed to and how that solver
+    solved its Newton systems (``solve``). Empty off training."""
+    from photon_tpu.obs import recent_trees
+
+    keys = ("entities", "padded_rows", "local_dim", "solver", "solve",
+            "chunk")
+    trees = recent_trees("estimator.fit", last=1)
+    return [{k: args.get(k) for k in keys}
+            for name, _, _, _, _, args in (trees[-1] if trees else ())
+            if name == "optim.re_bucket"]
 
 
 def _by_kind(series) -> dict | None:

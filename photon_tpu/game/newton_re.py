@@ -65,9 +65,28 @@ Hessian assembly) are written as explicit batched ``matmul``s over
 backend at the ``game_scale`` bench shape ([100K,16,256]): letting XLA
 fuse the scatter/scale producers into the dot turns a 1.4 s batched GEMM
 into a 9 s fused loop — the barrier forces the operands into contiguous
-buffers the fast GEMM path can consume. Newton systems are solved via
-batched Cholesky (the damped Hessian is symmetric PD by construction),
-which halves the per-iteration factorization cost vs generic LU.
+buffers the fast GEMM path can consume.
+
+**The Newton systems' factorization.** The damped Hessian is symmetric PD
+by construction, so the [T,T] system of every lane is solved by Cholesky,
+not generic LU (the library's batched Cholesky halves LU's cost on the
+CPU; that reading is the CPU's). On the chip the library call
+(``jnp.linalg.cholesky``: the TPU's ``Cholesky`` custom call, and
+``cho_solve``'s triangular inversions) does not use the batch axis: it
+read 3.7 us a 32 x 32 system whatever the batch, 22.6 ms at 6,040 systems
+and 69.3 ms at 18,879, the largest device operation of both GAME cells
+(PERF.md §6, PR 34). ``_lane_cholesky_solve`` is the same float32
+factorization written over [T,T,E] arrays whose minor axis is the entity,
+a ``fori_loop`` of T column steps each one fused elementwise pass over all
+E lanes: 1.10 and 2.17 ms at those sizes. Every width a gate admits takes
+it (``scripts/newton_solve_check.py`` on a v5e, lanes | library, ms, at
+4,096 systems: T 17: 0.67 | 8.41; 32: 1.09 | 15.4; 64: 3.28 | 33.6; 80:
+5.51 | 43.5; 96: 46.2 | 55.2; 112: 72.9 | 91.4; 128: 109.0 | 103.7, and at
+128 x 1,024, the widest a chunked bucket's ladder size makes it, 5.08 |
+26.4; at 3 systems of 32 the two tie). The cost steps up where the [T,T,E]
+matrix leaves the chip's fast memory (105 MB held there, 151 MB not), so
+T*T*E decides, not T; walking E in blocks would flatten that and waits
+for a cell with buckets that wide (PERF.md §7 item 12).
 
 Parity: reference ⟦RandomEffectCoordinate.scala⟧ + ⟦SingleNodeOptimizationProblem⟧
 (SURVEY.md §3.5) run one Breeze L-BFGS per entity; these solvers reach the
@@ -348,6 +367,68 @@ def _dense_design(batches, dtype):
     )
 
 
+def solve_form() -> str:
+    """How ``_newton_loop`` solves its [T,T] systems, for the loop and for
+    the ``optim.re_bucket`` span: ``lanes``, or ``lu`` under
+    ``jax_debug_nans`` (see the loop)."""
+    return "lu" if jax.config.jax_debug_nans else "lanes"
+
+
+def _lane_cholesky_solve(h, b):
+    """Solve ``h @ x = b`` for every lane by a Cholesky whose minor axis is
+    the entity: ``h`` [E,T,T] symmetric, ``b`` [E,T], returns ``x`` [E,T].
+
+    The same float32 factorization the library call makes, laid out so the
+    parallelism is where the work is: ``T`` column steps, each a few
+    elementwise operations over all ``E`` lanes of a [T,T,E] array
+    (right-looking: column ``j`` over the root of its pivot, rows above
+    ``j`` masked, its outer product taken off the trailing matrix). Only
+    the leading axis is indexed dynamically. Row ``j`` of the carried
+    array ends as column ``j`` of L, so one array holds the input, the
+    trailing matrix and the factor. A lane that is not positive definite
+    takes the root of a pivot <= 0 and comes back NaN in that lane alone,
+    as the library's does (``_newton_loop``'s ``bad`` test relies on it).
+    """
+    def at(m, j):                  # m[j], the leading axis alone dynamic
+        return jax.lax.dynamic_index_in_dim(m, j, 0, keepdims=False)
+
+    t_dim = h.shape[-1]
+    rows = jnp.arange(t_dim)[:, None]                      # [T, 1]
+    # The mean of the two triangles, as ``jnp.linalg.cholesky`` takes its
+    # input: a Hessian from a float32 GEMM is symmetric only to rounding.
+    a = 0.5 * (jnp.transpose(h, (1, 2, 0))
+               + jnp.transpose(h, (2, 1, 0)))              # [T, T, E]
+    y = b.T                                                # [T, E]
+
+    def factor_column(j, a):
+        col = at(a, j)
+        l_j = jnp.where(rows >= j, col / jnp.sqrt(at(col, j)), 0.0)
+        # One elementwise pass over the matrix, row j written by the same
+        # select: a dynamic_update_slice after the subtraction costs the
+        # TPU's compiler two more passes (an update fusion and a copy).
+        return jnp.where(rows[:, :, None] == j, l_j[None, :, :],
+                         a - l_j[:, None, :] * l_j[None, :, :])
+
+    a = jax.lax.fori_loop(0, t_dim, factor_column, a)
+
+    def forward(j, y):                                     # L y = b
+        l_j = at(a, j)
+        y_j = at(y, j) / at(l_j, j)
+        return jnp.where(rows == j, y_j, y - y_j * l_j)
+
+    y = jax.lax.fori_loop(0, t_dim, forward, y)
+
+    def backward(k, x):                                    # L^T x = y
+        j = t_dim - 1 - k
+        l_j = at(a, j)
+        # x is still zero at rows <= j and l_j is zero above j: the sum is
+        # over the rows already solved.
+        x_j = (at(y, j) - jnp.sum(l_j * x, axis=0)) / at(l_j, j)
+        return jax.lax.dynamic_update_index_in_dim(x, x_j, j, 0)
+
+    return jax.lax.fori_loop(0, t_dim, backward, jnp.zeros_like(y)).T
+
+
 def _newton_loop(x0, z0, cfg, value_at, grad_at, hess_at, lin_map,
                  probe_values, ridge):
     """Shared damped-Newton driver over a batch of independent lanes.
@@ -402,21 +483,19 @@ def _newton_loop(x0, z0, cfg, value_at, grad_at, hess_at, lin_map,
             h = hess_at(x, z)
             scale = 1.0 + jax.vmap(jnp.trace)(h) / t_dim
             h_damped = h + (ridge * scale)[:, None, None] * eye
-        # The damped Hessian is symmetric PD by construction, so a batched
-        # Cholesky halves the factorization cost vs generic LU (measured
-        # 2x on the [E,17,17] dual systems, CPU backend). Under --debug-nans
-        # take LU instead: a lane whose Hessian lost PD to rounding makes
-        # Cholesky EMIT NaN by design (caught by the fallback below), which
-        # debug_nans would escalate to FloatingPointError on an otherwise
-        # healthy run — LU returns a finite non-descent direction the same
-        # guard handles. Trace-time read: the flag is process-static.
+        # The damped Hessian is symmetric PD by construction, so Cholesky,
+        # with the entities on the lane axis (module doc). Under
+        # --debug-nans take LU instead: a lane whose Hessian lost PD to
+        # rounding makes Cholesky EMIT NaN by design (caught by the
+        # fallback below), which debug_nans would escalate to
+        # FloatingPointError on an otherwise healthy run — LU returns a
+        # finite non-descent direction the same guard handles. Trace-time
+        # read: the flag is process-static.
         with jax.named_scope("newton.solve"):
-            if jax.config.jax_debug_nans:
+            if solve_form() == "lu":
                 d = -jnp.linalg.solve(h_damped, g[..., None])[..., 0]
             else:
-                chol = jnp.linalg.cholesky(h_damped)
-                d = -jax.scipy.linalg.cho_solve(
-                    (chol, True), g[..., None])[..., 0]
+                d = -_lane_cholesky_solve(h_damped, g)
         dg = jnp.sum(d * g, axis=1)
         # H is PD(+ridge) so d is descent; a numerically non-descent lane —
         # including a failed factorization (NaN Cholesky of a lane whose

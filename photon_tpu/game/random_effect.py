@@ -425,6 +425,7 @@ def _solve_bucket(problem, bucket, batches, w0, local_mask, local_norm,
         newton_chunk_size,
         newton_eligible,
         penalty_terms,
+        solve_form,
         u_max_for,
     )
     from photon_tpu.obs.retrace import compile_watch
@@ -505,6 +506,9 @@ def _solve_bucket(problem, bucket, batches, w0, local_mask, local_norm,
 
     def finish(models, result, **info):
         info.setdefault("chunk", None)
+        # How the bucket's Newton systems were solved (the span's ``solve``).
+        info["solve"] = (solve_form() if info["solver"].startswith("newton")
+                         else None)
         info.setdefault("routing", "static")
         info.setdefault("calibration_seconds", 0.0)
         info.setdefault("calibrated", False)
@@ -958,7 +962,7 @@ def train_random_effects(
         # grade artifacts need first-call XLA compile separated out).
         re_span.set(
             solver=info["solver"], chunk=info["chunk"],
-            routing=info["routing"],
+            solve=info["solve"], routing=info["routing"],
             compile_seconds=info["compile_seconds"],
             calibration_seconds=info["calibration_seconds"],
         ).__exit__(None, None, None)

@@ -342,7 +342,13 @@ def test_a_ragged_size_class_compiles_in_seconds(one_chip):
     a described v5e, the design's vmapped scatter-add alone compiled for
     15.5 s and the scorer's batched gather for 309 s, a program a size
     class (the Newton program 3.9 s and the scorer 1.9 s now, all read
-    here, PR 33); the bound of 60 s tells the two apart on any machine."""
+    here, PR 33); the bound of 60 s tells the two apart on any machine.
+    Since PR 34 the Newton program factorizes its [E,32,32] systems with
+    the entities on the minor axis (``_lane_cholesky_solve``): no
+    ``Cholesky`` custom call is left in it, and it compiles in 2.4 s
+    where the library form took 2.2 s (as a process's second program; 4.1
+    and 2.5 s as its first; 1.07 GB of temporaries either way; read here
+    for a described v5e, PR 34)."""
     import time
 
     from photon_tpu.data.random_effect import _bucket_scores
@@ -367,6 +373,28 @@ def test_a_ragged_size_class_compiles_in_seconds(one_chip):
     # the design and its weighted copy (1.52 GB with the scatter).
     assert scorer.memory_analysis().temp_size_in_bytes < 1e6
     assert solver.memory_analysis().temp_size_in_bytes < 1.3e9
+    assert "Cholesky" not in solver.as_text()
+
+
+def test_the_widest_newton_program_holds_no_library_factorization(one_chip):
+    """256 entities x 64 rows in 128 local columns (the widest primal
+    bucket measured routing admits, ``NEWTON_CHUNK_MAX_P``, at the chunk
+    ladder's smallest size): the lane form at every width, so no
+    ``Cholesky`` custom call here either (on the chip 1.82 ms for the
+    call's 7.03 at this shape, PERF.md §6, PR 34)."""
+    from photon_tpu.game.newton_re import NEWTON_CHUNK_MAX_P, fit_bucket_newton
+
+    e, s, k, p = 256, 64, 3, NEWTON_CHUNK_MAX_P
+
+    def a(*shape, dtype="float32"):
+        return _sds((e,) + shape, dtype, one_chip)
+
+    batches = LabeledBatch(
+        features=SparseFeatures(idx=a(s, k, dtype="int32"), val=a(s, k), dim=p),
+        labels=a(s), offsets=a(s), weights=a(s))
+    solver = fit_bucket_newton.lower(
+        _problem(PER_USER), batches, a(p), a(p), None).compile()
+    assert "Cholesky" not in solver.as_text()
 
 
 def test_a_wide_local_dimension_is_not_picked_by_compare_select(one_chip):

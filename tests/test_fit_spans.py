@@ -171,6 +171,16 @@ def test_span_arguments_say_what_the_work_was(two_fits):
     assert by_name["descent.validate"]["coordinate"] in ("fixed", "perUser")
 
 
+def test_a_newton_buckets_span_says_how_its_systems_were_factorized(two_fits):
+    """``solve`` is ``newton_re.solve_form()``, what ``_newton_loop`` asks
+    while tracing; set at exit beside ``solver``."""
+    buckets = [s[ARGS] for s in two_fits[1] if s[NAME] == "optim.re_bucket"]
+    assert len(buckets) == 2
+    for args in buckets:
+        assert args["solver"] == "newton_primal" and args["chunk"] is None
+        assert args["solve"] == newton_re.solve_form() == "lanes"
+
+
 def test_fit_breakdown_adds_up_to_the_fit(two_fits):
     for tree, first in zip(two_fits, (True, False)):
         parts = fit_breakdown(tree)
