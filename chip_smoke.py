@@ -565,12 +565,13 @@ def _child_driver(spec: dict) -> dict:
 
 def _re_buckets() -> list:
     """What the last fit's ``optim.re_bucket`` spans say of each bucket
-    solve: its shape, the solver it was routed to and how that solver
-    solved its Newton systems (``solve``). Empty off training."""
+    solve: the coordinate's entity column (``re_type``), its shape, the
+    solver it was routed to and how that solver solved its Newton systems
+    (``solve``). Empty off training."""
     from photon_tpu.obs import recent_trees
 
-    keys = ("entities", "padded_rows", "local_dim", "solver", "solve",
-            "chunk")
+    keys = ("re_type", "entities", "padded_rows", "local_dim", "solver",
+            "solve", "chunk")
     trees = recent_trees("estimator.fit", last=1)
     return [{k: args.get(k) for k in keys}
             for name, _, _, _, _, args in (trees[-1] if trees else ())

@@ -167,6 +167,13 @@ class RandomEffectDataset:
         """Entities x padded rows, summed over the buckets."""
         return sum(b.n_entities * b.max_samples for b in self.buckets)
 
+    @property
+    def size_classes(self) -> tuple[int, ...]:
+        """The distinct padded row counts of the buckets, ascending: one a
+        size class of entity, however many buckets (local widths, chunks
+        of entities) a class was split into."""
+        return tuple(sorted({int(b.max_samples) for b in self.buckets}))
+
     def span_arguments(self) -> dict:
         """``{buckets, rows, row_slots}``: how many buckets a step over
         this dataset solves, the real rows in them and the padded row

@@ -100,33 +100,42 @@ def build_re_dataset_from_bundle(
             f"random effect {cfg.re_type!r} needs id tag column "
             f"{cfg.re_type!r}; bundle has {sorted(bundle.id_tags)}"
         )
-    val_np = np.asarray(jax.device_get(sf.val))
-    # Follow the bundle's feature precision (float64 under --dtype float64)
-    # — EXCEPT sub-f32 feed dtypes: the bf16 feed narrows the fixed-effect
-    # transfer only, while per-entity solves accumulate in f32 (the batched
-    # Cholesky kernels have no bf16 lowering), so RE buckets re-pack the
-    # already-quantized values as f32.
-    re_dtype = val_np.dtype
-    if re_dtype.itemsize < 4:
-        re_dtype = np.dtype(np.float32)
-    return build_random_effect_dataset(
-        re_type=cfg.re_type,
-        entity_keys_per_row=bundle.id_tags[cfg.re_type],
-        idx=np.asarray(jax.device_get(sf.idx)),
-        val=val_np,
-        labels=bundle.labels,
-        global_dim=sf.dim,
-        weights=bundle.weights,
-        active_bound=None if for_scoring else cfg.active_bound,
-        min_entity_rows=1 if for_scoring else cfg.min_entity_rows,
-        intercept_index=intercept_index,
-        max_features_per_entity=(
-            None if for_scoring else cfg.max_features_per_entity
-        ),
-        max_bucket_entities=cfg.max_bucket_entities,
-        host_resident=cfg.host_resident,
-        dtype=re_dtype,
-    )
+    # Reading the shard back, grouping the rows by key on the host, packing
+    # and placing the buckets: timed by key, since a fit with two keys
+    # groups its rows twice (docs/observability.md).
+    with trace_span("data.re_dataset", cat="data", re_type=cfg.re_type,
+                    scoring=for_scoring) as span:
+        val_np = np.asarray(jax.device_get(sf.val))
+        # Follow the bundle's feature precision (float64 under --dtype
+        # float64) — EXCEPT sub-f32 feed dtypes: the bf16 feed narrows the
+        # fixed-effect transfer only, while per-entity solves accumulate in
+        # f32 (the batched Cholesky kernels have no bf16 lowering), so RE
+        # buckets re-pack the already-quantized values as f32.
+        re_dtype = val_np.dtype
+        if re_dtype.itemsize < 4:
+            re_dtype = np.dtype(np.float32)
+        dataset = build_random_effect_dataset(
+            re_type=cfg.re_type,
+            entity_keys_per_row=bundle.id_tags[cfg.re_type],
+            idx=np.asarray(jax.device_get(sf.idx)),
+            val=val_np,
+            labels=bundle.labels,
+            global_dim=sf.dim,
+            weights=bundle.weights,
+            active_bound=None if for_scoring else cfg.active_bound,
+            min_entity_rows=1 if for_scoring else cfg.min_entity_rows,
+            intercept_index=intercept_index,
+            max_features_per_entity=(
+                None if for_scoring else cfg.max_features_per_entity
+            ),
+            max_bucket_entities=cfg.max_bucket_entities,
+            host_resident=cfg.host_resident,
+            dtype=re_dtype,
+        )
+        span.set(entities=dataset.n_entities,
+                 classes=len(dataset.size_classes),
+                 **dataset.span_arguments())
+    return dataset
 
 
 def _factorize_group_ids(values: np.ndarray) -> tuple[Array, int]:

@@ -889,9 +889,9 @@ def train_random_effects(
         # dataset's builder counted them) beside the row slots solved.
         row_slots = int(bucket.max_samples) * orig_e
         re_span = _trace_span(
-            "optim.re_bucket", cat="optim", bucket=b_i, entities=orig_e,
-            local_dim=p, padded_rows=int(bucket.max_samples),
-            row_slots=row_slots, rows=int(dataset.bucket_rows[b_i]),
+            "optim.re_bucket", cat="optim", re_type=dataset.re_type,
+            bucket=b_i, entities=orig_e, local_dim=p,
+            padded_rows=int(bucket.max_samples), row_slots=row_slots, rows=int(dataset.bucket_rows[b_i]),
         ).__enter__()
         info = {"solver": None}
         # Span closes on dispatch, not completed compute (the async

@@ -43,6 +43,7 @@ FIT_TREE = {
     "estimator.fit": None,
     "estimator.prepare": "estimator.fit",
     "estimator.prepare_validation": "estimator.fit",
+    "data.re_dataset": ("estimator.prepare", "estimator.prepare_validation"),
     "estimator.build_coordinates": "estimator.fit",
     "data.accel_tables": "estimator.build_coordinates",
     "descent.run": "estimator.fit",
@@ -112,6 +113,7 @@ def test_first_fit_leaves_exactly_the_catalogued_tree(two_fits):
     assert count["descent.step"] == count["descent.validate"] == 4
     assert count["optim.glm_fit"] == count["optim.re_bucket"] == 2
     assert count["data.accel_tables"] == 1
+    assert count["data.re_dataset"] == 2       # training rows, validation rows
     assert count["estimator.fit"] == count["descent.run"] == 1
 
 
@@ -121,7 +123,9 @@ def test_parent_ids_link_the_tree(two_fits):
         assert len(by_id) == len(tree)
         for s in tree:
             parent = by_id.get(s[PARENT_ID])
-            assert (parent and parent[NAME]) == FIT_TREE[s[NAME]], s[NAME]
+            wanted = FIT_TREE[s[NAME]]
+            wanted = wanted if isinstance(wanted, tuple) else (wanted,)
+            assert (parent and parent[NAME]) in wanted, s[NAME]
 
 
 def test_spans_of_a_fit_share_one_trace_id(two_fits):
@@ -146,6 +150,7 @@ def test_second_fit_on_the_same_bundle_prepares_nothing(two_fits):
     second = set(_names(two_fits[1]))
     assert second == set(FIT_TREE) - {"estimator.prepare",
                                       "estimator.prepare_validation",
+                                      "data.re_dataset",
                                       "data.accel_tables"}
 
 
@@ -179,6 +184,33 @@ def test_a_newton_buckets_span_says_how_its_systems_were_factorized(two_fits):
     for args in buckets:
         assert args["solver"] == "newton_primal" and args["chunk"] is None
         assert args["solve"] == newton_re.solve_form() == "lanes"
+
+
+def test_a_buckets_span_names_its_coordinates_entity_column(two_fits):
+    """``re_type`` is the dataset's: with two per-entity coordinates a
+    fit's tree says which of them a bucket belonged to."""
+    for tree in two_fits:
+        buckets = [s[ARGS] for s in tree if s[NAME] == "optim.re_bucket"]
+        assert [b["re_type"] for b in buckets] == ["userId", "userId"]
+
+
+@pytest.mark.parametrize("scoring, parent", [
+    (False, "estimator.prepare"), (True, "estimator.prepare_validation")])
+def test_a_dataset_span_says_what_was_grouped(two_fits, scoring, parent):
+    """One ``data.re_dataset`` a key and a bundle: the key, whether the
+    rows are scored or trained on, the entities, the size classes and the
+    dataset's own ``span_arguments()``."""
+    first = two_fits[0]
+    by_id = {s[SPAN_ID]: s for s in first}
+    (span,) = [s for s in first if s[NAME] == "data.re_dataset"
+               and s[ARGS]["scoring"] is scoring]
+    assert by_id[span[PARENT_ID]][NAME] == parent
+    args = dict(span[ARGS])
+    args.pop("trace_id")
+    # 6 users x 12 rows, padded to 16: one size class, one bucket
+    assert args == {"re_type": "userId", "scoring": scoring, "entities": 6,
+                    "classes": 1, "buckets": 1, "rows": 72,
+                    "row_slots": 6 * 16}
 
 
 def test_fit_breakdown_adds_up_to_the_fit(two_fits):
