@@ -42,7 +42,8 @@ from photon_tpu.types import REAL_ACCELERATOR_BACKENDS
 Array = jax.Array
 
 # The tables cost at most 20 B an entry on the device beside the ELL arrays'
-# 8 B (``window``: 8 B a slot in each of two tables; ``fast``: 20 B);
+# 8 B (``window``: 8 B a slot in each of two tables; ``planes``, of ``X.w``
+# alone: 8 B an entry; ``fast``: 20 B);
 # ``SparseFeatures.with_accelerator_paths`` attaches none over this. Which of
 # the two an op gets is ``ops/fast_sparse.py`` ``WINDOW_BREAK_EVEN_PASSES``.
 ACCEL_TABLE_BUDGET_BYTES = 4e9
@@ -97,7 +98,9 @@ class SparseFeatures:
     for ``X^T.r``), and the one seam of the sparse pass. Each op runs what
     its table is: ``window`` (a ``WindowTable``: the windowed one-hot Pallas
     kernel ``gather_reduce``, float32, nothing of the entries' length
-    written to HBM) or ``fast`` (a row-slice table: row-slice gather +
+    written to HBM), for ``X.w`` on a tall narrow matrix ``planes`` (a
+    ``PlaneTable``: the same lookup over the ELL columns as they lie, no
+    sort) or ``fast`` (a row-slice table: row-slice gather +
     one-hot reduce, what the build keeps for an op whose entries do not sort
     into narrow windows); and the ``plain`` one when no table is attached
     (gather / ``segment_sum``, inline below; also the reference the tests
@@ -198,15 +201,16 @@ class SparseFeatures:
 
     def _formulation(self, op: str, operand: Array) -> str:
         """Which formulation ``op`` puts into the program being traced
-        here: what the op's table is (``"window"`` or ``"fast"``) when the
-        tables are attached, else ``"plain"``; ``"plain"`` also for an
-        operand the float32 ``window`` kernel cannot take.
+        here: what the op's table is (``"window"``, ``"planes"`` or
+        ``"fast"``) when the tables are attached, else ``"plain"``;
+        ``"plain"`` also for an operand the float32 kernel of ``window``
+        and ``planes`` cannot take.
         Counted once per trace (or eager call) in
         ``sparse_op_traces_total{op, formulation}``, so a run can say what
         its programs really hold, not what a look-alike batch would get."""
         pass_counter.record(op)
         kind = "plain" if self.fast is None else self.fast.formulation(op)
-        if kind == "window" and operand.dtype != jnp.float32:
+        if kind in ("window", "planes") and operand.dtype != jnp.float32:
             kind = "plain"
         REGISTRY.counter(
             "sparse_op_traces_total",

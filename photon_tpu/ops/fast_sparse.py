@@ -1,17 +1,22 @@
-"""The table formulations of the sparse pass: ``window`` and ``fast``.
+"""The table formulations of the sparse pass: ``window``, its second
+arrangement ``planes``, and ``fast``.
 
-Two of the three formulations behind ``SparseFeatures`` (``data/batch.py``):
-the ops run one of these where the tables built here are attached, and the
-``plain`` gather / ``segment_sum`` where they are not. Both replace XLA's
-generic gather and scatter-add, which the TPU runs one element at a time,
-with layouts built once on the host from the (static) indices. What a pass
-costs on the chip, operation by operation, is in ``PERF.md`` §5; which
-formulation a run's programs hold, in the counter ``sparse_op_traces_total``.
+The formulations behind ``SparseFeatures`` (``data/batch.py``) beside
+``plain``: the ops run one of these where the tables built here are
+attached, and the ``plain`` gather / ``segment_sum`` where they are not.
+All replace XLA's generic gather and scatter-add, which the TPU runs one
+element at a time, with layouts built once on the host from the (static)
+indices. What a pass costs on the chip, operation by operation, is in
+``PERF.md`` §5; which formulation a run's programs hold, in the counter
+``sparse_op_traces_total`` and in the span ``data.accel_tables``
+(``formulation_matvec``, ``formulation_rmatvec``).
 
 ``build_fast_aux`` builds one table for each op (``X.w``, and ``X^T.r`` with
-its squared twin) and chooses the formulation of each from a number it can
-observe, the mean MXU passes a slot of the ``window`` table
-(``WINDOW_BREAK_EVEN_PASSES``):
+its squared twin) and chooses the formulation of each from numbers it can
+observe: the mean MXU passes a slot of the ``window`` table
+(``WINDOW_BREAK_EVEN_PASSES``) and, for ``X.w``, the chunks and passes either
+arrangement of the kernel would run on the matrix, weighed by the kernel's
+measured costs (``_xw_table``):
 
 * ``window`` (``WindowTable``, ``gather_reduce``): **one Pallas kernel for
   both ops, no gather at all.** A table is ``[B, Q]`` slots; every slot of
@@ -32,6 +37,20 @@ observe, the mean MXU passes a slot of the ``window`` table
   the 128 outputs are float32 on the VPU. Nothing of an entries' length is
   written to HBM: a slot costs 8 B of table read.
 
+* ``planes`` (``PlaneTable``, ``plane_lookup``): **the same lookup over the
+  ELL block as it lies, for ``X.w`` on a tall, narrow matrix.** The rows of
+  ``X.w`` are already where the output is: ``z[i] = Σ_k val[i,k]·w[idx[i,k]]``
+  needs a lookup an entry and no reduction across rows. Column ``k`` of the
+  ELL block (a *plane*) is one lookup of ``CHUNK`` consecutive rows, full by
+  construction; its result is multiplied by the plane's values and added,
+  plane after plane, to a ``[1, CHUNK]`` accumulator that is stored once: no
+  sort, no out lane, no epilogue, no transpose, 8 B an entry resident. A
+  plane-chunk reads the windows from its least to its greatest live column,
+  so it pays where planes are typed (a head plane, an intercept plane) or
+  ``w`` is a window long; the sorted table pays where rows are wide and
+  their columns spread (``glm_fit``: 76 entries over nine windows). The
+  pass itself (``_lookup``) is shared with ``window``, bit for bit.
+
 * ``fast`` (``RowSliceXw``, ``RowSliceXtr``): **row-slice gather +
   lane-select**, the formulation before the kernel and what an op keeps
   whose entries do not sort into narrow windows (``chip_smoke.py``'s uniform
@@ -49,9 +68,10 @@ keeps nothing: each call is a build. "Once per dataset" is the caller's to
 hold — ``GameEstimator`` keeps the tables with its prepared bundle, so every
 fit on that bundle after the first attaches them; the one-shot drivers build
 once a run. Ghost-padding entries (column id == dim, value 0) are in no
-``window`` table and point at a zero row in the ``fast`` ones.
+``window`` table, hold value 0 in a ``planes`` table (and widen no
+plane-chunk's windows) and point at a zero row in the ``fast`` ones.
 
-One thing ``window`` does differently from a gather: the one-hot product
+One thing the kernel does differently from a gather: the one-hot product
 multiplies every element of a window by 0 or 1, so a non-finite element
 makes every slot that reads its window NaN, not only the slots that read
 the element. A solver rejects such a trial point by its value either way.
@@ -86,7 +106,8 @@ WINDOW_BLOCKS = 42
 # Slots a pass handles at once; table rows are whole chunks, and each chunk
 # carries the contiguous run of windows its (sorted) slots read.
 CHUNK = 1024
-# Table rows a grid step of the kernel walks (a loop, not an unrolled body).
+# Table rows a grid step of the kernel walks (a loop, not an unrolled body);
+# by planes, chunks of rows, each of them K table rows.
 ROWS_PER_STEP = 8
 # Field widths of a slot's int32: window | block in window | lane | out lane.
 _WIN_SHIFT, _BLK_SHIFT, _LANE_SHIFT = 20, 14, 7
@@ -100,10 +121,33 @@ WINDOW_VMEM_VECTOR_BYTES = 48 << 20
 # the readings of ``scripts/sparse_formulation_check.py ops`` on the chip; the
 # three cells read 1.0 to 1.9 passes, chip_smoke.py's shape 10.7 and 13.0).
 WINDOW_BREAK_EVEN_PASSES = 8.0
+# What the kernel costs on a v5e, by which ``build_fast_aux`` weighs X.w's two
+# arrangements against each other, in microseconds: a pass over 1,024 slots;
+# a chunk of the sorted table beside its passes (the out-lane select, eight
+# adds, a share of the table row's transpose and of its ``segment_sum``); a
+# plane-chunk beside its passes (a decode, a product, an add). Least squares
+# over the twelve readings of ``scripts/sparse_formulation_check.py ops`` on
+# the chip (PERF.md §6, PR 37: either arrangement forced at the five cells'
+# shapes and the smoke's, 2.6 to 135 ms a call): 0.297, 0.238 and 0.035, and
+# these three give every one of the twelve back within 5%.
+WINDOW_PASS_US = 0.30
+WINDOW_CHUNK_US = 0.24
+PLANE_CHUNK_US = 0.035
+# X.w goes by planes only where that is cheaper than the sorted table by a
+# clear margin (its modelled cost under this share of the table's): a matrix
+# near level keeps the table it has and does not flip on a rounding
+# (``glm_fit_tron``'s reads 2.90 ms a call by planes and 2.66 by the table,
+# PR 37; the cells that go by planes read 0.61, 0.27 and 0.23 of it).
+PLANES_MARGIN = 0.75
 # ``fast`` writes a float32 row slice (512 B) a slot, so ``rmatvec_fast``
 # reduces its table a block of table rows at a time, each block's slices
 # about this many bytes (the whole of a 40 M-entry table would be 20 GB).
 ROW_SLICE_STEP_BYTES = 1 << 30
+
+
+def _mean_passes(passes) -> float:
+    n_pass = np.asarray(passes) & 255
+    return float(n_pass[n_pass > 0].mean()) if n_pass.any() else 0.0
 
 
 @jax.tree_util.register_dataclass
@@ -135,10 +179,41 @@ class WindowTable:
         """Mean MXU passes over the chunks that hold an entry: what
         ``build_fast_aux`` chose by (a host read of ``passes``; not for
         traced code)."""
-        n_pass = np.asarray(self.passes) & 255
-        return float(n_pass[n_pass > 0].mean()) if n_pass.any() else 0.0
+        return _mean_passes(self.passes)
 
     def cast_values(self, dtype) -> "WindowTable":
+        return dataclasses.replace(self, val=self.val.astype(dtype))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PlaneTable:
+    """``X.w``'s ``planes`` table: the ELL block as it lies, a *plane*
+    (column ``k`` of ``idx`` and ``val``) of ``CHUNK`` consecutive rows a
+    table row. ``z[c*CHUNK + j] = Σ_k val[c*K + k, j] · w[column of
+    word[c*K + k, j]]``.
+
+    ``word[C*K, CHUNK]`` int32 packs a slot's column in ``WindowTable``'s
+    window | block | lane fields (the out lane is the slot's own position,
+    its field 0); ``val`` is its value (0 for ghost entries and for the
+    rows that pad ``C`` to whole grid steps; may be stored narrower).
+    ``passes[C*K]`` holds ``first window << 8 | windows`` a plane-chunk, from
+    its least live column to its greatest (0: no live entry, skipped).
+    """
+
+    word: Array      # [C*K, CHUNK] int32
+    val: Array       # [C*K, CHUNK] float32 (or narrower)
+    passes: Array    # [C*K] int32
+    n_planes: int = dataclasses.field(metadata=dict(static=True))
+    n_windows: int = dataclasses.field(metadata=dict(static=True))
+
+    formulation = "planes"
+
+    def passes_per_slot(self) -> float:
+        """Mean MXU passes over the plane-chunks that hold an entry."""
+        return _mean_passes(self.passes)
+
+    def cast_values(self, dtype) -> "PlaneTable":
         return dataclasses.replace(self, val=self.val.astype(dtype))
 
 
@@ -192,20 +267,22 @@ class FastSparseAux:
     ``X^T.r`` (and its squared twin), each in the formulation
     ``build_fast_aux`` chose for it."""
 
-    xw: Union[WindowTable, RowSliceXw]
+    xw: Union[WindowTable, PlaneTable, RowSliceXw]
     xtr: Union[WindowTable, RowSliceXtr]
 
     def formulation(self, op: str) -> str:
-        """``"window"`` or ``"fast"``: what ``op`` runs on these tables."""
+        """``"window"``, ``"planes"`` or ``"fast"``: what ``op`` runs on
+        these tables."""
         return (self.xw if op == "matvec" else self.xtr).formulation
 
     def span_arguments(self) -> dict:
         """What the ``data.accel_tables`` span says of a build: each op's
-        formulation, and the mean MXU passes a slot of a ``window`` table."""
+        formulation, and the mean MXU passes a slot of a table the kernel
+        reads (``window``, ``planes``)."""
         out = {}
         for op, table in (("matvec", self.xw), ("rmatvec", self.xtr)):
             out[f"formulation_{op}"] = table.formulation
-            if isinstance(table, WindowTable):
+            if isinstance(table, (WindowTable, PlaneTable)):
                 out[f"passes_per_slot_{op}"] = round(table.passes_per_slot(), 4)
         return out
 
@@ -288,16 +365,31 @@ def _slots(counts: np.ndarray, q: int, rows_floor: int):
     return flat, ranges
 
 
+def _windows_of(n_gat: int) -> Optional[int]:
+    """The windows a gathered vector of ``n_gat`` elements spans; None where
+    the kernel cannot hold it (a slot's field, the VMEM the parts take)."""
+    n_windows = max(1, -(-n_gat // (WINDOW_BLOCKS * LANE)))
+    if (n_windows > MAX_WINDOWS
+            or 6 * n_windows * WINDOW_BLOCKS * LANE > WINDOW_VMEM_VECTOR_BYTES):
+        return None
+    return n_windows
+
+
+def _placed(table):
+    """A table built on the host, on the device."""
+    return jax.tree.map(jnp.asarray, table)
+
+
 def _window_table(red, gat, val, n_red: int, n_gat: int,
                   q_capacity: int) -> Optional[WindowTable]:
     """The ``window`` table of entries that reduce into index ``red`` (of
     ``n_red``) and gather index ``gat`` (of ``n_gat``), both int32 and sorted
-    by (``red >> 7``, ``gat``); None where the geometry cannot hold them
-    (the vector too long, a chunk over too many windows)."""
+    by (``red >> 7``, ``gat``), its arrays still on the host (``_placed``
+    once it is chosen); None where the geometry cannot hold them (the
+    vector too long, a chunk over too many windows)."""
     n_ranges = -(-n_red // LANE)
-    n_windows = max(1, -(-n_gat // (WINDOW_BLOCKS * LANE)))
-    if (n_windows > MAX_WINDOWS
-            or 6 * n_windows * WINDOW_BLOCKS * LANE > WINDOW_VMEM_VECTOR_BYTES):
+    n_windows = _windows_of(n_gat)
+    if n_windows is None:
         return None
     counts = np.bincount(red >> 7, minlength=n_ranges)
     # Q from the shape: no wider than the fullest range needs.
@@ -328,10 +420,8 @@ def _window_table(red, gat, val, n_red: int, n_gat: int,
             return None
         passes[flat[starts]] = (win[starts] << 8) | n_pass
     return WindowTable(
-        word=jnp.asarray(word.reshape(b, q)),
-        val=jnp.asarray(values.reshape(b, q)),
-        passes=jnp.asarray(passes), range=jnp.asarray(ranges),
-        n_ranges=n_ranges, n_windows=n_windows)
+        word=word.reshape(b, q), val=values.reshape(b, q), passes=passes,
+        range=ranges, n_ranges=n_ranges, n_windows=n_windows)
 
 
 def _row_slice_xw(idx: np.ndarray, dim: int) -> RowSliceXw:
@@ -378,6 +468,110 @@ def _keeps_window(table: Optional[WindowTable]) -> bool:
             and table.passes_per_slot() <= WINDOW_BREAK_EVEN_PASSES)
 
 
+def _kernel_us(n_pass: np.ndarray, chunk_us: float) -> float:
+    """The kernel's modelled microseconds over chunks (or plane-chunks) of
+    ``n_pass`` passes each; one of no pass is skipped."""
+    return float(np.count_nonzero(n_pass) * chunk_us
+                 + n_pass.sum() * WINDOW_PASS_US)
+
+
+@dataclasses.dataclass(frozen=True)
+class _PlaneCount:
+    """What ``X.w`` by planes would run on a matrix, and the least the
+    sorted table could: counted from the ELL block, before any sort."""
+
+    passes: np.ndarray       # [C*K] int32, ``PlaneTable.passes``
+    n_windows: int
+    us: float                # the planes' modelled cost
+    sorted_floor_us: float   # what no row-sorted table of it goes under
+
+
+def _count_planes(idx: np.ndarray, dim: int) -> Optional[_PlaneCount]:
+    """From the least and the greatest live column of every (128 rows,
+    plane), O(entries): the windows a plane-chunk spans, and for the sorted
+    table a floor (a 128-row range fills ``ceil(entries / CHUNK)`` chunks,
+    which between them read every window from the range's least to its
+    greatest: exactly that where a range is one chunk). None where the
+    planes cannot hold the matrix (``w`` too long for VMEM, a plane-chunk
+    over too many windows) or read over ``WINDOW_BREAK_EVEN_PASSES`` windows
+    a plane-chunk, the row-slice table's ground."""
+    n, k = idx.shape
+    n_windows = _windows_of(dim)
+    if n_windows is None:
+        return None
+    blocks = np.full((_round_up(n, ROWS_PER_STEP * CHUNK), k), dim, idx.dtype)
+    blocks[:n] = np.minimum(idx, dim)
+    blocks = blocks.reshape(-1, LANE, k)
+    live = blocks < dim
+    # Ghosts read ``dim``, the greatest. Where no entry is live: first =
+    # dim's window, last = -1, no pass.
+    first = blocks.min(axis=1) // (WINDOW_BLOCKS * LANE)
+    last = np.where(live, blocks, -1).max(axis=1) // (WINDOW_BLOCKS * LANE)
+
+    def spans(lo, hi):
+        return np.maximum(hi - lo + 1, 0)
+
+    per_chunk = CHUNK // LANE
+    first_pc = first.reshape(-1, per_chunk, k).min(axis=1)
+    n_pass = spans(first_pc, last.reshape(-1, per_chunk, k).max(axis=1))
+    if (int(n_pass.max(initial=0)) > MAX_PASSES_A_CHUNK
+            or _mean_passes(n_pass) > WINDOW_BREAK_EVEN_PASSES):
+        return None
+    chunks = -(-live.sum(axis=(1, 2)) // CHUNK)
+    floor = np.maximum(chunks, spans(first.min(axis=1), last.max(axis=1)))
+    return _PlaneCount(
+        passes=np.where(n_pass > 0, first_pc << 8 | n_pass, 0)
+        .astype(np.int32).ravel(),
+        n_windows=n_windows,
+        us=_kernel_us(n_pass, PLANE_CHUNK_US),
+        sorted_floor_us=float(chunks.sum() * WINDOW_CHUNK_US
+                              + floor.sum() * WINDOW_PASS_US))
+
+
+def _plane_table(idx: np.ndarray, val: np.ndarray, dim: int,
+                 count: _PlaneCount) -> PlaneTable:
+    n, k = idx.shape
+    live = idx < dim
+    win = idx // (WINDOW_BLOCKS * LANE)
+    word = idx - win * (WINDOW_BLOCKS * LANE)       # block in window | lane
+    word <<= _LANE_SHIFT
+    word |= win << _WIN_SHIFT
+
+    def by_plane(entries, dtype):
+        """``[N, K]`` -> ``[C*K, CHUNK]``: row ``c*K + k`` is plane ``k`` of
+        chunk ``c``'s rows; 0 in ghost entries and in the padding rows."""
+        out = np.zeros((len(count.passes) // k * CHUNK, k), dtype)
+        out[:n] = np.where(live, entries, 0)
+        return jnp.asarray(np.ascontiguousarray(
+            out.reshape(-1, CHUNK, k).transpose(0, 2, 1)).reshape(-1, CHUNK))
+
+    return PlaneTable(
+        word=by_plane(word, np.int32), val=by_plane(val, np.float32),
+        passes=jnp.asarray(count.passes), n_planes=k,
+        n_windows=count.n_windows)
+
+
+def _xw_table(idx: np.ndarray, val: np.ndarray, dim: int, q_capacity: int
+              ) -> Union[WindowTable, PlaneTable, RowSliceXw]:
+    """``X.w``'s table in the arrangement that is cheapest on this matrix:
+    by planes where they cost under ``PLANES_MARGIN`` of the sorted table
+    (outright, and with no sort, where they cost under that share of its
+    floor), else the sorted table, else (over the break-even) row slices."""
+    planes = _count_planes(idx, dim)
+    if (planes is not None
+            and planes.us < PLANES_MARGIN * planes.sorted_floor_us):
+        return _plane_table(idx, val, dim, planes)
+    table = _window_table(*_sorted_by_row_block(idx, val, dim), idx.shape[0],
+                          dim, q_capacity)
+    if not _keeps_window(table):
+        table = None
+    if planes is not None and (
+            table is None or planes.us < PLANES_MARGIN * _kernel_us(
+                table.passes & 255, WINDOW_CHUNK_US)):
+        return _plane_table(idx, val, dim, planes)
+    return _row_slice_xw(idx, dim) if table is None else _placed(table)
+
+
 def _import_the_kernels_modules_meanwhile() -> None:
     """Pallas takes about a second to import, and the first trace of a
     program that holds the kernel would wait for it (a fresh process's
@@ -399,7 +593,9 @@ def build_fast_aux(
     ``dim`` with value 0). ``q_capacity`` bounds a table row's width. Each op
     gets the ``window`` table unless its mean passes a slot lie over
     ``WINDOW_BREAK_EVEN_PASSES`` (or the table cannot be built), and the
-    row-slice table then.
+    row-slice table then; ``X.w`` the ``planes`` table where that is the
+    cheaper arrangement of the same lookup (``_xw_table``). Only the tables
+    chosen are placed on the device.
     """
     _import_the_kernels_modules_meanwhile()
     idx = np.asarray(idx)
@@ -408,14 +604,10 @@ def build_fast_aux(
 
     col, row, by_col = _sorted_by_column_block(idx, val, dim)
     xtr = _window_table(col, row, by_col, dim, n, q_capacity)
-    if not _keeps_window(xtr):
-        xtr = _row_slice_xtr(col, row, by_col, n, dim, q_capacity)
+    xtr = (_placed(xtr) if _keeps_window(xtr)
+           else _row_slice_xtr(col, row, by_col, n, dim, q_capacity))
     del col, row, by_col
-    xw = _window_table(*_sorted_by_row_block(idx, val, dim), n, dim,
-                       q_capacity)
-    if not _keeps_window(xw):
-        xw = _row_slice_xw(idx, dim)
-    return FastSparseAux(xw=xw, xtr=xtr)
+    return FastSparseAux(xw=_xw_table(idx, val, dim, q_capacity), xtr=xtr)
 
 
 # ------------------------------------------------------ ``window``: the kernel
@@ -454,43 +646,60 @@ def _window_parts(vec: Array, n_windows: int) -> Array:
     return parts.reshape(LANE, n_windows * LANE)
 
 
+def _window_iotas():
+    """``(lane_of, block_of)``, ``[128, CHUNK]``: a row's own index, and the
+    block a window holds in that row."""
+    lane_of = jax.lax.broadcasted_iota(jnp.int32, (LANE, CHUNK), 0)
+    # Row k of a window holds block k mod 42 (rows 126, 127: none).
+    block_of = (lane_of - jnp.where(lane_of >= WINDOW_BLOCKS, WINDOW_BLOCKS, 0)
+                - jnp.where(lane_of >= 2 * WINDOW_BLOCKS, WINDOW_BLOCKS, 0))
+    return lane_of, jnp.where(lane_of < 3 * WINDOW_BLOCKS, block_of, -1)
+
+
+def _lookup(vec_ref, word, packed, lane_of, block_of):
+    """The lookup both arrangements share: ``vec``'s float32 bits at the
+    element each of ``word``'s ``[1, CHUNK]`` slots names, over the windows
+    ``packed`` (``first << 8 | windows``) says its chunk reads; 0 in a slot
+    whose window is not among them."""
+    from jax.experimental import pallas as pl
+
+    lane = (word >> _LANE_SHIFT) & 127
+    block = (word >> _BLK_SHIFT) & 63
+    window = word >> _WIN_SHIFT
+    first = packed >> 8
+
+    def one_pass(p, got):
+        w = first + p
+        onehot = (block_of == jnp.where(window == w, block, 63)
+                  ).astype(jnp.bfloat16)                        # [128, CHUNK]
+        rows = jnp.dot(
+            vec_ref[:, pl.ds(pl.multiple_of(w * LANE, LANE), LANE)],
+            onehot, preferred_element_type=jnp.float32)
+        # A slot is in one window: the other passes add 0 to it.
+        return got + jnp.sum(jnp.where(lane_of == lane, rows, 0.0),
+                             axis=0, keepdims=True)
+
+    return jax.lax.fori_loop(0, packed & 255, one_pass,
+                             jnp.zeros((1, CHUNK), jnp.float32))
+
+
 def _window_kernel(passes_ref, word_ref, val_ref, vec_ref, out_ref, *, q: int):
     from jax.experimental import pallas as pl
 
     chunks = q // CHUNK
     step = pl.program_id(0)
-    lane_of = jax.lax.broadcasted_iota(jnp.int32, (LANE, CHUNK), 0)
-    # Row k of a window holds block k mod 42 (rows 126, 127: none).
-    block_of = (lane_of - jnp.where(lane_of >= WINDOW_BLOCKS, WINDOW_BLOCKS, 0)
-                - jnp.where(lane_of >= 2 * WINDOW_BLOCKS, WINDOW_BLOCKS, 0))
-    block_of = jnp.where(lane_of < 3 * WINDOW_BLOCKS, block_of, -1)
+    lane_of, block_of = _window_iotas()
 
     def table_row(r, carry):
         def chunk(c, acc):
             at = pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
             word = word_ref[pl.ds(r, 1), at]                    # [1, CHUNK]
             val = val_ref[pl.ds(r, 1), at]
-            out_lane = word & 127
-            lane = (word >> _LANE_SHIFT) & 127
-            block = (word >> _BLK_SHIFT) & 63
-            window = word >> _WIN_SHIFT
-            packed = passes_ref[(step * ROWS_PER_STEP + r) * chunks + c]
-            first = packed >> 8
-
-            def one_pass(p, got):
-                w = first + p
-                onehot = (block_of == jnp.where(window == w, block, 63)
-                          ).astype(jnp.bfloat16)                # [128, CHUNK]
-                rows = jnp.dot(
-                    vec_ref[:, pl.ds(pl.multiple_of(w * LANE, LANE), LANE)],
-                    onehot, preferred_element_type=jnp.float32)
-                # A slot is in one window: the other passes add 0 to it.
-                return got + jnp.sum(jnp.where(lane_of == lane, rows, 0.0),
-                                     axis=0, keepdims=True)
-
-            got = jax.lax.fori_loop(0, packed & 255, one_pass,
-                                    jnp.zeros((1, CHUNK), jnp.float32))
-            sel = jnp.where(lane_of == out_lane, got * val, 0.0)
+            got = _lookup(
+                vec_ref, word,
+                passes_ref[(step * ROWS_PER_STEP + r) * chunks + c],
+                lane_of, block_of)
+            sel = jnp.where(lane_of == (word & 127), got * val, 0.0)
             for j in range(CHUNK // LANE):
                 acc = acc + sel[:, j * LANE:(j + 1) * LANE]
             return acc
@@ -502,6 +711,32 @@ def _window_kernel(passes_ref, word_ref, val_ref, vec_ref, out_ref, *, q: int):
         return carry
 
     jax.lax.fori_loop(0, ROWS_PER_STEP, table_row, 0)
+
+
+def _plane_kernel(passes_ref, word_ref, val_ref, vec_ref, out_ref, *,
+                  planes: int):
+    """``ROWS_PER_STEP`` chunks of rows a grid step: a chunk's planes are
+    looked up one after another, each times its values, and summed in plane
+    order; one ``[1, CHUNK]`` store a chunk."""
+    from jax.experimental import pallas as pl
+
+    step = pl.program_id(0)
+    lane_of, block_of = _window_iotas()
+
+    def chunk(c, carry):
+        def plane(k, acc):
+            r = c * planes + k
+            got = _lookup(
+                vec_ref, word_ref[pl.ds(r, 1), :],
+                passes_ref[step * ROWS_PER_STEP * planes + r],
+                lane_of, block_of)
+            return acc + got * val_ref[pl.ds(r, 1), :]
+
+        out_ref[pl.ds(c, 1), :] = jax.lax.fori_loop(
+            0, planes, plane, jnp.zeros((1, CHUNK), jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, ROWS_PER_STEP, chunk, 0)
 
 
 def gather_reduce(table: WindowTable, vec: Array,
@@ -554,6 +789,46 @@ def _gather_reduce(table: WindowTable, vec: Array, square_vals: bool,
     return out_r.reshape(-1)
 
 
+def plane_lookup(table: PlaneTable, vec: Array) -> Array:
+    """``z[i] = Σ_k val[i, k] · vec[idx[i, k]]`` over ``table``'s planes:
+    ``[C * CHUNK]`` float32, the rows past the matrix's own all 0.
+
+    The lookup is ``gather_reduce``'s (``vec``'s float32 bits); the products
+    are float32 and summed in plane order. Values stored narrower are
+    widened first.
+    """
+    return _plane_lookup(table, vec, _interpret())
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _plane_lookup(table: PlaneTable, vec: Array, interpret: bool) -> Array:
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    k = table.n_planes
+    chunks = table.word.shape[0] // k
+    parts = _window_parts(vec, table.n_windows)
+    rows = pl.BlockSpec((ROWS_PER_STEP * k, CHUNK), lambda i, passes: (i, 0))
+    out = pl.pallas_call(
+        functools.partial(_plane_kernel, planes=k),
+        out_shape=jax.ShapeDtypeStruct((chunks, CHUNK), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(chunks // ROWS_PER_STEP,),
+            in_specs=[rows, rows, pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((ROWS_PER_STEP, CHUNK),
+                                   lambda i, passes: (i, 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(16 << 20) + 2 * parts.size
+            + 16 * ROWS_PER_STEP * k * CHUNK),
+        name="sparse_plane_lookup",
+        interpret=interpret,
+    )(table.passes, table.word, table.val.astype(jnp.float32), parts)
+    return out.reshape(-1)
+
+
 # ------------------------------------------------------------------ the ops
 
 
@@ -571,6 +846,8 @@ def matvec_fast(aux: FastSparseAux, val: Array, w: Array, dim: int) -> Array:
     n, k = val.shape
     if isinstance(aux.xw, WindowTable):
         return gather_reduce(aux.xw, w)[:n]
+    if isinstance(aux.xw, PlaneTable):
+        return plane_lookup(aux.xw, w)[:n]
     nblk = -(-dim // LANE)
     w2 = jnp.pad(w, (0, nblk * LANE - dim)).reshape(nblk, LANE)
     w2 = jnp.concatenate([w2, jnp.zeros((1, LANE), w.dtype)])  # ghost row

@@ -1,21 +1,27 @@
 #!/usr/bin/env python3
-"""What the three sparse formulations (``plain`` gather/segment_sum, the
-``fast`` row-slice tables and the ``window`` one-hot kernel) give on the
-device in use, op by op and end to end.
+"""What the sparse formulations (``plain`` gather/segment_sum, the ``fast``
+row-slice tables and the one-hot kernel over its two arrangements, the
+sorted ``window`` table and, for ``X.w``, the ``planes``) give on the device
+in use, op by op and end to end.
 
     python scripts/sparse_formulation_check.py ops       # this process owns the device
     python scripts/sparse_formulation_check.py training  # children own it, one at a time
 
-``ops``: ``matvec``/``rmatvec``/``sq_rmatvec`` of the three formulations at the
-fixed-effect shapes of the benchmark's three cells (their configurations'
-data, from the seed) and of chip_smoke.py, against float64 NumPy (largest
-error relative to the largest entry of the answer), the first call's seconds
-and the median of five warm calls on the host's clock (dispatch, one
-``block_until_ready``; not kernel time). One JSON line a shape, with the mean
-MXU passes a slot of each op's ``window`` table and what ``build_fast_aux``
-chooses by itself there: these readings are what
-``ops/fast_sparse.py`` ``WINDOW_BREAK_EVEN_PASSES`` was set from. A last line
-says whether the ``window`` lookup alone returned its operand's float32 bits.
+``ops``: ``matvec``/``rmatvec``/``sq_rmatvec`` of every formulation, each
+forced in turn, at the fixed-effect shapes of the benchmark's five cells
+(their configurations' generator, from the seed) and of chip_smoke.py,
+against float64 NumPy (largest error relative to the largest entry of the
+answer), the first call's seconds and the median of five warm calls on the
+host's clock (dispatch, one ``block_until_ready``; not kernel time). One JSON
+line a shape, with the mean MXU passes a slot of each op's ``window`` table,
+what ``build_fast_aux`` chooses by itself there, and under ``xw`` the two
+arrangements of ``X.w`` beside each other: a call's milliseconds (twenty
+calls in flight, over twenty), the chunks and passes it runs and what the
+build's model makes of them. These readings are what ``ops/fast_sparse.py``
+``WINDOW_BREAK_EVEN_PASSES``, ``WINDOW_PASS_US``, ``WINDOW_CHUNK_US``,
+``PLANE_CHUNK_US`` and ``PLANES_MARGIN`` were set from. A last line says
+whether either arrangement's lookup alone returned its operand's float32
+bits.
 
 ``training``: two one-device ``game_training_driver`` runs of chip_smoke.py's
 data that differ only in the formulation — so only in the order float32
@@ -33,6 +39,7 @@ runs the kernel in the Pallas interpreter).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -46,7 +53,20 @@ import chip_smoke  # noqa: E402  (jax-free)
 
 # The benchmark's cells, by the configuration file that holds their data.
 CELLS = {"glm_fit": "glm-logistic-l2", "glm_fit_tron": "glm-logistic-tron",
-         "game_fit": "game-logistic-user-re"}
+         "game_fit": "game-logistic-user-re",
+         "game_fit_ragged": "game-logistic-ragged-re",
+         "game_fit_crossed": "game-logistic-crossed-re"}
+# The constants of ``ops/fast_sparse.py`` that make ``build_fast_aux`` give
+# every op it can the named formulation (``X^T.r`` has no ``planes``: it
+# takes ``window`` there): a script's privilege, not an option of the program.
+FORCED = {
+    "fast": {"WINDOW_BREAK_EVEN_PASSES": -1.0},
+    "window": {"WINDOW_BREAK_EVEN_PASSES": float("inf"), "PLANES_MARGIN": 0.0},
+    "planes": {"WINDOW_BREAK_EVEN_PASSES": float("inf"),
+               "PLANES_MARGIN": float("inf")},
+}
+# ``fast`` X.w writes 512 B an entry at once: not asked of the chip over this.
+FAST_XW_BYTES = 8e9
 
 
 def _shapes(sizes: dict, seed: int, rehearse: bool):
@@ -55,16 +75,23 @@ def _shapes(sizes: dict, seed: int, rehearse: bool):
 
     from benchmarks import datagen
 
+    from benchmarks.kinds import fit_ragged
+
     for cell, config in CELLS.items():
         with open(os.path.join(HERE, "benchmarks", "configs",
                                config + ".json")) as f:
             data = json.load(f)["data"]
+        per_user = data.get("rows_per_user", 0)
+        rows = (data["rows"] if "rows" in data
+                else int(fit_ragged.user_counts(data).sum())
+                if isinstance(per_user, dict) else data["users"] * per_user)
         if rehearse:     # the cell's widths over a four-hundredth of its rows
-            data = {**data, **{key: max(1, data[key] // 400)
-                               for key in ("rows", "users") if key in data},
-                    "validation": {**data["validation"], "rows": 8}
-                    if "rows" in data["validation"] else data["validation"]}
-        train = datagen.generate(data, seed).train
+            rows //= 400
+        # The fixed effect's shard alone, as ``datagen.generate`` draws it
+        # for rows with no user.
+        wg = np.zeros(data["named_features"])
+        train = datagen._rows(np.random.default_rng([seed, 1]), data, None,
+                              rows, wg, None, None)
         yield (cell, train.gi.astype(np.int32), train.gv.astype(np.float32),
                data["named_features"] + 1)
     n = sizes["n_users"] * sizes["rows_per_user"]
@@ -76,26 +103,60 @@ def _shapes(sizes: dict, seed: int, rehearse: bool):
     yield "smoke", idx, rng.normal(size=(n, k)).astype(np.float32), dim
 
 
-def _select_is_bit_exact(seed: int) -> bool:
-    """The ``window`` lookup alone (one entry a row, value 1) on float32
-    operands from 1e-30 to 1e30, zeros and negatives."""
+def _select_is_bit_exact(seed: int, arrangement: str) -> bool:
+    """The lookup alone (one entry a row, value 1) on float32 operands from
+    1e-30 to 1e30, zeros and negatives, by the named arrangement."""
     import numpy as np
 
     from photon_tpu.data.batch import SparseFeatures
+    from photon_tpu.ops import fast_sparse
 
     rng = np.random.default_rng([seed, 10])
     n, dim = 4096, 20000
     x = (rng.normal(size=dim) * 10.0 ** rng.uniform(-30, 30, size=dim)
          ).astype(np.float32)
     x[::7] = 0.0
-    # Sorted, so that 128 rows read a narrow span and the build chooses
-    # ``window`` by itself.
+    # Sorted, so that 128 rows read a narrow span.
     idx = np.sort(rng.integers(0, dim, size=n)).astype(np.int32)[:, None]
-    feats = SparseFeatures(idx=idx, val=np.ones((n, 1), np.float32),
-                           dim=dim).with_fast_path()
-    assert feats.fast.formulation("matvec") == "window"
+    with _forced(fast_sparse, arrangement):
+        feats = SparseFeatures(idx=idx, val=np.ones((n, 1), np.float32),
+                               dim=dim).with_fast_path()
+    assert feats.fast.formulation("matvec") == arrangement
     got = np.asarray(feats.matvec(x))
     return bool((got.view(np.uint32) == x[idx[:, 0]].view(np.uint32)).all())
+
+
+@contextlib.contextmanager
+def _forced(fast_sparse, formulation: str):
+    kept = {name: getattr(fast_sparse, name) for name in FORCED[formulation]}
+    for name, value in FORCED[formulation].items():
+        setattr(fast_sparse, name, value)
+    try:
+        yield
+    finally:
+        for name, value in kept.items():
+            setattr(fast_sparse, name, value)
+
+
+def _xw_reading(fast_sparse, feats, fn, x) -> dict:
+    """One arrangement of ``X.w``: what it runs, what the build's model
+    makes of that, and a call's milliseconds with twenty in flight."""
+    import numpy as np
+
+    table = feats.fast.xw
+    n_pass = np.asarray(table.passes) & 255
+    calls = 20
+    t0 = time.monotonic()
+    for _ in range(calls):
+        out = fn(feats, x)
+    out.block_until_ready()
+    return {"chunks": int(np.count_nonzero(n_pass)),
+            "passes": int(n_pass.sum()),
+            "model_ms": fast_sparse._kernel_us(
+                n_pass, fast_sparse.WINDOW_CHUNK_US
+                if table.formulation == "window"
+                else fast_sparse.PLANE_CHUNK_US) / 1e3,
+            "call_ms": (time.monotonic() - t0) / calls * 1e3}
 
 
 def _ops(sizes: dict, seed: int, rehearse: bool) -> None:
@@ -106,7 +167,6 @@ def _ops(sizes: dict, seed: int, rehearse: bool) -> None:
     from photon_tpu.ops import fast_sparse
 
     dev = jax.devices()[0]
-    break_even = fast_sparse.WINDOW_BREAK_EVEN_PASSES
     for shape, idx, val, dim in _shapes(sizes, seed, rehearse):
         n, k = idx.shape
         rng = np.random.default_rng([seed, 11])
@@ -128,21 +188,18 @@ def _ops(sizes: dict, seed: int, rehearse: bool) -> None:
         chosen = plain.with_fast_path().fast
         out = {"mode": "ops", "device": dev.device_kind,
                "platform": dev.platform, "shape_of": shape,
-               "shape": [n, k, dim], "break_even_passes": break_even,
+               "shape": [n, k, dim],
+               "chosen_by": {name: getattr(fast_sparse, name) for name in (
+                   "WINDOW_BREAK_EVEN_PASSES", "WINDOW_PASS_US",
+                   "WINDOW_CHUNK_US", "PLANE_CHUNK_US", "PLANES_MARGIN")},
                "chosen": {op: chosen.formulation(op)
-                          for op in ("matvec", "rmatvec")}}
+                          for op in ("matvec", "rmatvec")}, "xw": {}}
         del chosen
-        # Each table formulation forced in turn, by the constant the build
-        # chooses by: a script's privilege, not an option of the program.
-        for name, forced in (("plain", None), ("fast", -1.0),
-                             ("window", float("inf"))):
+        for name in ("plain", *FORCED):
             feats = plain
-            if forced is not None:
-                fast_sparse.WINDOW_BREAK_EVEN_PASSES = forced
-                try:
+            if name in FORCED:
+                with _forced(fast_sparse, name):
                     feats = plain.with_fast_path()
-                finally:
-                    fast_sparse.WINDOW_BREAK_EVEN_PASSES = break_even
             if name == "window":
                 out["passes_per_slot"] = {
                     key[len("passes_per_slot_"):]: value
@@ -150,7 +207,10 @@ def _ops(sizes: dict, seed: int, rehearse: bool) -> None:
                     if key.startswith("passes_per_slot_")}
             for op, arg in (("matvec", w), ("rmatvec", v), ("sq_rmatvec", v)):
                 if feats.fast is not None and feats.fast.formulation(op) != name:
-                    continue     # no window table can hold this op's entries
+                    continue     # this op's entries have no such table
+                if (name, op) == ("fast", "matvec") and (
+                        512 * n * k > FAST_XW_BYTES):
+                    continue
                 fn = jax.jit(lambda f, x, op=op: getattr(f, op)(x))
                 x = jax.device_put(arg)
                 t0 = time.monotonic()
@@ -167,10 +227,14 @@ def _ops(sizes: dict, seed: int, rehearse: bool) -> None:
                         float(err / np.abs(want[op]).max()),
                     "first_call_s": round(first, 3),
                     "median_warm_s": sorted(warm)[2]}
+                if op == "matvec" and name in ("window", "planes"):
+                    out["xw"][name] = _xw_reading(fast_sparse, feats, fn, x)
             del feats
         print(json.dumps(out), flush=True)
-    print(json.dumps({"mode": "ops", "window_select_bit_exact":
-                      _select_is_bit_exact(seed)}), flush=True)
+    print(json.dumps({"mode": "ops", **{
+        f"{arrangement}_select_bit_exact": _select_is_bit_exact(
+            seed, arrangement)
+        for arrangement in ("window", "planes")}}), flush=True)
 
 
 def _training(sizes: dict, seed: int, out: str, platform: str) -> None:

@@ -30,6 +30,8 @@ from photon_tpu.ops.fast_sparse import (
     ROW_PAD,
     WINDOW_BLOCKS,
     FastSparseAux,
+    PlaneTable,
+    ROWS_PER_STEP,
     RowSliceXtr,
     RowSliceXw,
     WindowTable,
@@ -138,12 +140,26 @@ WINDOW_SHAPES = {
 }
 
 
-def _window_features(sh, shape: str) -> SparseFeatures:
+def _plane_table(sh, n: int, k: int, d: int) -> PlaneTable:
+    rows = -(-n // (ROWS_PER_STEP * CHUNK)) * ROWS_PER_STEP * k
+    return PlaneTable(
+        word=_sds((rows, CHUNK), "int32", sh),
+        val=_sds((rows, CHUNK), "float32", sh),
+        passes=_sds((rows,), "int32", sh), n_planes=k,
+        n_windows=-(-d // (WINDOW_BLOCKS * 128)))
+
+
+def _window_features(sh, shape: str, planes: bool = False) -> SparseFeatures:
+    """The cell's features on the kernel's tables; with ``planes`` X.w's
+    is the ``planes`` table (what the build chooses in ``game_fit``, and
+    forced in the others)."""
     n, k, d, xw, xtr = WINDOW_SHAPES[shape]
     return SparseFeatures(
         idx=_sds((n, k), "int32", sh), val=_sds((n, k), "float32", sh), dim=d,
-        fast=FastSparseAux(xw=_window_table(sh, *xw, n, d),
-                           xtr=_window_table(sh, *xtr, d, n)))
+        fast=FastSparseAux(
+            xw=_plane_table(sh, n, k, d) if planes
+            else _window_table(sh, *xw, n, d),
+            xtr=_window_table(sh, *xtr, d, n)))
 
 
 @pytest.fixture
@@ -157,7 +173,8 @@ def _holds_no_row_slices(compiled, n: int, k: int) -> None:
     """The kernel is in the program, and no float32 ``[*, 128]`` value of
     the entries' length (what the row-slice gather writes) is."""
     text = compiled.as_text()
-    assert "sparse_gather_reduce" in text or "tpu_custom_call" in text
+    assert ("sparse_gather_reduce" in text or "sparse_plane_lookup" in text
+            or "tpu_custom_call" in text)
     wide = [int(m) for m in re.findall(r"f32\[(\d+),128\]", text)]
     assert all(rows < n * k // 8 for rows in wide), max(wide)
 
@@ -211,17 +228,34 @@ def test_window_ops_compile_and_write_no_row_slices(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
-@pytest.mark.parametrize("shape,spec", [
-    ("glm_fit", FIXED), ("game_fit", FIXED), ("smoke", FIXED),
-    ("glm_fit_tron", FIXED_TRON)])
+@pytest.mark.parametrize("shape", list(WINDOW_SHAPES))
+def test_x_w_by_planes_compiles_and_writes_no_row_slices(
+        one_chip, compiled_kernel, shape):
+    """``plane_lookup`` at the cells' shapes and the smoke's (4, 8, 32, 52
+    and 76 planes a chunk): the chip's compiler takes it, and the program
+    holds no temporary of the entries' length."""
+    feats = _window_features(one_chip, shape, planes=True)
+    n, k, d = WINDOW_SHAPES[shape][:3]
+    compiled = jax.jit(lambda f, x: f.matvec(x)).lower(
+        feats, _sds((d,), "float32", one_chip)).compile()
+    assert "sparse_plane_lookup" in compiled.as_text()
+    _holds_no_row_slices(compiled, n, k)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("shape,spec,planes", [
+    ("glm_fit", FIXED, False), ("game_fit", FIXED, False),
+    ("smoke", FIXED, False), ("glm_fit_tron", FIXED_TRON, False),
+    ("game_fit", FIXED, True), ("glm_fit_tron", FIXED_TRON, True)])
 def test_fit_program_holds_the_kernel_and_no_row_slices(
-        one_chip, compiled_kernel, shape, spec):
+        one_chip, compiled_kernel, shape, spec, planes):
     """``_fit_jitted`` (L-BFGS, and TRON with its nested loops) over the
-    ``window`` tables: the kernel inside ``lax.while_loop``s compiles for
-    the chip, and the fit program's temporaries fall to a few vectors."""
+    ``window`` tables, and with X.w by ``planes``: the kernel inside
+    ``lax.while_loop``s compiles for the chip, and the fit program's
+    temporaries fall to a few vectors."""
     from photon_tpu.functions.problem import _fit_jitted
 
-    feats = _window_features(one_chip, shape)
+    feats = _window_features(one_chip, shape, planes)
     n, k, d = WINDOW_SHAPES[shape][:3]
     batch = LabeledBatch(
         features=feats, labels=_sds((n,), "float32", one_chip),
@@ -240,11 +274,11 @@ def test_fit_program_holds_the_kernel_and_no_row_slices(
 def test_a_fit_over_ten_million_rows_holds_no_whole_table_of_row_slices(
         one_chip, compiled_kernel):
     """``game_fit_ragged``'s fixed effect (PR 33): 9,997,911 rows x 4 over
-    26,765 columns. X.w keeps ``window`` (five windows of ``w``); X^T.r
-    keeps the row-slice table (a column range's slots read rows all over a
-    vector too long for VMEM), whose 40 M slots would be 20 GB of row
-    slices at once. Walked a block of table rows at a time the fit program
-    read 1.6 GB of temporaries here, and compiles."""
+    26,765 columns. X.w goes by ``planes`` (PR 37; five windows of ``w``);
+    X^T.r keeps the row-slice table (a column range's slots read rows all
+    over a vector too long for VMEM), whose 40 M slots would be 20 GB of
+    row slices at once. Walked a block of table rows at a time the fit
+    program read 1.6 GB of temporaries here, and compiles."""
     from photon_tpu.functions.problem import _fit_jitted
 
     n, k, d, cs_rows = 9997911, 4, 26765, 19932
@@ -258,7 +292,7 @@ def test_a_fit_over_ten_million_rows_holds_no_whole_table_of_row_slices(
         n_ranges=-(-d // 128), n_row_blocks=-(-n // 128))
     feats = SparseFeatures(
         idx=_sds((n, k), "int32", sh), val=_sds((n, k), "float32", sh), dim=d,
-        fast=FastSparseAux(xw=_window_table(sh, 78112, 1024, n, d), xtr=xtr))
+        fast=FastSparseAux(xw=_plane_table(sh, n, k, d), xtr=xtr))
     batch = LabeledBatch(
         features=feats, labels=_sds((n,), "float32", sh),
         offsets=_sds((n,), "float32", sh), weights=_sds((n,), "float32", sh))

@@ -1,7 +1,8 @@
 """Correctness of the table formulations of the sparse pass
-(ops/fast_sparse.py: the ``window`` Pallas kernel, here in the interpreter,
-and the ``fast`` row-slice tables) and the incremental-score L-BFGS variant,
-vs the generic implementations."""
+(ops/fast_sparse.py: the Pallas kernel over its two arrangements, the sorted
+``window`` table and ``X.w``'s ``planes``, here in the interpreter, and the
+``fast`` row-slice tables) and the incremental-score L-BFGS variant, vs the
+generic implementations."""
 import dataclasses
 
 import jax
@@ -14,12 +15,15 @@ from photon_tpu.functions.objective import GLMObjective
 from photon_tpu.functions.problem import GLMOptimizationProblem
 from photon_tpu.ops import fast_sparse
 from photon_tpu.ops.fast_sparse import (
+    FastSparseAux,
+    PlaneTable,
     RowSliceXtr,
     RowSliceXw,
     WindowTable,
     build_fast_aux,
     gather_reduce,
     matvec_fast,
+    plane_lookup,
     rmatvec_fast,
 )
 from photon_tpu.ops.losses import loss_for_task
@@ -28,17 +32,28 @@ from photon_tpu.optim import (LBFGS, OptimizerConfig, OptimizerType,
 from photon_tpu.types import TaskType
 
 
-FORMULATIONS = ["window", "fast"]
+FORMULATIONS = ["window", "planes", "fast"]
+# The kernel's two arrangements of ``X.w``.
+ARRANGEMENTS = ["window", "planes"]
+XW_TABLE = {"window": WindowTable, "planes": PlaneTable, "fast": RowSliceXw}
+# What ``X^T.r`` runs beside each: it has no ``planes``.
+XTR_OF = {"window": "window", "planes": "window", "fast": "fast"}
+FORCED = {
+    "window": {"WINDOW_BREAK_EVEN_PASSES": float("inf"), "PLANES_MARGIN": 0.0},
+    "planes": {"WINDOW_BREAK_EVEN_PASSES": float("inf"),
+               "PLANES_MARGIN": float("inf")},
+    "fast": {"WINDOW_BREAK_EVEN_PASSES": -1.0},
+}
 
 
 @pytest.fixture
 def force(monkeypatch):
-    """Make ``build_fast_aux`` give every op the named table formulation,
-    by the constant it chooses by."""
+    """Make ``build_fast_aux`` give every op the named table formulation
+    (``X^T.r`` under ``planes``: ``window``), by the constants it chooses
+    by."""
     def _force(formulation):
-        monkeypatch.setattr(
-            fast_sparse, "WINDOW_BREAK_EVEN_PASSES",
-            {"window": float("inf"), "fast": -1.0}[formulation])
+        for name, value in FORCED[formulation].items():
+            monkeypatch.setattr(fast_sparse, name, value)
     return _force
 
 
@@ -69,7 +84,8 @@ def test_matvec_rmatvec_match_generic(force, formulation, n, dim, k, skew):
     sf = _random_sparse(n, dim, k, seed=1, skew=skew)
     aux = build_fast_aux(np.asarray(sf.idx), np.asarray(sf.val), dim,
                          q_capacity=64)
-    assert aux.formulation("matvec") == aux.formulation("rmatvec") == formulation
+    assert aux.formulation("matvec") == formulation
+    assert aux.formulation("rmatvec") == XTR_OF[formulation]
     rng = np.random.default_rng(2)
     w = jnp.asarray(rng.normal(size=dim).astype(np.float32))
     v = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -186,7 +202,7 @@ def test_problem_run_uses_scored_path_and_matches():
 
 def _table_values(aux):
     """The arrays of the attached tables that hold feature values."""
-    return [t.val if isinstance(t, WindowTable) else t.cs_val
+    return [t.cs_val if isinstance(t, RowSliceXtr) else t.val
             for t in (aux.xw, aux.xtr) if not isinstance(t, RowSliceXw)]
 
 
@@ -205,7 +221,7 @@ def test_value_dtype_bfloat16_exact_for_binary_features(force, formulation):
     nf = sf.with_value_dtype(jnp.bfloat16)
     assert nf.val.dtype == jnp.bfloat16
     assert [v.dtype for v in _table_values(nf.fast)] == (
-        [jnp.bfloat16] * (2 if formulation == "window" else 1))
+        [jnp.bfloat16] * (1 if formulation == "fast" else 2))
 
     w = jnp.asarray(rng.normal(size=dim).astype(np.float32))
     v = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -322,8 +338,9 @@ def test_digit_dtype_narrows_and_results_match(force):
 @pytest.mark.parametrize("nnz", [5, 8, 52, 75, 76])
 def test_matvec_fast_matches_float64_gather_at_row_width(
         force, formulation, nnz, value_dtype):
-    """X.w (the kernel's row-range table, and the flat lane select) against
-    the plain gather in float64, at row widths on and off a multiple of 8,
+    """X.w (the kernel's row-range table, its planes, and the flat lane
+    select) against the plain gather in float64, at row widths on and off a
+    multiple of 8,
     an odd row count (72,309's kind), ghost entries in some rows and values
     stored narrow."""
     force(formulation)
@@ -339,6 +356,7 @@ def test_matvec_fast_matches_float64_gather_at_row_width(
     sf = SparseFeatures(idx=jnp.asarray(idx), val=jnp.asarray(val),
                         dim=dim).with_value_dtype(value_dtype)
     aux = sf.with_fast_path(q_capacity=64).fast
+    assert type(aux.xw) is XW_TABLE[formulation]
     if formulation == "fast":
         assert aux.xw.hi.ndim == aux.xw.lo.ndim == 1
     else:
@@ -428,7 +446,7 @@ def test_fast_ops_match_float64_on_duplicate_and_hot_columns(
         _scatter64(idx, val, dz, d, square=True), rtol=0, atol=5e-5)
 
 
-@pytest.mark.parametrize("formulation", FORMULATIONS)
+@pytest.mark.parametrize("formulation", ["window", "fast"])
 @pytest.mark.parametrize("square_vals", [False, True])
 @pytest.mark.parametrize("n", [257, 1000, 1025])
 def test_rmatvec_fast_matches_float64_scatter_off_the_row_grids(
@@ -527,27 +545,37 @@ def _cell_like(seed, n, k, dim, head, ghost_frac=0.05):
             np.where(ghost, 0, val).astype(np.float32))
 
 
-# Scaled-down shapes of the three cells and of the smoke: the row widths of
-# each (76, 52, 8, 32), a row count off every grid (72,309's kind), vectors
-# over more than one 5,376-element window on the side each cell has them.
+# Scaled-down shapes of the five cells and of the smoke: the row widths of
+# each (76, 52, 8, 4, 3, 32), a row count off every grid (72,309's kind),
+# vectors over more than one 5,376-element window on the side each cell has
+# them. ``game_fit_ragged``'s planes are typed as the cell's: a head plane
+# (window 0), two over all 26,764 named columns (windows 0 to 4), the
+# intercept (window 4); ``game_fit_crossed``'s three read one window.
 CELL_SHAPES = {
     "glm_fit": (565, 76, 6001, 1024),
     "glm_fit_tron": (723, 52, 2100, 1024),
     "game_fit": (6003, 8, 377, 64),
+    "game_fit_ragged": (3003, 4, 26765, 20),
+    "game_fit_crossed": (3003, 3, 21, 20),
     "smoke": (1024, 32, 12000, 1024),
 }
+# Every op the kernel runs: ``X^T.r`` and its squared twin have no planes.
+KERNEL_OPS = [(a, op) for a in ARRANGEMENTS
+              for op in ("matvec", "rmatvec", "sq_rmatvec")
+              if a == "window" or op == "matvec"]
 
 
-@pytest.mark.parametrize("op", ["matvec", "rmatvec", "sq_rmatvec"])
+@pytest.mark.parametrize("arrangement,op", KERNEL_OPS)
 @pytest.mark.parametrize("shape", list(CELL_SHAPES))
-def test_window_ops_match_plain_at_the_cells_shapes(force, shape, op):
-    force("window")
+def test_window_ops_match_plain_at_the_cells_shapes(force, shape,
+                                                    arrangement, op):
+    force(arrangement)
     n, k, dim, head = CELL_SHAPES[shape]
     idx, val = _cell_like(31, n, k, dim, head)
     assert (idx == dim).any()
     plain = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
     feats = plain.with_fast_path()
-    assert feats.fast.formulation(op) == "window"
+    assert feats.fast.formulation(op) == arrangement
     rng = np.random.default_rng(32)
     x = jnp.asarray(rng.normal(size=dim if op == "matvec" else n)
                     .astype(np.float32))
@@ -558,29 +586,32 @@ def test_window_ops_match_plain_at_the_cells_shapes(force, shape, op):
                                atol=1e-5 * np.abs(want).max())
 
 
-@pytest.mark.parametrize("where", ["jit", "while_loop"])
-def test_window_ops_under_jit_and_inside_a_while_loop(force, where):
+@pytest.mark.parametrize("arrangement", ARRANGEMENTS)
+@pytest.mark.parametrize("where", ["jit", "while_loop", "nested_while_loops"])
+def test_window_ops_under_jit_and_inside_a_while_loop(force, where,
+                                                      arrangement):
     """The kernel traced into a larger program, as ``_fit_jitted`` holds it
-    (L-BFGS's and TRON's loops are ``lax.while_loop``s)."""
-    force("window")
+    (L-BFGS's loop is a ``lax.while_loop``, TRON's CG loop one inside
+    another)."""
+    force(arrangement)
     n, k, dim, head = CELL_SHAPES["glm_fit_tron"]
     idx, val = _cell_like(33, n, k, dim, head)
     plain = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
     feats = plain.with_fast_path()
+    assert feats.fast.formulation("matvec") == arrangement
     w0 = jnp.asarray(np.random.default_rng(34).normal(size=dim)
                      .astype(np.float32))
 
     def step(f, w):
         return w - 0.01 * f.rmatvec(jnp.tanh(f.matvec(w)))
 
-    if where == "jit":
-        run = jax.jit(step)
-    else:
-        def run(f, w):
-            return jax.lax.while_loop(
-                lambda c: c[0] < 3, lambda c: (c[0] + 1, step(f, c[1])),
-                (0, w))[1]
-        run = jax.jit(run)
+    def thrice(body):
+        return lambda f, w: jax.lax.while_loop(
+            lambda c: c[0] < 3, lambda c: (c[0] + 1, body(f, c[1])),
+            (0, w))[1]
+
+    run = jax.jit({"jit": step, "while_loop": thrice(step),
+                   "nested_while_loops": thrice(thrice(step))}[where])
     np.testing.assert_allclose(np.asarray(run(feats, w0)),
                                np.asarray(run(plain, w0)),
                                rtol=1e-5, atol=1e-5)
@@ -595,12 +626,14 @@ def _one_entry_a_row(n, dim, seed):
                                dim)
 
 
+@pytest.mark.parametrize("arrangement", ARRANGEMENTS)
 @pytest.mark.parametrize("values", [
     "1e-30_to_1e30", "negatives", "zeros_between", "integers", "ulps_of_one"])
-def test_window_select_returns_the_float32_bits(force, values):
+def test_window_select_returns_the_float32_bits(force, values, arrangement):
     """The lookup alone: three exact bfloat16 parts through a one-hot
-    product give back the operand's float32, bit for bit."""
-    force("window")
+    product give back the operand's float32, bit for bit, by either
+    arrangement of the slots."""
+    force(arrangement)
     n, dim = 700, 12000          # w over three windows
     rng = np.random.default_rng(35)
     x = rng.normal(size=dim) * 10.0 ** rng.uniform(-30, 30, size=dim)
@@ -615,41 +648,114 @@ def test_window_select_returns_the_float32_bits(force, values):
     x = x.astype(np.float32)
     idx, feats = _one_entry_a_row(n, dim, 36)
     feats = feats.with_fast_path()
-    assert feats.fast.formulation("matvec") == "window"
+    assert feats.fast.formulation("matvec") == arrangement
     got = np.asarray(feats.matvec(jnp.asarray(x)))
     np.testing.assert_array_equal(got.view(np.uint32),
                                   x[idx[:, 0]].view(np.uint32))
 
 
-@pytest.mark.parametrize("shape,chosen", [("narrow", "window"),
-                                          ("wide", "fast")])
-def test_build_counts_passes_a_slot_and_chooses_by_them(shape, chosen):
-    """Columns in a narrow head read one window a chunk; columns anywhere
-    in forty windows read twenty of them (a range's 1,024 sorted entries are
-    two chunks), and the op keeps the row-slice table."""
-    n, k, dim = 1024, 8, 40 * fast_sparse.WINDOW_BLOCKS * 128
+def _typed_planes(rng, n, dim, head=20):
+    """``game_fit_ragged``'s row: a head entry, two anywhere, the intercept."""
+    return np.concatenate([
+        rng.integers(0, head, size=(n, 1)), rng.integers(0, dim - 1, (n, 2)),
+        np.full((n, 1), dim - 1)], axis=1).astype(np.int32)
+
+
+def _matrix(shape):
+    """``(idx, dim)`` of the matrices the choice is read on."""
     rng = np.random.default_rng(37)
-    idx = rng.integers(0, dim, size=(n, k)).astype(np.int32)
-    if shape == "narrow":
-        idx %= 1024
-    val = rng.normal(size=(n, k)).astype(np.float32)
+    windows = fast_sparse.WINDOW_BLOCKS * 128
+    if shape == "tall_typed":           # five windows of w, four planes
+        return _typed_planes(rng, 4096, 26765), 26765
+    if shape == "tall_one_window":      # game_fit_crossed's, game_fit's kind
+        return rng.integers(0, 21, size=(4096, 3)).astype(np.int32), 21
+    if shape == "glm_fit":
+        # 76 entries a row, half of them anywhere in nine windows: a sorted
+        # chunk reads a window or two, a plane-chunk of the tail all nine.
+        n, k, dim, head = 2048, 76, 47237, 1024
+        return _cell_like(37, n, k, dim, head, ghost_frac=0.0)[0], dim
+    if shape == "near_level":
+        # Eight head planes and eight over four windows: a 128-row range is
+        # a sorted chunk of one pass and one of four (0.48 + 1.5 us by the
+        # model), 1,024 rows by planes 16 plane-chunks of 8 + 32 passes
+        # (12.56 us against 15.84): cheaper by planes, and not by the margin.
+        dim = 4 * windows
+        return np.concatenate([
+            rng.integers(0, 1000, size=(2048, 8)),
+            rng.integers(0, dim, size=(2048, 8))], axis=1).astype(np.int32), dim
+    if shape == "wide":                 # columns anywhere in forty windows
+        return (rng.integers(0, 40 * windows, size=(1024, 8))
+                .astype(np.int32), 40 * windows)
+    raise ValueError(shape)
+
+
+@pytest.mark.parametrize("shape,chosen,sorts", [
+    ("tall_typed", "planes", False), ("tall_one_window", "planes", False),
+    ("glm_fit", "window", True), ("near_level", "window", True),
+    ("wide", "fast", True)])
+def test_build_counts_both_arrangements_and_chooses_by_them(
+        monkeypatch, shape, chosen, sorts):
+    """``build_fast_aux`` counts what X.w would run by planes and by the
+    sorted table and weighs the two by the kernel's measured costs: a tall
+    typed matrix goes by planes (outright: no sort is made), ``glm_fit``'s
+    shape keeps the sorted table, and so does a matrix on which the planes
+    are cheaper by less than the margin; columns anywhere in forty windows
+    read twenty of them a sorted chunk and forty a plane-chunk, and the op
+    keeps the row-slice table. The table not chosen is not on the pytree."""
+    idx, dim = _matrix(shape)
+    n, k = idx.shape
+    val = np.random.default_rng(38).normal(size=(n, k)).astype(np.float32)
+
+    count = fast_sparse._count_planes(idx, dim)
     forced = fast_sparse._window_table(
         *fast_sparse._sorted_by_row_block(idx, val, dim), n, dim, 2048)
-    if shape == "narrow":
-        assert forced.passes_per_slot() == 1.0
+    sorted_us = fast_sparse._kernel_us(forced.passes & 255,
+                                       fast_sparse.WINDOW_CHUNK_US)
+    if shape == "wide":
+        assert count is None and forced.passes_per_slot() > 10
     else:
-        assert forced.passes_per_slot() > 10
+        # The floor is one: no sorted table of the matrix goes under it.
+        assert count.sorted_floor_us <= sorted_us + 1e-9
+        assert (count.us < fast_sparse.PLANES_MARGIN * sorted_us) == (
+            chosen == "planes")
+    if shape == "near_level":
+        assert count.us < sorted_us
+    if shape == "tall_typed":
+        n_pass = (count.passes & 255).reshape(-1, k)
+        assert (n_pass[: n // 1024] == [1, 5, 5, 1]).all()
+        assert not n_pass[n // 1024:].any()         # the padding chunks
+        assert forced.passes_per_slot() == 5.0      # 512 entries a chunk
+        assert (count.passes >> 8).reshape(-1, k)[0].tolist() == [0, 0, 0, 4]
+
+    sorted_calls = []
+    sort = fast_sparse._sorted_by_row_block
+    monkeypatch.setattr(
+        fast_sparse, "_sorted_by_row_block",
+        lambda *a: sorted_calls.append(1) or sort(*a))
     aux = build_fast_aux(idx, val, dim)
+    assert bool(sorted_calls) == sorts
     assert aux.formulation("matvec") == chosen
-    assert isinstance(aux.xw, WindowTable if chosen == "window" else RowSliceXw)
-    # X^T.r gathers by row: 1,024 rows are one window, whatever the columns.
+    assert type(aux.xw) is XW_TABLE[chosen]
+    leaves = jax.tree_util.tree_leaves(aux.xw)
+    assert len(leaves) == {"planes": 3, "window": 4, "fast": 2}[chosen]
+    assert all(isinstance(leaf, jax.Array) for leaf in leaves)
+    # X^T.r gathers by row: these rows are one window, whatever the columns.
     assert aux.formulation("rmatvec") == "window"
     assert aux.xtr.passes_per_slot() == 1.0
     args = aux.span_arguments()
     assert args["formulation_matvec"] == chosen
     assert args["formulation_rmatvec"] == "window"
-    assert ("passes_per_slot_matvec" in args) == (chosen == "window")
+    assert ("passes_per_slot_matvec" in args) == (chosen != "fast")
+    if chosen == "planes":
+        assert args["passes_per_slot_matvec"] == {
+            "tall_typed": 3.0, "tall_one_window": 1.0}[shape]
     assert args["passes_per_slot_rmatvec"] == 1.0
+    w = jnp.asarray(np.random.default_rng(39).normal(size=dim)
+                    .astype(np.float32))
+    plain = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
+    np.testing.assert_allclose(
+        np.asarray(matvec_fast(aux, plain.val, w, dim)),
+        np.asarray(plain.matvec(w)), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("square_vals", [False, True])
@@ -690,12 +796,15 @@ def test_a_vector_too_long_for_vmem_keeps_the_row_slice_table(monkeypatch):
     assert isinstance(aux.xtr, WindowTable)        # dz: one
 
 
-@pytest.mark.parametrize("op", ["matvec", "rmatvec"])
-def test_padding_slots_contribute_nothing(force, op):
+@pytest.mark.parametrize("arrangement,op", [
+    ("window", "matvec"), ("window", "rmatvec"), ("planes", "matvec")])
+def test_padding_slots_contribute_nothing(force, arrangement, op):
     """A table is mostly padding at this size (3 entries a range in rows of
-    512 slots): the padding's value is 0 and its lookup lands on element 0
-    of the vector, which may be as large as float32 goes."""
-    force("window")
+    512 slots; by planes 3 live entries among the ghosts of 300 rows and
+    the rows that pad them to whole grid steps): the padding's value is 0
+    and its lookup lands on element 0 of the vector, which may be as large
+    as float32 goes."""
+    force(arrangement)
     n, dim = 300, 400
     idx = np.full((n, 2), dim, np.int32)
     val = np.zeros((n, 2), np.float32)
@@ -737,25 +846,51 @@ def test_gather_reduce_is_the_one_kernel_of_both_ops(force):
         np.asarray(aux.xtr.val) != 0).sum() == (val != 0).sum()
 
 
-def test_window_table_takes_plain_for_an_operand_that_is_not_float32(force):
+def test_plane_lookup_is_x_w_over_the_ell_block_as_it_lies(force):
+    """``matvec`` by planes is ``plane_lookup``: the table holds the ELL
+    block's live entries plane by plane, in the rows' own order (no sort),
+    and the rows that pad it to whole grid steps read 0."""
+    force("planes")
+    n, k, dim, head = CELL_SHAPES["game_fit_ragged"]
+    idx, val = _cell_like(39, n, k, dim, head)
+    table = build_fast_aux(idx, val, dim).xw
+    w = jnp.asarray(np.random.default_rng(40).normal(size=dim)
+                    .astype(np.float32))
+    out = np.asarray(plane_lookup(table, w))
+    steps = -(-n // (fast_sparse.ROWS_PER_STEP * fast_sparse.CHUNK))
+    assert out.shape == (steps * fast_sparse.ROWS_PER_STEP
+                         * fast_sparse.CHUNK,)
+    assert not out[n:].any()
+    np.testing.assert_array_equal(
+        out[:n], np.asarray(matvec_fast(
+            FastSparseAux(xw=table, xtr=None), jnp.asarray(val), w, dim)))
+    by_plane = np.asarray(table.val).reshape(-1, k, fast_sparse.CHUNK)
+    np.testing.assert_array_equal(
+        by_plane.transpose(0, 2, 1).reshape(-1, k)[:n], val)
+    assert table.n_planes == k and table.n_windows == 5
+
+
+@pytest.mark.parametrize("arrangement", ARRANGEMENTS)
+def test_window_table_takes_plain_for_an_operand_that_is_not_float32(
+        force, arrangement):
     """The kernel is float32; a float64 operand (x64 runs off the chip)
     runs the op's ``plain`` arm in its own precision, and is counted so."""
     from photon_tpu.obs.metrics import REGISTRY
 
-    force("window")
+    force(arrangement)
     idx, val = _cell_like(41, 200, 6, 300, 32)
     feats = SparseFeatures(jnp.asarray(idx), jnp.asarray(val),
                            300).with_fast_path()
     counter = REGISTRY.counter("sparse_op_traces_total", "")
     before = {kind: counter.value(op="matvec", formulation=kind)
-              for kind in ("window", "plain")}
+              for kind in (arrangement, "plain")}
     w64 = jnp.asarray(np.random.default_rng(42).normal(size=300), jnp.float64)
     z64 = feats.matvec(w64)
     assert z64.dtype == jnp.float64
     feats.matvec(w64.astype(jnp.float32))
     assert counter.value(op="matvec", formulation="plain") == before["plain"] + 1
-    assert counter.value(op="matvec", formulation="window") == (
-        before["window"] + 1)
+    assert counter.value(op="matvec", formulation=arrangement) == (
+        before[arrangement] + 1)
     want = (np.append(np.asarray(w64), 0.0)[idx] * val.astype(np.float64)).sum(1)
     np.testing.assert_allclose(np.asarray(z64), want, rtol=1e-12, atol=1e-12)
 

@@ -210,6 +210,39 @@ def test_a_sweep_of_one_call_builds_once(builds):
     assert _table_counts(tree) == [(0, 1), (1, 0)]
 
 
+@pytest.mark.parametrize("arrangement", ["window", "planes"])
+def test_a_kept_bundle_hands_its_x_w_table_to_the_second_fit(
+        builds, monkeypatch, arrangement):
+    """Whichever arrangement the build chose for ``X.w`` (here: was made to
+    choose, by the margin it chooses by), the second fit on the bundle
+    takes that table by identity: no build, no table span, the same
+    coefficients bit for bit."""
+    monkeypatch.setattr(fast_sparse, "PLANES_MARGIN",
+                        {"window": 0.0, "planes": float("inf")}[arrangement])
+    estimator = _estimator()
+    bundle = _bundle(1)
+    first, tree = _fit(estimator, bundle)
+    kept = _kept(estimator)["global"].fast
+    assert kept.formulation("matvec") == arrangement
+    assert isinstance(kept.xw, fast_sparse.PlaneTable) == (
+        arrangement == "planes")
+    spans = [s[ARGS] for s in tree if s[NAME] == TABLES]
+    assert [s["formulation_matvec"] for s in spans] == [arrangement]
+    assert spans[0]["passes_per_slot_matvec"] == 1.0
+
+    second, tree = _fit(estimator, bundle)
+    assert builds.calls == 1
+    assert _table_counts(tree) == [(1, 0)]
+    assert not [s for s in tree if s[NAME] == TABLES]
+    assert _kept(estimator)["global"].fast is kept
+
+    def fixed(results):
+        return np.asarray(
+            results[0].model.models["fixed"].model.coefficients.means)
+
+    np.testing.assert_array_equal(fixed(first), fixed(second))
+
+
 def test_two_fixed_effects_on_one_shard_share_one_build(builds):
     estimator = GameEstimator(
         task=TaskType.LOGISTIC_REGRESSION,
