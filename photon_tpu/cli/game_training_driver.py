@@ -663,8 +663,11 @@ def _run_inner(args, task) -> dict:
                 )
             # Where the fit's seconds went, from its own span tree.
             parts = fit_breakdown(recent_trees("estimator.fit", last=1)[0])
-            logger.info("fit %.2f s = %s", parts.pop("fit"), " + ".join(
-                f"{name} {seconds:.2f}" for name, seconds in parts.items()))
+            fit_s, waited = parts.pop("fit"), parts.pop("waited")
+            logger.info(
+                "fit %.2f s = %s; host waited on the device %.2f", fit_s,
+                " + ".join(f"{name} {seconds:.2f}"
+                           for name, seconds in parts.items()), waited)
 
         suite = (
             EvaluationSuite.parse(args.evaluators) if args.evaluators else None

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu.obs.metrics import REGISTRY
-from photon_tpu.obs.trace import trace_span
+from photon_tpu.obs.trace import device_wait, trace_span
 from photon_tpu.ops import pass_counter
 from photon_tpu.types import REAL_ACCELERATOR_BACKENDS
 
@@ -129,10 +129,9 @@ class SparseFeatures:
 
         if self.fast is not None:
             return self
-        aux = build_fast_aux(
-            jax.device_get(self.idx), jax.device_get(self.val), self.dim,
-            q_capacity=q_capacity,
-        )
+        with device_wait("accel_tables"):
+            idx, val = jax.device_get(self.idx), jax.device_get(self.val)
+        aux = build_fast_aux(idx, val, self.dim, q_capacity=q_capacity)
         if jnp.dtype(self.val.dtype).itemsize < 4:
             # Values were already narrowed (with_value_dtype before attach):
             # the tables' values must match or their half of the bandwidth

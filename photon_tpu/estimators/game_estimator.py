@@ -61,6 +61,7 @@ from photon_tpu.game.descent import (
 from photon_tpu.io.data_reader import GameDataBundle
 from photon_tpu.obs import (
     current_trace_id,
+    device_wait,
     new_trace_id,
     trace_context,
     trace_span,
@@ -105,7 +106,9 @@ def build_re_dataset_from_bundle(
     # groups its rows twice (docs/observability.md).
     with trace_span("data.re_dataset", cat="data", re_type=cfg.re_type,
                     scoring=for_scoring) as span:
-        val_np = np.asarray(jax.device_get(sf.val))
+        with device_wait("re_dataset"):
+            val_np = np.asarray(jax.device_get(sf.val))
+            idx_np = np.asarray(jax.device_get(sf.idx))
         # Follow the bundle's feature precision (float64 under --dtype
         # float64) — EXCEPT sub-f32 feed dtypes: the bf16 feed narrows the
         # fixed-effect transfer only, while per-entity solves accumulate in
@@ -117,7 +120,7 @@ def build_re_dataset_from_bundle(
         dataset = build_random_effect_dataset(
             re_type=cfg.re_type,
             entity_keys_per_row=bundle.id_tags[cfg.re_type],
-            idx=np.asarray(jax.device_get(sf.idx)),
+            idx=idx_np,
             val=val_np,
             labels=bundle.labels,
             global_dim=sf.dim,
@@ -713,9 +716,12 @@ class GameEstimator:
         validation: ValidationData,
         suite: EvaluationSuite,
     ) -> EvaluationResults:
-        scores = validation.offsets + sum(
-            validation.scorers[cid](model[cid]) for cid in model.keys()
-        )
+        per_coordinate = []
+        for cid in model.keys():
+            with trace_span("validate.score", cat="estimator",
+                            coordinate=cid):
+                per_coordinate.append(validation.scorers[cid](model[cid]))
+        scores = validation.offsets + sum(per_coordinate)
         return suite.evaluate(
             scores,
             validation.labels,
@@ -742,9 +748,14 @@ def fit_breakdown(tree: Sequence[tuple]) -> dict[str, float]:
     the first fit on a bundle that needs them only),
     one entry per trained coordinate (its steps), ``validate``, and
     ``descent``: what is left, the host work between them. The parts add
-    up to ``fit``; a part that took no time is left out."""
+    up to ``fit``; a part that took no time is left out. Last, and no part
+    (it lies inside the others): ``waited``, the seconds the fit's thread
+    was blocked in a device-to-host read (``device.wait``)."""
     parts = {"prepare": 0.0, "tables": 0.0}
+    waited = 0.0
     for name, _, _, start, end, args in tree:
+        if name == "device.wait":
+            waited += end - start
         part = (args.get("coordinate") if name == "descent.step"
                 else _BREAKDOWN_PARTS.get(name))
         if part is not None:
@@ -752,7 +763,8 @@ def fit_breakdown(tree: Sequence[tuple]) -> dict[str, float]:
     parts["validate"] = parts.pop("validate", 0.0)   # after the coordinates
     fit = tree[-1][4] - tree[-1][3]
     parts["descent"] = fit - sum(parts.values())
-    return {"fit": fit, **{k: v for k, v in parts.items() if v}}
+    return {"fit": fit, **{k: v for k, v in parts.items() if v},
+            "waited": waited}
 
 
 def select_best(

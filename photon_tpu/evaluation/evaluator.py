@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from photon_tpu.evaluation import metrics
+from photon_tpu.obs import device_wait, trace_span
 
 Array = jax.Array
 
@@ -74,7 +75,8 @@ class Evaluator:
             )
         else:  # pragma: no cover - parse() keeps kinds closed
             raise ValueError(f"unknown evaluator kind {self.kind}")
-        return float(v)
+        with device_wait("evaluator"):
+            return float(v)
 
     def better_than(self, a: float, b: float) -> bool:
         """Is metric value ``a`` strictly better than ``b`` (NaN never wins)?"""
@@ -184,5 +186,7 @@ class EvaluationSuite:
                 gid = group_ids_by_column[ev.group_column]
                 if num_groups_by_column:
                     ng = num_groups_by_column.get(ev.group_column)
-            values[ev.name] = ev.evaluate(scores, labels, weights, gid, ng)
+            with trace_span("validate.evaluate", cat="evaluation",
+                            evaluator=ev.name).label(ev.name):
+                values[ev.name] = ev.evaluate(scores, labels, weights, gid, ng)
         return EvaluationResults(values, self.evaluators[0].name)

@@ -25,7 +25,7 @@ from photon_tpu.game.random_effect import (
     train_random_effects,
 )
 from photon_tpu.models.glm import GeneralizedLinearModel
-from photon_tpu.obs import trace_span, tracing_active
+from photon_tpu.obs import device_wait, trace_span, tracing_active
 from photon_tpu.parallel.data_parallel import fit_data_parallel
 
 Array = jax.Array
@@ -88,8 +88,9 @@ class FixedEffectCoordinate:
                 # One tiny D2H per solve, paid only when a trace is being
                 # collected: iteration count + convergence reason make the
                 # optimizer lane of the timeline self-describing.
-                sp.set(iterations=int(result.iterations),
-                       reason=result.reason_name())
+                with device_wait("fixed_solve"):
+                    sp.set(iterations=int(result.iterations),
+                           reason=result.reason_name())
         return FixedEffectModel(model, self.feature_shard), result
 
     def score(self, model: FixedEffectModel) -> Array:

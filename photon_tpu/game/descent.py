@@ -23,7 +23,7 @@ import numpy as np
 from photon_tpu.evaluation import EvaluationResults, EvaluationSuite
 from photon_tpu.faults import fault_point
 from photon_tpu.game.coordinates import Coordinate, DatumScoringModel
-from photon_tpu.obs import instant, trace_span
+from photon_tpu.obs import device_wait, instant, trace_span
 
 Array = jax.Array
 
@@ -71,14 +71,17 @@ def _solver_outcome(result, tron_counters: Optional[dict] = None
     if not results or not all(
             hasattr(r, "converged_reason") for r in results):
         return None
-    reasons = np.concatenate(
-        [np.asarray(r.converged_reason).ravel() for r in results])
+    with device_wait("solver_outcome"):
+        reasons = np.concatenate(
+            [np.asarray(r.converged_reason).ravel() for r in results])
+        iterations = int(max(
+            np.asarray(r.iterations).max() for r in results))
+        data_passes = int(sum(
+            np.asarray(r.data_passes).sum() for r in results))
     codes, counts = np.unique(reasons, return_counts=True)
     return {
-        "iterations": int(max(
-            np.asarray(r.iterations).max() for r in results)),
-        "data_passes": int(sum(
-            np.asarray(r.data_passes).sum() for r in results)),
+        "iterations": iterations,
+        "data_passes": data_passes,
         "reasons": {CONVERGENCE_REASON_NAMES[int(c)]: int(n)
                     for c, n in zip(codes, counts)},
         **(tron_counters or {}),
@@ -91,8 +94,9 @@ def _tron_counters(result) -> dict:
     solve, so that only a TRON step carries them."""
     if getattr(result, "hvp", None) is None:
         return {}
-    return {k: int(np.asarray(getattr(result, k)))
-            for k in ("hvp", "cg_steps", "rejected")}
+    with device_wait("tron_counters"):
+        return {k: int(np.asarray(getattr(result, k)))
+                for k in ("hvp", "cg_steps", "rejected")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,7 +277,9 @@ class CoordinateDescent:
                             residual_offset = total - scores[cid]
                             model, solve_result = coord.train(
                                 residual_offset, models.get(cid))
-                            new_score = coord.score(model)
+                            with trace_span("descent.score", cat="descent",
+                                            coordinate=cid):
+                                new_score = coord.score(model)
                             new_total = residual_offset + new_score
                             # Tiny D2H fetch: the step record (and span) must
                             # report COMPLETED compute, not async dispatch
@@ -282,7 +288,8 @@ class CoordinateDescent:
                             # dependency
                             # new_score <- model <- solve forces the whole
                             # step — and is the commit gate above.
-                            np.asarray(new_score[:1])
+                            with device_wait("step"):
+                                np.asarray(new_score[:1])
                             # The step is done: what TRON counted on the
                             # device goes on the span (no other solver's
                             # step carries these arguments), with what the
@@ -359,7 +366,9 @@ class CoordinateDescent:
                 if validation is not None:
                     with trace_span("descent.validate", cat="descent",
                                     coordinate=cid):
-                        v_cache[cid] = validation.scorers[cid](model)
+                        with trace_span("validate.score", cat="descent",
+                                        coordinate=cid):
+                            v_cache[cid] = validation.scorers[cid](model)
                         v_scores = sum(v_cache.values())
                         record.validation = suite.evaluate(
                             validation.offsets + v_scores,

@@ -104,6 +104,7 @@ import numpy as np
 from photon_tpu.data.random_effect import SELECT_MAX_COLUMNS
 from photon_tpu.models.coefficients import Coefficients
 from photon_tpu.models.glm import GeneralizedLinearModel
+from photon_tpu.obs import device_wait
 from photon_tpu.ops.losses import loss_for_task
 from photon_tpu.optim.base import (
     FUNCTION_VALUES_CONVERGED,
@@ -187,7 +188,8 @@ def u_max_for(d_pen) -> int:
     """Worst-per-entity count of UNPENALIZED columns (d_pen == 0) that the
     dual path must carry as explicit β parameters — typically 1 (the
     reg-masked intercept). Static for jit."""
-    return int(jnp.max(jnp.sum(d_pen <= 0.0, axis=1)))
+    with device_wait("u_max"):
+        return int(jnp.max(jnp.sum(d_pen <= 0.0, axis=1)))
 
 
 def _primal_need_bytes(e: int, s: int, p: int, esize: float) -> float:

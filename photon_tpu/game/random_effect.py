@@ -24,6 +24,7 @@ import numpy as np
 from photon_tpu.data.random_effect import EntityBucket, RandomEffectDataset
 from photon_tpu.faults import fault_point
 from photon_tpu.functions.problem import GLMOptimizationProblem
+from photon_tpu.obs import device_wait, trace_span
 from photon_tpu.parallel.mesh import (
     axes_size,
     batch_sharding,
@@ -174,8 +175,9 @@ class RandomEffectModel:
         Entities/columns absent from this model get the per-source fill.
         Returns one projected per-bucket list per source."""
         key_to_dense = self._key_to_dense
-        old_proj = [np.asarray(p) for p in self.bucket_proj]
-        old_vals = [[np.asarray(c) for c in src] for src in sources]
+        with device_wait("project_stacks"):
+            old_proj = [np.asarray(p) for p in self.bucket_proj]
+            old_vals = [[np.asarray(c) for c in src] for src in sources]
         slot_bucket, slot_lane = self._slots
         # dense REId of ``dataset`` -> dense REId here, -1 = never trained
         old_of = np.fromiter(
@@ -184,8 +186,9 @@ class RandomEffectModel:
         stride = self.global_dim + 1
         out: list[list[Array]] = [[] for _ in sources]
         for b in dataset.buckets:
-            proj = np.asarray(b.proj)
-            eids = np.asarray(b.entity_ids)
+            with device_wait("project_stacks"):
+                proj = np.asarray(b.proj)
+                eids = np.asarray(b.entity_ids)
             vals = [
                 np.full(proj.shape, fill, src[0].dtype)
                 for src, fill in zip(old_vals, fills)
@@ -630,7 +633,8 @@ def _solve_bucket(problem, bucket, batches, w0, local_mask, local_norm,
     measured_oom = None
     if (solver_routing.routing_mode() == "measured" and sticky is None):
         def sync(out):
-            np.asarray(out[1].value[:1])  # tiny D2H (repo-standard sync)
+            with device_wait("routing_sync"):
+                np.asarray(out[1].value[:1])  # tiny D2H (repo-standard sync)
 
         try:
             # Same chaos hook as the static ladder: an injected
@@ -724,7 +728,8 @@ def _alive_devices(devices, want: int):
     alive = []
     for d in devices:
         try:
-            np.asarray(jax.device_put(np.zeros((1,), np.float32), d))
+            with device_wait("device_probe"):
+                np.asarray(jax.device_put(np.zeros((1,), np.float32), d))
             alive.append(d)
         except Exception:  # noqa: BLE001 - a dead device is the point
             continue
@@ -848,47 +853,52 @@ def train_random_effects(
 
         p = bucket.local_dim
         e = bucket.n_entities
-        if init_coefs is not None:
-            w0 = jnp.asarray(init_coefs[b_i], bucket.val.dtype)
-            if w0.shape[0] < e:  # mesh padding added inert lanes
-                w0 = jnp.pad(w0, ((0, e - w0.shape[0]), (0, 0)))
-        else:
-            w0 = jnp.zeros((e, p), bucket.val.dtype)
+        # The bucket's inputs, all eager dispatches from the host (the
+        # starting point, the mask's and the offsets' gathers), in a span
+        # of their own beside the solve's.
+        with trace_span("optim.re_inputs", cat="optim",
+                        re_type=dataset.re_type, bucket=b_i):
+            if init_coefs is not None:
+                w0 = jnp.asarray(init_coefs[b_i], bucket.val.dtype)
+                if w0.shape[0] < e:  # mesh padding added inert lanes
+                    w0 = jnp.pad(w0, ((0, e - w0.shape[0]), (0, 0)))
+            else:
+                w0 = jnp.zeros((e, p), bucket.val.dtype)
 
-        # Project the global regularization mask into each local subspace.
-        # Ghost slots get mask 1 (their coefficients stay 0 regardless).
-        if global_reg_mask is not None:
-            ext = jnp.concatenate(
-                [global_reg_mask.astype(bucket.val.dtype), jnp.ones((1,), bucket.val.dtype)]
-            )
-            local_mask = ext[bucket.proj]
-        else:
-            local_mask = jnp.ones((e, p), bucket.val.dtype)
+            # Project the global regularization mask into each local
+            # subspace. Ghost slots get mask 1 (their coefficients stay 0
+            # regardless).
+            if global_reg_mask is not None:
+                ext = jnp.concatenate(
+                    [global_reg_mask.astype(bucket.val.dtype), jnp.ones((1,), bucket.val.dtype)]
+                )
+                local_mask = ext[bucket.proj]
+            else:
+                local_mask = jnp.ones((e, p), bucket.val.dtype)
 
-        batches = bucket.local_batches(offsets)
-        local_norm = (
-            project_context(normalization, bucket.proj, dataset.global_dim)
-            if normalization is not None
-            else None
-        )
-        local_prior = priors[b_i] if priors is not None else None
-        if local_prior is not None and local_prior.means.shape[0] < e:
-            # mesh padding added inert lanes: extend with zero-precision rows
-            pad = e - local_prior.means.shape[0]
-            local_prior = jax.tree.map(
-                lambda a: jnp.pad(a, ((0, pad), (0, 0))), local_prior
+            batches = bucket.local_batches(offsets)
+            local_norm = (
+                project_context(normalization, bucket.proj, dataset.global_dim)
+                if normalization is not None
+                else None
             )
+            local_prior = priors[b_i] if priors is not None else None
+            if local_prior is not None and local_prior.means.shape[0] < e:
+                # mesh padding added inert lanes: extend with
+                # zero-precision rows
+                pad = e - local_prior.means.shape[0]
+                local_prior = jax.tree.map(
+                    lambda a: jnp.pad(a, ((0, pad), (0, 0))), local_prior
+                )
 
         # Placement now happens INSIDE _solve_bucket (full-bucket plans
         # place once; chunked plans slice host-side and fan each chunk's
         # device_put out per shard with the transfer double-buffered).
 
-        from photon_tpu.obs import trace_span as _trace_span
-
         # What the bucket's padding costs, on the span: real rows (as the
         # dataset's builder counted them) beside the row slots solved.
         row_slots = int(bucket.max_samples) * orig_e
-        re_span = _trace_span(
+        re_span = trace_span(
             "optim.re_bucket", cat="optim", re_type=dataset.re_type,
             bucket=b_i, entities=orig_e, local_dim=p,
             padded_rows=int(bucket.max_samples), row_slots=row_slots, rows=int(dataset.bucket_rows[b_i]),
@@ -936,10 +946,11 @@ def train_random_effects(
                     # originals) instead of burning the recovery budget
                     # on re-reads that can never succeed.
                     try:
-                        rehosted = jax.tree.map(
-                            np.asarray,
-                            (bucket, batches, w0, local_mask, local_prior),
-                        )
+                        with device_wait("shard_rehost"):
+                            rehosted = jax.tree.map(
+                                np.asarray,
+                                (bucket, batches, w0, local_mask, local_prior),
+                            )
                     except Exception:  # noqa: BLE001 - data lost with device
                         degraded = None
                 if degraded is None:

@@ -42,6 +42,7 @@ from photon_tpu.obs.trace import (
     TailSampler,
     TraceCollector,
     current_trace_id,
+    device_wait,
     install_tail_sampler,
     instant,
     new_trace_id,
@@ -71,6 +72,7 @@ __all__ = [
     "TailSampler",
     "TraceCollector",
     "current_trace_id",
+    "device_wait",
     "install_tail_sampler",
     "instant",
     "new_trace_id",

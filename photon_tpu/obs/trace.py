@@ -36,6 +36,12 @@ Design constraints (docs/observability.md):
   its name, so the program's spans lie in the profile's ``/host:CPU`` plane
   beside the device planes. Nothing to switch on; a process that has not
   imported ``jax`` is never made to (the serving front line's workers).
+* **Where the host waits on the device.** Dispatch is asynchronous, so a
+  span that ends at dispatch says what the host did. Every read that
+  blocks a fit's thread until the device has a value is the leaf span
+  ``device.wait`` (:func:`device_wait`, argument ``site``): seconds inside
+  are the host blocked on the chip, a layer's seconds outside them the
+  host's own (docs/observability.md §"A fit's span tree").
 * **Mergeable across processes.** Every collector stamps a
   :data:`ANCHOR_EVENT` metadata instant at install — the wall-clock ↔
   ``perf_counter`` correspondence plus pid/hostname/role — so
@@ -65,6 +71,7 @@ __all__ = [
     "uninstall_tail_sampler",
     "tail_sampler",
     "trace_span",
+    "device_wait",
     "recent_trees",
     "instant",
     "process_role",
@@ -774,12 +781,13 @@ class trace_span:
     event's ``args``. A span entered by hand and still open when an outer
     span of its thread exits (an exception unwound past it) ends there,
     with that exception as its error. While a ``jax.profiler`` session
-    records, the span is also a ``TraceAnnotation`` of the same name.
+    records, the span is also a ``TraceAnnotation`` of the same name
+    (:meth:`label` adds to that one's).
     """
 
     __slots__ = ("name", "cat", "args", "trace_id", "seconds", "span_id",
                  "parent_id", "_t0", "_keep", "_discarded", "_records",
-                 "_annotation")
+                 "_annotation", "_label")
 
     def __init__(self, name: str, cat: str = "app",
                  trace_id: Optional[str] = None, **args):
@@ -790,6 +798,7 @@ class trace_span:
         self.seconds = 0.0
         self._keep = False
         self._discarded = False
+        self._label = name
 
     def set(self, **args) -> "trace_span":
         self.args.update(args)
@@ -801,6 +810,14 @@ class trace_span:
         :func:`recent_trees`. (A kept root under another keeps a tree of
         its own; the outer tree does not hold it.)"""
         self._keep = True
+        return self
+
+    def label(self, text: str) -> "trace_span":
+        """Ask, before entering, that the span's profiler annotation be
+        called ``<name>:<text>``. An annotation carries a name and no
+        arguments, so this is how a profile tells apart what the kept tree
+        and the collector tell apart by an argument; their name stays."""
+        self._label = f"{self.name}:{text}"
         return self
 
     def discard(self) -> None:
@@ -830,7 +847,7 @@ class trace_span:
         if annotation is None and "jax" in sys.modules:
             annotation = _find_annotation()
         if annotation is not None and annotation.is_enabled():
-            self._annotation = annotation(self.name)
+            self._annotation = annotation(self._label)
             self._annotation.__enter__()
         else:
             self._annotation = None
@@ -874,6 +891,19 @@ class trace_span:
                 ids["parent_id"] = self.parent_id
             col.complete(self.name, self.cat, self._t0, self.seconds,
                          {**args, **ids})
+
+
+def device_wait(site: str) -> trace_span:
+    """``with device_wait("step"): np.asarray(score[:1])`` — the span
+    ``device.wait`` around a read that blocks this thread until the device
+    has the value: every device-to-host read of a fit goes through here, so
+    that a fit's tree says where its host waited on the chip. ``site`` names
+    the place (an argument in the tree, ``device.wait:<site>`` in a
+    profile). It wraps the read that is there and adds none: seconds inside
+    it are a lower bound of the device's busy seconds (what was dispatched
+    before must finish first) plus the transfer's own; seconds of a layer
+    outside it are the host's, an upper bound of the device's idle ones."""
+    return trace_span("device.wait", cat="device", site=site).label(site)
 
 
 def instant(name: str, cat: str = "event", **args) -> None:

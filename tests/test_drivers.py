@@ -120,7 +120,9 @@ def test_training_and_scoring_drivers_end_to_end(game_data, tmp_path):
     assert os.path.exists(out / "index" / "global")
     assert os.path.exists(out / "photon.log")
     # the fit's own account of its seconds, from its span tree
-    m = re.search(r"fit (\S+) s = (.*)", open(out / "photon.log").read())
+    m = re.search(r"fit (\S+) s = (.*); host waited on the device (\S+)",
+                  open(out / "photon.log").read())
+    assert 0.0 < float(m.group(3)) < float(m.group(1))
     terms = dict(t.rsplit(" ", 1) for t in m.group(2).split(" + "))
     assert list(terms)[:1] == ["prepare"] and list(terms)[-1] == "descent"
     assert {"fixed", "perUser", "validate"} <= set(terms)

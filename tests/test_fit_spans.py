@@ -27,7 +27,12 @@ from photon_tpu.functions.problem import GLMOptimizationProblem, _fit_jitted
 from photon_tpu.game import newton_re
 from photon_tpu.io.data_reader import GameDataBundle
 from photon_tpu.obs import trace as obs_trace
-from photon_tpu.obs.trace import recent_trees, trace_span, tracing
+from photon_tpu.obs.trace import (
+    device_wait,
+    recent_trees,
+    trace_span,
+    tracing,
+)
 from photon_tpu.optim import (
     OptimizerConfig,
     RegularizationContext,
@@ -51,9 +56,19 @@ FIT_TREE = {
     "descent.step": "descent.sweep",
     "optim.fixed_solve": "descent.step",
     "optim.glm_fit": "optim.fixed_solve",
+    "optim.re_inputs": "descent.step",
     "optim.re_bucket": "descent.step",
+    "descent.score": "descent.step",
     "descent.validate": "descent.sweep",
     "estimator.evaluate": "estimator.fit",
+    "validate.score": ("descent.validate", "estimator.evaluate"),
+    "validate.evaluate": ("descent.validate", "estimator.evaluate"),
+    # by site: step; solver_outcome; project_stacks; evaluator; re_dataset;
+    # accel_tables (a traced fit's fixed_solve lies under optim.fixed_solve,
+    # a dual bucket's u_max under optim.re_bucket)
+    "device.wait": ("descent.step", "descent.sweep", "validate.score",
+                    "validate.evaluate", "data.re_dataset",
+                    "data.accel_tables"),
 }
 
 
@@ -112,6 +127,11 @@ def test_first_fit_leaves_exactly_the_catalogued_tree(two_fits):
     assert count["descent.sweep"] == 2
     assert count["descent.step"] == count["descent.validate"] == 4
     assert count["optim.glm_fit"] == count["optim.re_bucket"] == 2
+    assert count["optim.re_inputs"] == 2 and count["descent.score"] == 4
+    # a coordinate's scorer a step, every coordinate's on the returned
+    # model; two evaluators after a step and two on the returned model
+    assert count["validate.score"] == 4 + 2
+    assert count["validate.evaluate"] == 2 * 4 + 2
     assert count["data.accel_tables"] == 1
     assert count["data.re_dataset"] == 2       # training rows, validation rows
     assert count["estimator.fit"] == count["descent.run"] == 1
@@ -213,13 +233,110 @@ def test_a_dataset_span_says_what_was_grouped(two_fits, scoring, parent):
                     "row_slots": 6 * 16}
 
 
+def _children(tree):
+    out = {}
+    for s in tree:
+        out.setdefault(s[PARENT_ID], []).append(s)
+    return out
+
+
+def _sites(spans):
+    return [s[ARGS]["site"] for s in spans if s[NAME] == "device.wait"]
+
+
+def test_every_blocking_read_is_a_leaf_that_names_its_site(two_fits):
+    for tree in two_fits:
+        children = _children(tree)
+        waits = [s for s in tree if s[NAME] == "device.wait"]
+        assert waits and not any(children.get(s[SPAN_ID]) for s in waits)
+        assert set(_sites(waits)) - {"re_dataset", "accel_tables"} == {
+            "step", "solver_outcome", "project_stacks", "evaluator"}
+
+
+def test_a_step_scores_then_waits_once_for_the_device(two_fits):
+    """A step is its solve, its scorer and one commit read, in that order;
+    its seconds are theirs and its own: the children lie one after another
+    inside it."""
+    for tree in two_fits:
+        children = _children(tree)
+        steps = [s for s in tree if s[NAME] == "descent.step"]
+        assert len(steps) == 4
+        for step in steps:
+            inside = sorted(children[step[SPAN_ID]], key=lambda s: s[START])
+            assert _sites(inside) == ["step"]
+            assert [s[NAME] for s in inside][-2:] == ["descent.score",
+                                                       "device.wait"]
+            assert inside[-2][ARGS]["coordinate"] == step[ARGS]["coordinate"]
+            at, covered = step[START], 0.0
+            for s in inside:
+                assert at <= s[START] <= s[END] <= step[END]
+                at, covered = s[END], covered + s[END] - s[START]
+            own = (step[END] - step[START]) - covered
+            assert 0.0 <= own <= step[END] - step[START]
+
+
+def test_a_bucket_is_its_inputs_then_its_solve_under_the_step(two_fits):
+    """What the benchmark's bucket readers walk: a bucket span's parent is
+    a ``descent.step`` and that one's a ``descent.sweep``; nothing stands
+    between. The inputs' span is the sibling before."""
+    for tree in two_fits:
+        by_id = {s[SPAN_ID]: s for s in tree}
+        children = _children(tree)
+        buckets = [s for s in tree if s[NAME] == "optim.re_bucket"]
+        assert len(buckets) == 2
+        for bucket in buckets:
+            step = by_id[bucket[PARENT_ID]]
+            assert step[NAME] == "descent.step"
+            assert by_id[step[PARENT_ID]][NAME] == "descent.sweep"
+            inside = sorted(children[step[SPAN_ID]], key=lambda s: s[START])
+            assert [s[NAME] for s in inside] == [
+                "optim.re_inputs", "optim.re_bucket", "descent.score",
+                "device.wait"]
+            inputs = inside[0][ARGS]
+            assert (inputs["re_type"], inputs["bucket"]) == (
+                bucket[ARGS]["re_type"], bucket[ARGS]["bucket"])
+
+
+def test_validation_is_a_scorer_and_the_evaluators_each_with_its_wait(
+        two_fits):
+    for tree in two_fits:
+        by_id = {s[SPAN_ID]: s for s in tree}
+        children = _children(tree)
+        for v in (s for s in tree if s[NAME] == "descent.validate"):
+            inside = sorted(children[v[SPAN_ID]], key=lambda s: s[START])
+            assert [s[NAME] for s in inside] == [
+                "validate.score", "validate.evaluate", "validate.evaluate"]
+            assert inside[0][ARGS]["coordinate"] == v[ARGS]["coordinate"]
+        (final,) = [s for s in tree if s[NAME] == "estimator.evaluate"]
+        inside = sorted(children[final[SPAN_ID]], key=lambda s: s[START])
+        assert [(s[NAME], s[ARGS].get("coordinate")) for s in inside] == [
+            ("validate.score", "fixed"), ("validate.score", "perUser"),
+            ("validate.evaluate", None), ("validate.evaluate", None)]
+        evaluations = [s for s in tree if s[NAME] == "validate.evaluate"]
+        assert [s[ARGS]["evaluator"] for s in evaluations] == [
+            "AUC", "LOGISTIC_LOSS"] * 5
+        for e in evaluations:
+            assert _sites(children[e[SPAN_ID]]) == ["evaluator"]
+        # a per-entity model scored on other rows pulls its stacks; a
+        # fixed effect's scorer reads nothing
+        for s in (s for s in tree if s[NAME] == "validate.score"):
+            sites = set(_sites(children.get(s[SPAN_ID], ())))
+            assert sites == ({"project_stacks"}
+                             if s[ARGS]["coordinate"] == "perUser" else set())
+
+
 def test_fit_breakdown_adds_up_to_the_fit(two_fits):
     for tree, first in zip(two_fits, (True, False)):
         parts = fit_breakdown(tree)
-        assert list(parts)[0] == "fit" and list(parts)[-1] == "descent"
+        assert list(parts)[0] == "fit" and list(parts)[-2:] == [
+            "descent", "waited"]
         assert ("prepare" in parts) == ("tables" in parts) == first
         assert {"fixed", "perUser", "validate"} <= set(parts)
+        waited = parts.pop("waited")
+        assert waited == pytest.approx(sum(
+            s[END] - s[START] for s in tree if s[NAME] == "device.wait"))
         fit = parts.pop("fit")
+        assert 0.0 < waited < fit
         assert fit == pytest.approx(tree[-1][END] - tree[-1][START])
         assert sum(parts.values()) == pytest.approx(fit)
         assert all(v > 0 for v in parts.values())
@@ -230,8 +347,11 @@ def test_fit_breakdown_of_a_hand_built_tree():
         ("data.accel_tables", 3, 2, 0.1, 1.1, {}),
         ("estimator.build_coordinates", 2, 1, 0.0, 1.2, {}),
         ("optim.glm_fit", 7, 6, 1.3, 1.7, {}),
+        ("device.wait", 11, 6, 1.7, 1.8, {"site": "step"}),
         ("descent.step", 6, 5, 1.3, 1.8, {"coordinate": "global"}),
+        ("device.wait", 12, 8, 1.85, 1.9, {"site": "evaluator"}),
         ("descent.validate", 8, 5, 1.8, 1.9, {"coordinate": "global"}),
+        ("device.wait", 13, 9, 2.0, 2.2, {"site": "step"}),
         ("descent.step", 9, 5, 1.9, 2.2, {"coordinate": "per-user"}),
         ("descent.sweep", 5, 4, 1.25, 2.3, {}),
         ("descent.run", 4, 1, 1.2, 2.3, {}),
@@ -240,10 +360,10 @@ def test_fit_breakdown_of_a_hand_built_tree():
     ]
     parts = fit_breakdown(tree)
     assert list(parts) == ["fit", "tables", "global", "per-user", "validate",
-                           "descent"]
+                           "descent", "waited"]
     assert parts == pytest.approx({
         "fit": 2.5, "tables": 1.0, "global": 0.5, "per-user": 0.3,
-        "validate": 0.2, "descent": 0.5})
+        "validate": 0.2, "descent": 0.5, "waited": 0.35})
 
 
 def test_the_ring_holds_256_roots_and_drops_the_oldest():
@@ -360,7 +480,8 @@ def test_spans_lie_on_the_profilers_host_plane(tmp_path):
     with jax.profiler.trace(str(tmp_path)):
         with trace_span("estimator.fit", cat="estimator"):
             with trace_span("data.accel_tables", cat="data"):
-                jnp.ones(8).sum().block_until_ready()
+                with device_wait("accel_tables") as waited:
+                    jnp.ones(8).sum().block_until_ready()
     with trace_span("test.after_the_session", cat="test"):
         pass
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
@@ -369,14 +490,21 @@ def test_spans_lie_on_the_profilers_host_plane(tmp_path):
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for e in line.events:
-                if e.name in ("estimator.fit", "data.accel_tables",
-                              "test.after_the_session"):
+                if e.name.startswith(("estimator.fit", "data.accel_tables",
+                                      "device.wait",
+                                      "test.after_the_session")):
                     found[e.name] = (plane.name, e.start_ns,
                                      e.start_ns + e.duration_ns)
-    assert set(found) == {"estimator.fit", "data.accel_tables"}
+    # a wait's annotation says where (an annotation has no arguments); the
+    # kept tree's name stays and the site is its argument
+    assert set(found) == {"estimator.fit", "data.accel_tables",
+                          "device.wait:accel_tables"}
     assert {f[0] for f in found.values()} == {"/host:CPU"}
     fit, tables = found["estimator.fit"], found["data.accel_tables"]
-    assert fit[1] <= tables[1] and tables[2] <= fit[2]
+    wait = found["device.wait:accel_tables"]
+    assert fit[1] <= tables[1] <= wait[1] and wait[2] <= tables[2] <= fit[2]
+    assert (waited.name, waited.args) == ("device.wait",
+                                          {"site": "accel_tables"})
 
 
 def _sparse_batch(n=16, d=8, k=3):
