@@ -182,6 +182,16 @@ class RandomEffectDataset:
                 "rows": int(sum(self.bucket_rows)),
                 "row_slots": self.row_slots}
 
+    @functools.cached_property
+    def kept_projection(self) -> dict:
+        """What projecting a model into this dataset leaves for the next
+        projection (``game/random_effect.py``
+        ``RandomEffectModel.projection_plan``): ``plan``, the last plan
+        built into it, and ``outcome``, whether the last projection
+        ``built`` that plan or ``reused`` it. It lives as long as this
+        object and is not copied with it."""
+        return {}
+
     def scatter_scores(self, per_bucket_scores: Sequence[Array]) -> Array:
         """Assemble a global [n_rows] score vector from per-bucket [E, S]
         scores (padding slots point at the ghost row and are dropped)."""

@@ -57,7 +57,9 @@ from photon_tpu.game.descent import (
     CoordinateStepRecord,
     GameModel,
     ValidationData,
+    validation_scores,
 )
+from photon_tpu.game.random_effect import EntityScorer
 from photon_tpu.io.data_reader import GameDataBundle
 from photon_tpu.obs import (
     current_trace_id,
@@ -685,7 +687,7 @@ class GameEstimator:
                     self._intercept_for(dcfg.feature_shard),
                     for_scoring=True,
                 )
-                scorers[cid] = lambda m, v_ds=v_ds: m.score_new_dataset(v_ds)
+                scorers[cid] = EntityScorer(v_ds)
 
         group_cols = {
             ev.group_column
@@ -718,9 +720,8 @@ class GameEstimator:
     ) -> EvaluationResults:
         per_coordinate = []
         for cid in model.keys():
-            with trace_span("validate.score", cat="estimator",
-                            coordinate=cid):
-                per_coordinate.append(validation.scorers[cid](model[cid]))
+            per_coordinate.append(validation_scores(
+                validation, cid, model[cid], cat="estimator"))
         scores = validation.offsets + sum(per_coordinate)
         return suite.evaluate(
             scores,

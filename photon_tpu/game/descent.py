@@ -23,6 +23,7 @@ import numpy as np
 from photon_tpu.evaluation import EvaluationResults, EvaluationSuite
 from photon_tpu.faults import fault_point
 from photon_tpu.game.coordinates import Coordinate, DatumScoringModel
+from photon_tpu.game.random_effect import EntityScorer
 from photon_tpu.obs import device_wait, instant, trace_span
 
 Array = jax.Array
@@ -114,6 +115,19 @@ class ValidationData:
     scorers: Mapping[str, object]
     group_ids_by_column: Optional[Mapping[str, Array]] = None
     num_groups_by_column: Optional[Mapping[str, int]] = None
+
+
+def validation_scores(validation: ValidationData, cid: str, model,
+                      cat: str) -> Array:
+    """``validation.scorers[cid](model)`` in a ``validate.score`` span; a
+    per-entity scorer (``EntityScorer``) says on it, as ``plan``, whether
+    the call built its projection plan or reused the one kept."""
+    scorer = validation.scorers[cid]
+    with trace_span("validate.score", cat=cat, coordinate=cid) as span:
+        scores = scorer(model)
+        if isinstance(scorer, EntityScorer):
+            span.set(plan=scorer.plan)
+    return scores
 
 
 @dataclasses.dataclass(frozen=True)
@@ -366,9 +380,8 @@ class CoordinateDescent:
                 if validation is not None:
                     with trace_span("descent.validate", cat="descent",
                                     coordinate=cid):
-                        with trace_span("validate.score", cat="descent",
-                                        coordinate=cid):
-                            v_cache[cid] = validation.scorers[cid](model)
+                        v_cache[cid] = validation_scores(
+                            validation, cid, model, cat="descent")
                         v_scores = sum(v_cache.values())
                         record.validation = suite.evaluate(
                             validation.offsets + v_scores,

@@ -67,6 +67,11 @@ _RE_CALIBRATION_SECONDS = _OBS_REGISTRY.counter(
     "Wall seconds spent in solver-routing calibration races "
     "(game/solver_routing.py)",
 )
+_RE_PROJECTION_PLANS = _OBS_REGISTRY.counter(
+    "re_projection_plans_total",
+    "Projections of a per-entity model into another dataset, by whether "
+    "the projection built its plan or reused the one the dataset kept",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,47 +163,59 @@ class RandomEffectModel:
             bucket[dense], lane[dense] = b, ln
         return bucket, lane
 
-    def _project_stacks(
-        self,
-        dataset: RandomEffectDataset,
-        sources: Sequence[Sequence[Array]],
-        fills: Sequence[float],
-    ) -> list[list[Array]]:
-        """Project per-entity [E, P] stacks (aligned with this model's bucket
-        structure) into ``dataset``'s local subspaces, several value sets in
-        ONE pass. Host-side remap — the reference's model-RDD join by REId
-        (SURVEY.md §3.6) — with no per-entity Python: the entities of one
-        (new bucket, trained bucket) pair are matched together, each new
-        local column against its entity's trained columns (ascending, as
-        the builders write them) by ONE ``searchsorted`` over keys
-        ``lane * stride + column``.
-        Entities/columns absent from this model get the per-source fill.
-        Returns one projected per-bucket list per source."""
+    def projection_plan(self, dataset: RandomEffectDataset) -> "ProjectionPlan":
+        """The plan of a projection from this model's structure into
+        ``dataset``: the one the dataset keeps if it was built for this
+        structure (every model trained on one dataset shares it, whatever
+        its coefficients), else a new one, which the dataset then keeps in
+        its place. Counted in ``re_projection_plans_total{outcome}``."""
+        kept = dataset.kept_projection
+        plan = kept.get("plan")
+        if plan is not None and plan.fits(self):
+            outcome = "reused"
+        else:
+            plan = kept["plan"] = self._build_plan(dataset)
+            outcome = "built"
+        kept["outcome"] = outcome
+        _RE_PROJECTION_PLANS.inc(outcome=outcome)
+        return plan
+
+    def _build_plan(self, dataset: RandomEffectDataset) -> "ProjectionPlan":
+        """Match ``dataset``'s local subspaces against this model's: the
+        reference's model-RDD join by REId (SURVEY.md §3.6), on the host
+        with no per-entity Python. The entities of one (new bucket,
+        trained bucket) pair are matched together, each new local column
+        against its entity's trained columns (ascending, as the builders
+        write them) by ONE ``searchsorted`` over keys ``lane * stride +
+        column``. A hit becomes the flat index of its trained slot in this
+        model's per-bucket ``[E, P]`` stacks laid end to end; an entity or
+        column absent from this model, a ghost column and a padded lane
+        stay -1."""
         key_to_dense = self._key_to_dense
         with device_wait("project_stacks"):
             old_proj = [np.asarray(p) for p in self.bucket_proj]
-            old_vals = [[np.asarray(c) for c in src] for src in sources]
+        start = np.cumsum([0] + [p.size for p in old_proj])
+        if start[-1] >= np.iinfo(np.int32).max:
+            raise ValueError(f"{start[-1]} trained slots overflow an int32 index")
         slot_bucket, slot_lane = self._slots
         # dense REId of ``dataset`` -> dense REId here, -1 = never trained
         old_of = np.fromiter(
             (key_to_dense.get(k, -1) for k in dataset.entity_keys),
             np.int64, len(dataset.entity_keys))
         stride = self.global_dim + 1
-        out: list[list[Array]] = [[] for _ in sources]
+        index = []
         for b in dataset.buckets:
             with device_wait("project_stacks"):
                 proj = np.asarray(b.proj)
                 eids = np.asarray(b.entity_ids)
-            vals = [
-                np.full(proj.shape, fill, src[0].dtype)
-                for src, fill in zip(old_vals, fills)
-            ]
+            idx = np.full(proj.shape, -1, np.int32)
             dense_old = np.where(eids >= 0, old_of[np.maximum(eids, 0)], -1)
             from_bucket = np.where(
                 dense_old >= 0, slot_bucket[np.maximum(dense_old, 0)], -1)
             for bo in np.unique(from_bucket[from_bucket >= 0]):
                 lanes = np.flatnonzero(from_bucket == bo)
                 from_lanes = slot_lane[dense_old[lanes]]
+                width = old_proj[bo].shape[1]
                 row = np.arange(len(lanes), dtype=np.int64)[:, None] * stride
                 # ascending: a lane's trained columns, its ghosts last
                 have = (old_proj[bo][from_lanes] + row).ravel()
@@ -207,18 +224,26 @@ class RandomEffectModel:
                                  len(have) - 1).reshape(want.shape)
                 hit = (have[pos] == want) & (proj[lanes] < self.global_dim)
                 lane_at, col = np.nonzero(hit)
-                for s, src in enumerate(old_vals):
-                    vals[s][lanes[lane_at], col] = (
-                        src[bo][from_lanes].ravel()[pos[hit]])
-            for s, v in enumerate(vals):
-                out[s].append(jnp.asarray(v))
-        return out
+                at = pos[hit]
+                idx[lanes[lane_at], col] = (
+                    start[bo] + from_lanes[at // width] * width + at % width)
+            index.append(idx)
+        return ProjectionPlan(
+            index=tuple(jax.device_put(index)),
+            shapes=tuple(p.shape for p in old_proj),
+            bucket_proj=tuple(self.bucket_proj),
+            entity_keys=self.entity_keys,
+            entity_to_slot=self.entity_to_slot,
+            global_dim=self.global_dim,
+        )
 
     def project_to(self, dataset: RandomEffectDataset) -> list[Array]:
         """Coefficient stacks re-projected into a *different* dataset's local
         subspaces (validation / scoring data); entities unseen at training
-        time get the zero model."""
-        return self._project_stacks(dataset, [self.bucket_coefs], [0.0])[0]
+        time get the zero model: one gather on the device through
+        ``projection_plan(dataset)``."""
+        return self.projection_plan(dataset).gather(
+            [self.bucket_coefs], [0.0])[0]
 
     def project_posteriors_to(
         self, dataset: RandomEffectDataset
@@ -227,9 +252,8 @@ class RandomEffectModel:
         one entity pass — the raw material for incremental-training priors.
         Unseen entities/columns get the N(0, 1) default posterior."""
         if self.bucket_variances is not None:
-            means, variances = self._project_stacks(
-                dataset, [self.bucket_coefs, self.bucket_variances], [0.0, 1.0]
-            )
+            means, variances = self.projection_plan(dataset).gather(
+                [self.bucket_coefs, self.bucket_variances], [0.0, 1.0])
         else:
             means = self.project_to(dataset)
             variances = [jnp.ones_like(m) for m in means]
@@ -255,6 +279,78 @@ class RandomEffectModel:
             b.scores(c) for b, c in zip(dataset.buckets, coef_stacks)
         ]
         return dataset.scatter_scores(per_bucket)
+
+
+@dataclasses.dataclass(frozen=True)
+class ProjectionPlan:
+    """Where every slot of a target dataset's per-bucket ``[E', P']``
+    stacks takes its value from, for one model structure: its flat index
+    in the model's per-bucket ``[E, P]`` stacks laid end to end (ravel
+    order), -1 for the fill. Built on the host once for a pair of
+    structure and dataset (``RandomEffectModel.projection_plan``) and held
+    on the device; a projection is then one gather there."""
+
+    index: tuple                     # per target bucket: int32 [E', P']
+    shapes: tuple                    # the model's per-bucket [E, P]
+    bucket_proj: tuple               # the structure it was built for:
+    entity_keys: Sequence            # held, and compared by identity
+    entity_to_slot: dict
+    global_dim: int
+
+    def fits(self, model: RandomEffectModel) -> bool:
+        """``model`` has the structure this plan was built for: the same
+        objects (``coordinates.py`` ``_same_structure``'s test), so no
+        value needs reading to know it."""
+        return (model.entity_keys is self.entity_keys
+                and model.entity_to_slot is self.entity_to_slot
+                and model.global_dim == self.global_dim
+                and len(model.bucket_proj) == len(self.bucket_proj)
+                and all(p is q for p, q in
+                        zip(model.bucket_proj, self.bucket_proj)))
+
+    def gather(self, sources: Sequence[Sequence[Array]],
+               fills: Sequence[float]) -> list[list[Array]]:
+        """Each source's stacks (shaped as the model's) projected into the
+        target's buckets, ``fill`` where the plan holds -1."""
+        for src in sources:
+            if tuple(np.shape(s) for s in src) != self.shapes:
+                raise ValueError(
+                    f"stacks {[np.shape(s) for s in src]} are not the "
+                    f"{list(self.shapes)} this plan was built for")
+        return _gather_stacks(tuple(tuple(src) for src in sources),
+                              self.index, tuple(float(f) for f in fills))
+
+
+@functools.partial(jax.jit, static_argnames="fills")
+def _gather_stacks(sources, index, fills):
+    """One program a projection: a source's stacks raveled end to end (and
+    one element more, so that the operand is never empty), then every
+    target bucket's stack one gather of them."""
+    out = []
+    for stacks, fill in zip(sources, fills):
+        dtype = stacks[0].dtype if stacks else jnp.float32
+        flat = jnp.concatenate(
+            [s.ravel() for s in stacks] + [jnp.zeros((1,), dtype)])
+        out.append([jnp.where(i >= 0, flat[jnp.maximum(i, 0)], fill)
+                    for i in index])
+    return out
+
+
+class EntityScorer:
+    """A per-entity coordinate's validation scorer: a model's scores on
+    ``dataset``'s rows (``score_new_dataset``) through the plan the dataset
+    keeps, so that the models of every step and of every fit over one
+    training dataset reuse the plan the first of them built. ``plan`` is
+    what the last call did: ``"built"`` or ``"reused"``."""
+
+    def __init__(self, dataset: RandomEffectDataset):
+        self.dataset = dataset
+        self.plan: Optional[str] = None
+
+    def __call__(self, model) -> Array:
+        scores = model.score_new_dataset(self.dataset)
+        self.plan = self.dataset.kept_projection["outcome"]
+        return scores
 
 
 def _pad_bucket(

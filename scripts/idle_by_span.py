@@ -193,16 +193,20 @@ def report(cell: str, config: dict, seed: int, reduced: dict, trees: list
             f"benchmarks.layer_metrics.{name}").read(state)
         for name in READERS}
     waits: dict = {}
+    plans: dict = {}
     for tree in trees:
         for name, _, _, start, end, args in tree:
             if name == WAIT:
                 site = args.get("site")
                 waits[site] = waits.get(site, 0.0) + (end - start) / fits
+            elif name == "validate.score" and "plan" in args:
+                plans[args["plan"]] = plans.get(args["plan"], 0) + 1
     out = {"cell": cell, "seed": seed, "fits": fits,
            "window_s": reduced["window_s"],
            "busy_s_a_fit": reduced["busy_s"] / fits,
            "idle_s_a_fit": idle / fits, "readings": readings,
            "wait_s_a_fit_by_site": waits,
+           "projection_plans": plans,
            "spans_a_fit": sum(len(t) for t in trees) / fits,
            "by_span": {name: {k: v / fits for k, v in r.items()}
                        for name, r in rows}}

@@ -25,6 +25,7 @@ from photon_tpu.estimators import (
 )
 from photon_tpu.functions.problem import GLMOptimizationProblem, _fit_jitted
 from photon_tpu.game import newton_re
+from photon_tpu.game.random_effect import _RE_PROJECTION_PLANS as _PLANS
 from photon_tpu.io.data_reader import GameDataBundle
 from photon_tpu.obs import trace as obs_trace
 from photon_tpu.obs.trace import (
@@ -63,8 +64,8 @@ FIT_TREE = {
     "estimator.evaluate": "estimator.fit",
     "validate.score": ("descent.validate", "estimator.evaluate"),
     "validate.evaluate": ("descent.validate", "estimator.evaluate"),
-    # by site: step; solver_outcome; project_stacks; evaluator; re_dataset;
-    # accel_tables (a traced fit's fixed_solve lies under optim.fixed_solve,
+    # by site: step; solver_outcome; project_stacks (a plan being built);
+    # evaluator; re_dataset; accel_tables (a traced fit's fixed_solve lies under optim.fixed_solve,
     # a dual bucket's u_max under optim.re_bucket)
     "device.wait": ("descent.step", "descent.sweep", "validate.score",
                     "validate.evaluate", "data.re_dataset",
@@ -89,10 +90,11 @@ def _bundle(seed, n_users=6, rows_per_user=12, d_global=5, d_user=3):
 
 
 @pytest.fixture(scope="module")
-def two_fits():
+def fitted():
     """The kept trees of two fits of one estimator on one bundle, fixed +
-    one random effect, validation on. The fast-path tables are a TPU
-    default; here the CPU is named an accelerator so that they are built."""
+    one random effect, validation on, and what ``re_projection_plans_total``
+    counted over them. The fast-path tables are a TPU default; here the
+    CPU is named an accelerator so that they are built."""
     estimator = GameEstimator(
         task=TaskType.LOGISTIC_REGRESSION,
         coordinate_data_configs={
@@ -105,13 +107,21 @@ def two_fits():
         for cid in ("fixed", "perUser")}
     train, validation = _bundle(1), _bundle(2)
     before = len(recent_trees("estimator.fit"))
+    plans = {o: _PLANS.value(outcome=o) for o in ("built", "reused")}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(batch_mod, "REAL_ACCELERATOR_BACKENDS", ("cpu",))
         estimator.fit(train, validation, [config])
         estimator.fit(train, validation, [config])
     trees = recent_trees("estimator.fit")
     assert len(trees) == min(before + 2, 256)
-    return trees[-2:]
+    return {"trees": trees[-2:],
+            "plans": {o: _PLANS.value(outcome=o) - n
+                      for o, n in plans.items()}}
+
+
+@pytest.fixture(scope="module")
+def two_fits(fitted):
+    return fitted["trees"]
 
 
 def _names(tree):
@@ -245,12 +255,16 @@ def _sites(spans):
 
 
 def test_every_blocking_read_is_a_leaf_that_names_its_site(two_fits):
-    for tree in two_fits:
+    """The first fit builds the per-entity scorer's projection plan and
+    reads the model's columns for it; the second reuses the plan and reads
+    nothing of the kind."""
+    for tree, sites in zip(two_fits, (
+            {"step", "solver_outcome", "project_stacks", "evaluator"},
+            {"step", "solver_outcome", "evaluator"})):
         children = _children(tree)
         waits = [s for s in tree if s[NAME] == "device.wait"]
         assert waits and not any(children.get(s[SPAN_ID]) for s in waits)
-        assert set(_sites(waits)) - {"re_dataset", "accel_tables"} == {
-            "step", "solver_outcome", "project_stacks", "evaluator"}
+        assert set(_sites(waits)) - {"re_dataset", "accel_tables"} == sites
 
 
 def test_a_step_scores_then_waits_once_for_the_device(two_fits):
@@ -317,12 +331,39 @@ def test_validation_is_a_scorer_and_the_evaluators_each_with_its_wait(
             "AUC", "LOGISTIC_LOSS"] * 5
         for e in evaluations:
             assert _sites(children[e[SPAN_ID]]) == ["evaluator"]
-        # a per-entity model scored on other rows pulls its stacks; a
-        # fixed effect's scorer reads nothing
-        for s in (s for s in tree if s[NAME] == "validate.score"):
-            sites = set(_sites(children.get(s[SPAN_ID], ())))
-            assert sites == ({"project_stacks"}
-                             if s[ARGS]["coordinate"] == "perUser" else set())
+        # a fixed effect's scorer reads nothing and has no plan
+        for s in (s for s in tree if s[NAME] == "validate.score"
+                  and s[ARGS]["coordinate"] == "fixed"):
+            assert "plan" not in s[ARGS]
+            assert not _sites(children.get(s[SPAN_ID], ()))
+
+
+def test_a_per_entity_scorer_builds_its_plan_once_over_both_fits(fitted):
+    """The first ``perUser`` scorer of the first fit builds the projection
+    plan and holds every read of site ``project_stacks`` of both fits; every
+    later one, in that fit and the next, reuses the plan the validation
+    dataset keeps and waits on nothing: the estimator keeps both datasets
+    with the bundle, and every model trained on one dataset shares its
+    ``proj`` arrays. Counted alike by ``re_projection_plans_total``."""
+    scorers = []
+    for tree in fitted["trees"]:
+        children = _children(tree)
+        for s in sorted(tree, key=lambda s: s[START]):
+            if (s[NAME] == "validate.score"
+                    and s[ARGS]["coordinate"] == "perUser"):
+                scorers.append((s, _sites(children.get(s[SPAN_ID], ()))))
+    # two steps of the coordinate and the returned model, a fit
+    assert len(scorers) == 2 * 3
+    (first, first_sites), *later = scorers
+    assert first[ARGS]["plan"] == "built"
+    reads = [s for tree in fitted["trees"] for s in tree
+             if s[NAME] == "device.wait"
+             and s[ARGS]["site"] == "project_stacks"]
+    assert reads and {s[PARENT_ID] for s in reads} == {first[SPAN_ID]}
+    assert set(first_sites) == {"project_stacks"}
+    for s, sites in later:
+        assert s[ARGS]["plan"] == "reused" and sites == []
+    assert fitted["plans"] == {"built": 1, "reused": 2 * 3 - 1}
 
 
 def test_fit_breakdown_adds_up_to_the_fit(two_fits):
