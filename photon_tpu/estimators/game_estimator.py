@@ -572,6 +572,7 @@ class GameEstimator:
                     latent_dim=dcfg.latent_dim,
                     n_alternations=dcfg.n_alternations,
                     seed=self.seed,
+                    device_cache=prep.get("device_cache"),
                 )
             else:
                 dataset = prep["train"][cid]

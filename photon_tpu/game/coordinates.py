@@ -172,13 +172,23 @@ class RandomEffectCoordinate:
 @dataclasses.dataclass(frozen=True)
 class FactoredRandomEffectCoordinate:
     """Per-entity models in a learned latent space — reference
-    ⟦FactoredRandomEffectCoordinate⟧ (see game/factored_random_effect.py)."""
+    ⟦FactoredRandomEffectCoordinate⟧ (see game/factored_random_effect.py).
+    Trains and scores in the latent space, over the dataset's rows laid
+    flat; never in an entity's local subspace."""
 
     dataset: RandomEffectDataset
     problem: GLMOptimizationProblem
     latent_dim: int = 8
     n_alternations: int = 2
     seed: int = 0
+    # The sweep cache of ``RandomEffectCoordinate`` (host-resident bucket
+    # datasets pinned on device at first touch).
+    device_cache: Optional[object] = None
+
+    def _data(self) -> RandomEffectDataset:
+        if self.device_cache is None:
+            return self.dataset
+        return self.device_cache.dataset_mirror(self.dataset)
 
     def train(self, offsets: Array, init=None):
         from photon_tpu.game.factored_random_effect import (
@@ -193,26 +203,23 @@ class FactoredRandomEffectCoordinate:
         if not isinstance(init, (FactoredRandomEffectModel, RandomEffectModel)):
             init = None
         return train_factored_random_effects(
-            self.problem, self.dataset, offsets,
+            self.problem, self._data(), offsets,
             latent_dim=self.latent_dim,
             n_alternations=self.n_alternations,
             seed=self.seed,
             init=init,
         )
 
+    def span_arguments(self) -> dict:
+        """What a step of this coordinate works on, for its
+        ``descent.step`` span: the dataset's, and ``latent_dim``."""
+        return {**self.dataset.span_arguments(), "latent_dim": self.latent_dim}
+
     def score(self, model) -> Array:
-        # Score through the effective per-entity model; a foreign model
-        # (loaded warm start / locked coordinate, possibly a plain
-        # RandomEffectModel) goes through key-matched re-projection.
-        eff = getattr(model, "effective", model)
-        same = len(eff.bucket_proj) == len(self.dataset.buckets) and all(
-            p is b.proj for p, b in zip(eff.bucket_proj, self.dataset.buckets)
-        )
-        return (
-            eff.score_dataset(self.dataset)
-            if same
-            else eff.score_new_dataset(self.dataset)
-        )
+        """Scores of the training rows: a factored model's in its latent
+        space; a foreign plain model's (a loaded warm start, a locked
+        coordinate) through key-matched re-projection."""
+        return model.score_new_dataset(self._data())
 
 
 Coordinate = Union[

@@ -77,7 +77,9 @@ def test_factored_training_learns_low_rank_structure():
     )
     assert model.latent_dim == 3
     assert model.projection.shape == (dim, 3)
-    assert len(results) == len(ds.buckets)
+    # every solve of the step: three latent steps' buckets (two
+    # alternations and the refresh) and the two projection steps
+    assert len(results) == 3 * len(ds.buckets) + 2
     scores = np.asarray(model.score_dataset(ds))
     auc = _auc(scores, y)
     assert auc > 0.75, auc
@@ -254,3 +256,251 @@ def test_factored_rejects_unsupported_options():
         reg_weight=1.0, max_iterations=5, down_sampling_rate=0.5)}
     with pytest.raises(ValueError, match="down-sampling"):
         est.fit(bundle, None, [cfg])
+
+
+# ------------------------------------------- the latent space vs the reference
+#
+# The factored coordinate trains and scores in its latent space over the
+# rows laid flat (game/factored_random_effect.py). These hold each piece to
+# the plain NumPy float64 reference the benchmark states
+# (benchmarks/references/factored_re.py), on users whose local subspaces
+# (the movies they rated) are up to 60 columns wide: past the 32 at which a
+# bucket's per-entity scorer changes path. Tolerances: the program runs in
+# float32, whose rounding over sums of a few hundred terms reads about
+# 1e-6 of a result; each limit below is that with room, and far under the
+# 4e-3 that bfloat16 projected rows read (``test_bfloat16_rows_fail_...``).
+
+F32_ROOM = 2e-5       # a flat product, a score, an objective or a gradient
+INIT_ROOM = 1e-4      # four rounds of subspace iteration, each re-orthogonal
+LATENT_ROOM = 1e-3    # latent residual after 8 Newton steps: float32 optimum
+
+
+def _factored_data(seed, n_users=40, n_movies=96, rank=4):
+    """Each user rates 20 to 60 distinct movies; labels from a planted
+    rank-``rank`` term. The movie shard: one entry a row, value 1."""
+    rng = np.random.default_rng(seed)
+    counts = rng.integers(20, 61, n_users)
+    users = np.repeat(np.arange(n_users), counts)
+    movies = np.concatenate([rng.choice(n_movies, c, replace=False)
+                             for c in counts])
+    uf = rng.normal(size=(n_users, rank))
+    vf = rng.normal(size=(n_movies, rank)) * (1.5 / np.sqrt(rank))
+    z = np.einsum("nr,nr->n", uf[users], vf[movies])
+    y = (rng.random(len(z)) < 1 / (1 + np.exp(-z))).astype(np.float32)
+    order = rng.permutation(len(y))
+    users, movies, y = users[order], movies[order], y[order]
+    idx = movies[:, None].astype(np.int32)
+    val = np.ones((len(y), 1), np.float32)
+    return users, idx, val, y, n_movies
+
+
+def _factored_setup(seed=11, latent=8, lam=1.0):
+    from benchmarks.references import factored_re
+
+    users, idx, val, y, dim = _factored_data(seed)
+    ds = build_random_effect_dataset("userId", users, idx, val, y, dim)
+    ref_rows = factored_re.FactoredRows(
+        idx=idx, val=val, ent=users, y=y, dim=dim,
+        n_entities=int(users.max()) + 1, l2=lam)
+    return ds, ref_rows, users
+
+
+def _by_user(lats, ds):
+    """Per-bucket latent stacks as ``[users, p]`` rows by user key."""
+    flat = np.concatenate([np.asarray(b, np.float64) for b in lats])
+    slots = np.concatenate([np.asarray(b.entity_ids) for b in ds.buckets])
+    keys = np.asarray(ds.entity_keys)
+    out = np.zeros((len(keys), flat.shape[1]))
+    out[keys[slots]] = flat
+    return out
+
+
+def _rel(got, want):
+    return float(np.linalg.norm(np.asarray(got, np.float64) - want)
+                 / np.linalg.norm(want))
+
+
+def test_flat_products_match_the_reference_on_wide_buckets():
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, ref, users = _factored_setup()
+    assert max(b.local_dim for b in ds.buckets) > 32   # the wide path
+    rows = f.flat_rows(ds)
+    rng = np.random.default_rng(0)
+    P = rng.normal(size=(ref.dim, 8)).astype(np.float32)
+    beta = rng.normal(size=(rows.n_slots, 8)).astype(np.float32)
+    r = rng.normal(size=rows.idx.shape[0]).astype(np.float32)
+    b_user = _by_user(np.split(beta, np.cumsum(
+        [b.n_entities for b in ds.buckets])[:-1]), ds)
+
+    xp = ref.times(P.astype(np.float64))
+    for b in ds.buckets:                       # XP laid into the slots
+        got = np.asarray(f.project_slots(jnp.asarray(P), rows.idx, rows.val,
+                                         b.row_ids))
+        live = np.asarray(b.row_ids) < len(users)
+        want = xp[np.asarray(b.row_ids)[live]]
+        assert _rel(got[live], want) < F32_ROOM
+        assert not got[~live].any()
+    margins = f.latent_scores(jnp.asarray(P), jnp.asarray(beta), rows.idx,
+                              rows.val, rows.slot, n_rows=rows.n_rows)
+    assert _rel(margins, np.sum(xp * ref.of_entity(b_user), 1)) < F32_ROOM
+    rr = np.asarray(r[: len(users)], np.float64)
+    got = f._transpose_dot(jnp.asarray(r), jnp.asarray(beta), rows.idx,
+                           rows.val, rows.slot, ref.dim)
+    assert _rel(got, ref.transpose_times(rr[:, None] * ref.of_entity(b_user))
+                ) < F32_ROOM
+
+
+def test_the_init_is_the_reference_subspace():
+    from benchmarks.references import factored_re
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, ref, users = _factored_setup()
+    offsets = np.random.default_rng(1).normal(size=len(users)) * 0.5
+    got = f.subspace_init(_problem(), f.flat_rows(ds),
+                          jnp.asarray(offsets, jnp.float32), ref.dim, 8,
+                          seed=0)
+    want = factored_re.subspace_init(ref, offsets.astype(np.float32), 8,
+                                     seed=0, rounds=f.INIT_ROUNDS)
+    assert _rel(got, want) < INIT_ROOM
+    np.testing.assert_allclose(np.asarray(got).T @ np.asarray(got),
+                               np.eye(8), atol=1e-5)
+
+
+def test_a_latent_step_solves_the_stated_latent_problems():
+    from benchmarks.references import factored_re
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, ref, users = _factored_setup()
+    rng = np.random.default_rng(2)
+    P = (rng.normal(size=(ref.dim, 8)) * 0.3).astype(np.float32)
+    offsets = (rng.normal(size=len(users)) * 0.5).astype(np.float32)
+    lats0 = [jnp.zeros((b.n_entities, 8)) for b in ds.buckets]
+    lats, results = f.latent_step(_problem(max_iter=8), ds, f.flat_rows(ds),
+                                  jnp.asarray(P), jnp.asarray(offsets), lats0)
+    assert len(results) == len(ds.buckets)
+    problems = factored_re.LatentProblems.at(ref, P, offsets)
+    b = _by_user(lats, ds)
+    assert max(problems.residual_by_class(b).values()) < LATENT_ROOM
+    # the objective at the program's answer is the reference's optimum's
+    best = problems.solve(np.zeros_like(b))
+    assert abs(problems.value(b) - problems.value(best)) \
+        < F32_ROOM * problems.value(best)
+
+
+def test_a_projection_step_runs_the_stated_lbfgs():
+    from benchmarks import reference
+    from benchmarks.references import factored_re
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, ref, users = _factored_setup()
+    rng = np.random.default_rng(3)
+    P0 = (rng.normal(size=(ref.dim, 8)) * 0.3).astype(np.float32)
+    lats = [jnp.asarray(rng.normal(size=(b.n_entities, 8)), jnp.float32)
+            for b in ds.buckets]
+    offsets = (rng.normal(size=len(users)) * 0.5).astype(np.float32)
+    P, result = f.projection_step(_problem(max_iter=4), f.flat_rows(ds),
+                                  jnp.asarray(P0), jnp.asarray(offsets), lats)
+    problem = factored_re.ProjectionProblem(ref, _by_user(lats, ds), offsets)
+    want = reference.lbfgs(problem, 4, P0.ravel().astype(np.float64))
+    got_v = np.asarray(result.values, np.float64)[:5]
+    got_g = np.asarray(result.grad_norms, np.float64)[:5]
+    # objective and gradient at the start, then the stated path
+    assert abs(got_v[0] - want["values"][0]) < F32_ROOM * want["values"][0]
+    assert abs(got_g[0] - want["grad_norms"][0]) \
+        < F32_ROOM * want["grad_norms"][0]
+    assert want["sure"] == 4
+    np.testing.assert_allclose(got_v, want["values"], rtol=F32_ROOM)
+    w = np.asarray(P, np.float64).ravel()
+    assert abs(float(result.value) - problem.value_from_margins(
+        problem.margins(w), w)) < F32_ROOM * float(result.value)
+
+
+def test_latent_space_scores_equal_the_effective_models():
+    from benchmarks.references import factored_re
+
+    ds, ref, users = _factored_setup()
+    model, _ = train_factored_random_effects(
+        _problem(max_iter=6), ds, jnp.zeros(len(users)), latent_dim=8,
+        n_alternations=1)
+    latent = np.asarray(model.score_dataset(ds))
+    # the materialized per-entity coefficients score the rows alike, by
+    # the per-entity scorer's gather at these widths
+    effective = np.asarray(model.effective.score_dataset(ds))
+    assert _rel(latent, effective) < F32_ROOM
+    want = factored_re.scores(ref, np.asarray(model.projection, np.float64),
+                              _by_user(model.bucket_latent, ds))
+    assert _rel(latent, want) < F32_ROOM
+    # rows of another dataset: a user the model never saw scores 0
+    vu, vi, vv, vy, _ = _factored_data(12, n_users=50)
+    vds = build_random_effect_dataset("userId", vu, vi, vv, vy, ref.dim)
+    got = np.asarray(model.score_new_dataset(vds))
+    assert not got[vu >= 40].any() and got[vu < 40].any()
+    vref = factored_re.FactoredRows(idx=vi, val=vv, ent=np.where(
+        vu < 40, vu, -1), y=vy, dim=ref.dim, n_entities=40, l2=1.0)
+    assert _rel(got, factored_re.scores(
+        vref, np.asarray(model.projection, np.float64),
+        _by_user(model.bucket_latent, ds))) < F32_ROOM
+
+
+def test_a_whole_factored_step_matches_the_reference_alternation():
+    from benchmarks.references import factored_re
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, ref, users = _factored_setup()
+    offsets = (np.random.default_rng(4).normal(size=len(users)) * 0.3
+               ).astype(np.float32)
+    model, results = train_factored_random_effects(
+        _problem(max_iter=4), ds, jnp.asarray(offsets), latent_dim=8,
+        n_alternations=2)
+    want = factored_re.alternation(ref, offsets, 8, seed=0,
+                                   rounds=f.INIT_ROUNDS, alternations=2,
+                                   iterations=4)
+    got = np.asarray(model.score_dataset(ds))
+    # float32 and float64 paths part by rounding over 2 alternations of a
+    # nonconvex problem: the scores read 3.3e-5 apart, and 7.7e-4 with the
+    # latent steps' projected rows rounded to bfloat16
+    assert _rel(got, factored_re.scores(ref, want["P"], want["b"])) < 2e-4
+
+
+def test_bfloat16_rows_fail_the_tolerances():
+    """The limits above are tight enough to see projected rows rounded
+    to bfloat16."""
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, ref, users = _factored_setup()
+    rows = f.flat_rows(ds)
+    rng = np.random.default_rng(0)
+    P = rng.normal(size=(ref.dim, 8)).astype(np.float32)
+    beta = rng.normal(size=(rows.n_slots, 8)).astype(np.float32)
+    b_user = _by_user(np.split(beta, np.cumsum(
+        [b.n_entities for b in ds.buckets])[:-1]), ds)
+    rounded = jnp.asarray(P).astype(jnp.bfloat16).astype(jnp.float32)
+    margins = f.latent_scores(rounded, jnp.asarray(beta), rows.idx,
+                              rows.val, rows.slot, n_rows=rows.n_rows)
+    want = np.sum(ref.times(P.astype(np.float64)) * ref.of_entity(b_user), 1)
+    assert _rel(margins, want) > 1e-3
+
+
+def test_the_flat_rows_are_kept_while_the_dataset_lives():
+    """The factored path keeps a dataset's flat rows and latent plan in a
+    holder of its own, built once and let go with the dataset; the latent
+    buckets' column maps are made for the step and kept nowhere."""
+    import gc
+
+    from photon_tpu.game import factored_random_effect as f
+
+    ds, _, _ = _factored_setup()
+    rows = f.flat_rows(ds)
+    assert f.flat_rows(ds) is rows
+    P = jnp.zeros((ds.global_dim, 8), jnp.float32)
+    buckets = f._latent_buckets(ds, rows, P)
+    assert [tuple(b.idx.shape) for b in buckets] == [
+        tuple(b.idx.shape[:2]) + (8,) for b in ds.buckets]
+    assert np.array_equal(np.asarray(buckets[0].idx[0, 0]), np.arange(8))
+    assert set(f._kept(ds)) == {"rows"}
+    assert "rows" not in ds.kept_projection
+    key = id(ds)
+    del ds, buckets
+    gc.collect()
+    assert key not in f._KEPT
