@@ -22,7 +22,11 @@ scatter-add into ``[D+1, p]`` (the projection gradient): no operand is
 batched per entity. The latent step hands each bucket to the per-entity
 solver of the plain random effect (``train_random_effects``, its routing,
 the Newton lanes) as a dense bucket of ``p`` local columns; the projection
-step runs the shared L-BFGS on maintained margins. ``P`` starts as the top-
+step runs the shared L-BFGS on maintained margins. Where the shard holds
+one entry a row (an item id), the projection step runs over the rows
+sorted by item instead (``sorted_rows``, built once a dataset): its
+products are the one-hot products of ``ops/item_sorted.py`` on the MXU,
+and the rows' latent vectors are laid out once a step. ``P`` starts as the top-
 ``p`` right singular subspace of the entities × columns matrix of first-
 order residuals, found by subspace iteration through the same two flat
 products (``subspace_init``), and ``β`` from the first latent step. The
@@ -52,6 +56,7 @@ from photon_tpu.game.random_effect import (
 )
 from photon_tpu.obs import device_wait, trace_span
 from photon_tpu.obs.metrics import REGISTRY
+from photon_tpu.ops import item_sorted
 from photon_tpu.ops.losses import loss_for_task
 from photon_tpu.optim import LBFGS, OptimizerResult
 from photon_tpu.types import TaskType
@@ -67,6 +72,11 @@ _PROJECTION_PASSES = REGISTRY.counter(
     "Data passes (one flat product of the rows with P, or its transpose) "
     "made by the projection steps of factored random effects",
 )
+_PROJECTION_PLAN_BUILDS = REGISTRY.counter(
+    "factored_projection_plan_builds_total",
+    "Rows sorted by item for the projection steps of factored random "
+    "effects (``sorted_rows``): one a dataset, kept while it lives",
+)
 
 
 # Rows a flat product takes at a time. On the TPU a gather writes its
@@ -75,6 +85,7 @@ _PROJECTION_PASSES = REGISTRY.counter(
 CHUNK_ROWS = 1 << 19
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class FlatRows:
     """A per-entity dataset's rows in the global row order, padded to whole
@@ -90,20 +101,43 @@ class FlatRows:
     slot: Array       # [R] int32
     weights: Array    # [R]
     labels: Array     # [R]
-    n_rows: int
-    n_slots: int
+    n_rows: int = dataclasses.field(metadata=dict(static=True))
+    n_slots: int = dataclasses.field(metadata=dict(static=True))
 
 
-# What the factored path keeps for a dataset while the dataset lives, by
-# the dataset's id: ``rows`` (``flat_rows``) and ``latent_plan``.
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class SortedRows:
+    """A one-entry dataset's flat rows sorted by their item, stably, the
+    ghost and padding rows last, padded to whole grid steps of the kernels
+    (``ops/item_sorted.py``): ``perm[i]`` is the flat row at sorted
+    position ``i`` (past the flat rows' offsets for a padding row); ``col``
+    and ``val`` the sorted items and values (``[C, CHUNK]``; a ghost or
+    padding row: item ``D``, value 0); ``slot``, ``weights`` and ``labels``
+    sorted alike; ``windows`` the chunks' windows (``item_windows``).
+    ``window_passes`` is the windows one product reads."""
+
+    perm: Array       # [R'] int32
+    col: Array        # [C, CHUNK] int32
+    val: Array        # [C, CHUNK]
+    slot: Array       # [R'] int32
+    weights: Array    # [R']
+    labels: Array     # [R']
+    windows: Array    # [C] int32
+    window_passes: int = dataclasses.field(metadata=dict(static=True))
+
+
+# What the factored path keeps for an object while it lives, by its id:
+# for a dataset ``rows`` (``flat_rows``) and ``latent_plan``; for the
+# dataset's flat rows ``sorted`` (``sorted_rows``).
 _KEPT: dict = {}
 
 
-def _kept(dataset: RandomEffectDataset) -> dict:
-    key = id(dataset)
+def _kept(owner) -> dict:
+    key = id(owner)
     if key not in _KEPT:
         _KEPT[key] = {}
-        weakref.finalize(dataset, _KEPT.pop, key, None)
+        weakref.finalize(owner, _KEPT.pop, key, None)
     return _KEPT[key]
 
 
@@ -149,6 +183,51 @@ def _build_flat_rows(dataset: RandomEffectDataset) -> FlatRows:
     return FlatRows(idx=jnp.asarray(idx), val=jnp.asarray(val),
                     slot=jnp.asarray(slot), weights=jnp.asarray(weights),
                     labels=jnp.asarray(labels), n_rows=n, n_slots=start)
+
+
+def sorted_rows(rows: FlatRows, P: Array):
+    """The rows sorted by item for a projection step at ``P``
+    (:class:`SortedRows`), built on the host once and kept while the rows
+    (and so their dataset) live; ``None`` where the step keeps the flat
+    products: more than one entry a row, data or ``P`` not float32, or a
+    ``P`` the kernels cannot hold (``item_sorted.holds``)."""
+    dim, p = P.shape
+    if not (rows.idx.shape[1] == 1 and rows.val.dtype == jnp.float32
+            and P.dtype == jnp.float32 and item_sorted.holds(dim, p)):
+        return None
+    kept = _kept(rows)
+    if "sorted" not in kept:
+        kept["sorted"] = _build_sorted_rows(rows, dim)
+        _PROJECTION_PLAN_BUILDS.inc()
+    return kept["sorted"]
+
+
+def _build_sorted_rows(rows: FlatRows, dim: int) -> SortedRows:
+    with device_wait("factored_plan"):
+        col, val, slot, weights, labels = (np.asarray(a).reshape(-1) for a in (
+            rows.idx, rows.val, rows.slot, rows.weights, rows.labels))
+    n = len(col)
+    # 16-bit keys take NumPy's radix sort; the ghost item ``dim`` sorts last.
+    order = np.argsort(col.astype(np.uint16) if dim < 1 << 16 else col,
+                       kind="stable")
+    padded = -(-n // item_sorted.STEP_ROWS) * item_sorted.STEP_ROWS
+
+    def laid(a: np.ndarray, fill) -> np.ndarray:
+        out = np.full(padded, fill, a.dtype)
+        out[:n] = a[order]
+        return out
+
+    items = laid(col, dim)
+    windows = item_sorted.item_windows(items, dim)
+    return SortedRows(
+        perm=jnp.asarray(laid(np.arange(n, dtype=np.int32), n)),
+        col=jnp.asarray(items.reshape(-1, item_sorted.CHUNK)),
+        val=jnp.asarray(laid(val, 0).reshape(-1, item_sorted.CHUNK)),
+        slot=jnp.asarray(laid(slot, rows.n_slots)),
+        weights=jnp.asarray(laid(weights, 0)),
+        labels=jnp.asarray(laid(labels, 0)),
+        windows=jnp.asarray(windows),
+        window_passes=item_sorted.window_passes(windows))
 
 
 # ------------------------------------------------------ the flat products
@@ -291,27 +370,74 @@ def subspace_init(problem: GLMOptimizationProblem, rows: FlatRows,
         offsets, task=problem.task, n_slots=rows.n_slots, rounds=INIT_ROUNDS)
 
 
+def _latent_columns(beta: Array, slot: Array, rows: int) -> Array:
+    """``[rows, R]``: column ``n`` is ``beta[slot[n]]`` (0 past ``beta``'s
+    rows, and in the rows past its width), gathered ``CHUNK_ROWS`` rows at
+    a time and laid lane-dense: never ``[R, p]``, which the TPU pads to 128
+    lanes."""
+    table = jnp.pad(beta, ((0, 0), (0, rows - beta.shape[1])))
+    size = _chunk_rows(slot)
+
+    def one(i, out):
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _take(table, _chunk(slot, i)).T, i * size, axis=1)
+
+    return jax.lax.fori_loop(0, _n_chunks(slot), one,
+                             jnp.zeros((rows, slot.shape[0]), beta.dtype))
+
+
+def _sorted_products(plan: SortedRows, beta: Array, offsets: Array,
+                     shape: tuple):
+    """``(times, transpose, offsets)`` over rows sorted by item: the latent
+    vectors laid once (``_latent_columns``), the offsets put in the plan's
+    order, and both products the kernels of ``ops/item_sorted.py``, in
+    float32."""
+    bt = _latent_columns(beta.astype(jnp.float32), plan.slot,
+                         item_sorted.latent_rows(shape[1]))
+    return (
+        lambda table: item_sorted.sorted_times(
+            plan.windows, plan.col, plan.val, table, bt),
+        lambda r: item_sorted.sorted_scatter(
+            plan.windows, plan.col, plan.val, r, bt, shape),
+        jnp.take(offsets.astype(jnp.float32), plan.perm, mode="fill",
+                 fill_value=0))
+
+
+def _flat_products(rows: FlatRows, beta: Array, offsets: Array,
+                   shape: tuple):
+    """``(times, transpose, offsets)`` over the rows laid flat."""
+    return (
+        lambda table: _times_dot(table, beta, rows.idx, rows.val, rows.slot),
+        lambda r: _transpose_dot(r, beta, rows.idx, rows.val, rows.slot,
+                                 shape[0]),
+        _padded(offsets, rows.idx.shape[0], beta.dtype))
+
+
 @partial(jax.jit, static_argnums=(0,))
-def _projection_step(problem, P, idx, val, slot, weights, labels, offsets,
-                     beta):
+def _projection_step(problem, P, rows, offsets, beta):
     """Refit ``P`` with every β fixed: L-BFGS over ``vec(P)`` on maintained
     margins ``z[n] = XP[n] · β_{u(n)} + o_n``; the gradient is
-    ``Xᵀ(d1 ⊙ B) + λP`` with ``B[n] = β_{u(n)}``, a flat scatter-add."""
+    ``Xᵀ(d1 ⊙ B) + λP`` with ``B[n] = β_{u(n)}``. Over ``SortedRows`` the
+    margins are kept in the plan's order for the whole solve and both
+    products are the item-sorted kernels; over ``FlatRows``, the flat
+    gather and scatter-add."""
     loss = loss_for_task(problem.task)
     lam = problem.regularization.l2_weight(problem.reg_weight)
     dim, p = P.shape
-    offsets = _padded(offsets, idx.shape[0], P.dtype)
+    times, transpose, offsets = (
+        _sorted_products if isinstance(rows, SortedRows) else _flat_products
+    )(rows, beta, offsets, P.shape)
+    weights, labels = rows.weights, rows.labels
 
     def score_delta(w):
-        return _times_dot(w.reshape(dim, p), beta, idx, val, slot)
+        return times(w.reshape(dim, p))
 
     def value(z, w):
         return jnp.sum(weights * loss.loss(z, labels)) + 0.5 * lam * jnp.sum(
             w * w)
 
     def grad(z, w):
-        d1 = weights * loss.d1(z, labels)
-        return (_transpose_dot(d1, beta, idx, val, slot, dim).reshape(-1)
+        return (transpose(weights * loss.d1(z, labels)).reshape(-1)
                 + lam * w)
 
     objective = ScoreSpaceObjective(
@@ -387,13 +513,18 @@ def latent_step(problem: GLMOptimizationProblem,
 
 def projection_step(problem: GLMOptimizationProblem, rows: FlatRows,
                     P: Array, offsets: Array, lats: Sequence[Array]):
-    """``P`` refitted at the given ``β``: ``(P, result)``."""
+    """``P`` refitted at the given ``β``: ``(P, result)``. Over the rows
+    sorted by item where ``sorted_rows`` gives a plan, else over the flat
+    rows."""
     with trace_span("factored.projection", cat="optim",
                     parameters=int(P.size)) as span:
+        plan = sorted_rows(rows, P)
+        span.set(formulation="flat" if plan is None else "sorted")
+        if plan is not None:
+            span.set(window_passes=plan.window_passes)
         beta = jnp.concatenate(list(lats))
         P, result = _projection_step(
-            problem, P, rows.idx, rows.val, rows.slot, rows.weights,
-            rows.labels, offsets, beta)
+            problem, P, rows if plan is None else plan, offsets, beta)
         outcome = _outcome([result])
         span.set(**outcome)
     _PROJECTION_PASSES.inc(outcome["passes"])

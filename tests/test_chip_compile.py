@@ -566,6 +566,53 @@ def test_entity_sharded_bucket_solver_compiles_on_four_devices(four_chips):
         _problem(PER_USER), batches, w0, mask, None).compile()
 
 
+# The factored cell's projection step: 9,997,911 rows padded to whole
+# chunks of the flat rows, 26,744 items, 69,246 users' latent vectors.
+FACTORED_ROWS, FACTORED_ITEMS, FACTORED_SLOTS = 10485760, 26744, 69246
+
+
+@pytest.mark.parametrize("latent", [8, 32])
+def test_the_factored_projection_step_compiles_over_rows_sorted_by_item(
+        one_chip, compiled_kernel, latent):
+    """``_projection_step`` over ``SortedRows`` at the factored cell's
+    shapes: the chip's compiler takes both kernels inside the L-BFGS loop,
+    and the rows' latent vectors are laid ``[p, rows]`` and never held as
+    ``[rows, p]`` (5.4 GB at 32, padded to 128 lanes). Read here at 32:
+    1.98 GB of temporaries, the 1.34 GB of ``Bᵀ`` among them."""
+    from photon_tpu.functions.problem import GLMOptimizationProblem
+    from photon_tpu.game import factored_random_effect as f
+    from photon_tpu.optim import (
+        OptimizerConfig,
+        OptimizerType,
+        RegularizationContext,
+        RegularizationType,
+    )
+    from photon_tpu.types import TaskType
+
+    sh = one_chip
+    r, chunks = FACTORED_ROWS, FACTORED_ROWS // CHUNK
+    plan = f.SortedRows(
+        perm=_sds((r,), "int32", sh), col=_sds((chunks, CHUNK), "int32", sh),
+        val=_sds((chunks, CHUNK), "float32", sh),
+        slot=_sds((r,), "int32", sh), weights=_sds((r,), "float32", sh),
+        labels=_sds((r,), "float32", sh),
+        windows=_sds((chunks,), "int32", sh), window_passes=chunks)
+    problem = GLMOptimizationProblem(
+        task=TaskType.LOGISTIC_REGRESSION, optimizer_type=OptimizerType.LBFGS,
+        optimizer_config=OptimizerConfig(max_iterations=3),
+        regularization=RegularizationContext(RegularizationType.L2),
+        reg_weight=10.0)
+    compiled = f._projection_step.lower(
+        problem, _sds((FACTORED_ITEMS, latent), "float32", sh), plan,
+        _sds((9997911,), "float32", sh),
+        _sds((FACTORED_SLOTS, latent), "float32", sh)).compile()
+    text = compiled.as_text()
+    assert "factored_sorted_times" in text
+    assert "factored_sorted_scatter" in text
+    assert f"f32[{r},{latent}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 # ----------------------------------- what the compiler refuses (ROADMAP R-a3)
 
 

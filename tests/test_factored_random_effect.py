@@ -275,9 +275,10 @@ INIT_ROOM = 1e-4      # four rounds of subspace iteration, each re-orthogonal
 LATENT_ROOM = 1e-3    # latent residual after 8 Newton steps: float32 optimum
 
 
-def _factored_data(seed, n_users=40, n_movies=96, rank=4):
+def _factored_data(seed, n_users=40, n_movies=96, rank=4, entries=1):
     """Each user rates 20 to 60 distinct movies; labels from a planted
-    rank-``rank`` term. The movie shard: one entry a row, value 1."""
+    rank-``rank`` term. The movie shard: one entry a row, value 1 (with
+    ``entries`` 2, a second movie beside it, value 0.5)."""
     rng = np.random.default_rng(seed)
     counts = rng.integers(20, 61, n_users)
     users = np.repeat(np.arange(n_users), counts)
@@ -289,15 +290,16 @@ def _factored_data(seed, n_users=40, n_movies=96, rank=4):
     y = (rng.random(len(z)) < 1 / (1 + np.exp(-z))).astype(np.float32)
     order = rng.permutation(len(y))
     users, movies, y = users[order], movies[order], y[order]
-    idx = movies[:, None].astype(np.int32)
-    val = np.ones((len(y), 1), np.float32)
+    idx = np.stack([movies, (7 * movies + 1) % n_movies][:entries],
+                   axis=1).astype(np.int32)
+    val = np.array([1.0, 0.5][:entries], np.float32)[None].repeat(len(y), 0)
     return users, idx, val, y, n_movies
 
 
-def _factored_setup(seed=11, latent=8, lam=1.0):
+def _factored_setup(seed=11, latent=8, lam=1.0, entries=1):
     from benchmarks.references import factored_re
 
-    users, idx, val, y, dim = _factored_data(seed)
+    users, idx, val, y, dim = _factored_data(seed, entries=entries)
     ds = build_random_effect_dataset("userId", users, idx, val, y, dim)
     ref_rows = factored_re.FactoredRows(
         idx=idx, val=val, ent=users, y=y, dim=dim,
@@ -388,19 +390,32 @@ def test_a_latent_step_solves_the_stated_latent_problems():
         < F32_ROOM * problems.value(best)
 
 
-def test_a_projection_step_runs_the_stated_lbfgs():
+@pytest.mark.parametrize("entries,latent,formulation", [
+    (1, 8, "sorted"),      # one movie a row: the rows sorted by movie
+    (2, 8, "flat"),        # two entries a row
+    (1, 43, "flat"),       # three parts of 43 latent columns pass 128 rows
+])
+def test_a_projection_step_runs_the_stated_lbfgs(entries, latent,
+                                                 formulation):
     from benchmarks import reference
     from benchmarks.references import factored_re
     from photon_tpu.game import factored_random_effect as f
+    from photon_tpu.obs.trace import recent_trees, trace_span
 
-    ds, ref, users = _factored_setup()
+    ds, ref, users = _factored_setup(entries=entries)
     rng = np.random.default_rng(3)
-    P0 = (rng.normal(size=(ref.dim, 8)) * 0.3).astype(np.float32)
-    lats = [jnp.asarray(rng.normal(size=(b.n_entities, 8)), jnp.float32)
+    P0 = (rng.normal(size=(ref.dim, latent)) * 0.3).astype(np.float32)
+    lats = [jnp.asarray(rng.normal(size=(b.n_entities, latent)), jnp.float32)
             for b in ds.buckets]
     offsets = (rng.normal(size=len(users)) * 0.5).astype(np.float32)
-    P, result = f.projection_step(_problem(max_iter=4), f.flat_rows(ds),
-                                  jnp.asarray(P0), jnp.asarray(offsets), lats)
+    with trace_span("test.projection_step").keep_tree():
+        P, result = f.projection_step(_problem(max_iter=4), f.flat_rows(ds),
+                                      jnp.asarray(P0), jnp.asarray(offsets),
+                                      lats)
+    span = next(s for s in recent_trees("test.projection_step", 1)[0]
+                if s[0] == "factored.projection")[5]
+    assert span["formulation"] == formulation
+    assert ("window_passes" in span) == (formulation == "sorted")
     problem = factored_re.ProjectionProblem(ref, _by_user(lats, ds), offsets)
     want = reference.lbfgs(problem, 4, P0.ravel().astype(np.float64))
     got_v = np.asarray(result.values, np.float64)[:5]
@@ -414,6 +429,73 @@ def test_a_projection_step_runs_the_stated_lbfgs():
     w = np.asarray(P, np.float64).ravel()
     assert abs(float(result.value) - problem.value_from_margins(
         problem.margins(w), w)) < F32_ROOM * float(result.value)
+
+
+def test_the_sorted_plan_is_built_once_and_kept_while_the_dataset_lives():
+    """A dataset's rows are sorted by movie at its first projection step
+    and kept with its flat rows: later steps build nothing, another
+    dataset builds its own, and the plan goes with its dataset."""
+    import gc
+
+    from photon_tpu.game import factored_random_effect as f
+    from photon_tpu.obs.metrics import REGISTRY
+
+    builds = REGISTRY.counter("factored_projection_plan_builds_total")
+    ds, ref, users = _factored_setup()
+    rng = np.random.default_rng(5)
+    P0 = jnp.asarray(rng.normal(size=(ref.dim, 8)) * 0.3, jnp.float32)
+    lats = [jnp.zeros((b.n_entities, 8)) for b in ds.buckets]
+    offsets = jnp.zeros(len(users))
+    before = builds.value()
+    for _ in range(2):
+        f.projection_step(_problem(max_iter=1), f.flat_rows(ds), P0,
+                          offsets, lats)
+    assert builds.value() == before + 1
+    rows = f.flat_rows(ds)
+    assert f.sorted_rows(rows, P0) is f._kept(rows)["sorted"]
+    other, _, _ = _factored_setup(seed=12)
+    f.sorted_rows(f.flat_rows(other), P0)
+    assert builds.value() == before + 2
+    key = id(rows)
+    del ds, rows
+    gc.collect()
+    assert key not in f._KEPT
+    assert builds.value() == before + 2
+
+
+def test_bfloat16_sorted_products_fail_the_tolerances():
+    """The limits above are tight enough to see the sorted products'
+    operands rounded to bfloat16: the projection step's first gradient."""
+    from benchmarks.references import factored_re
+    from photon_tpu.game import factored_random_effect as f
+    from photon_tpu.ops import item_sorted
+    from photon_tpu.ops.losses import loss_for_task
+
+    ds, ref, users = _factored_setup()
+    rows = f.flat_rows(ds)
+    rng = np.random.default_rng(6)
+    P0 = (rng.normal(size=(ref.dim, 8)) * 0.3).astype(np.float32)
+    lats = [jnp.asarray(rng.normal(size=(b.n_entities, 8)), jnp.float32)
+            for b in ds.buckets]
+    offsets = (rng.normal(size=len(users)) * 0.5).astype(np.float32)
+    problem = factored_re.ProjectionProblem(ref, _by_user(lats, ds), offsets)
+    want = ref.entity_transpose_times(ref.d1(problem.margins(
+        P0.ravel().astype(np.float64))), problem.b).ravel()
+    plan = f.sorted_rows(rows, jnp.asarray(P0))
+    loss = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+
+    def first_gradient(rounded):
+        def low(x):
+            return x.astype(jnp.bfloat16).astype(x.dtype) if rounded else x
+
+        times, transpose, o = f._sorted_products(
+            plan, low(jnp.concatenate(lats)), jnp.asarray(offsets),
+            P0.shape)
+        z = times(low(jnp.asarray(P0))) + o
+        return transpose(plan.weights * loss.d1(z, plan.labels))
+
+    assert _rel(first_gradient(False).ravel(), want) < F32_ROOM
+    assert _rel(first_gradient(True).ravel(), want) > 1e-3
 
 
 def test_latent_space_scores_equal_the_effective_models():
